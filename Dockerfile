@@ -18,7 +18,10 @@ WORKDIR /opt/gordo
 COPY pyproject.toml README.md ./
 COPY gordo_components_tpu ./gordo_components_tpu
 
-# TPU runtime: swap `jax` for `jax[tpu]` when building for TPU VMs
+# This image runs on the CPU backend (plain `jax`). For TPU VMs install
+# `jax[tpu]` instead and set JAX_PLATFORMS=tpu in the pod spec: a process
+# that cannot get its chip then fails at start-up, where JAX would
+# otherwise hand back the CPU without failing.
 RUN pip install --no-cache-dir .
 
 ENTRYPOINT ["python", "-m", "gordo_components_tpu.cli"]

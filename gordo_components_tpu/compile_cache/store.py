@@ -48,10 +48,11 @@ import os
 import pickle
 import shutil
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from ..observability.registry import REGISTRY
 from ..store import StoreError, atomic_commit, sweep_leftovers, verify_artifact
+from ..utils.backend import CACHE_DIR_ENV
 from . import fingerprint as fp
 
 logger = logging.getLogger(__name__)
@@ -80,7 +81,8 @@ _M_LOOKUPS = REGISTRY.counter(
 _M_WRITES = REGISTRY.counter(
     "gordo_compile_cache_writes_total",
     "Persistent compile-cache write-backs, by outcome (ok / error / "
-    "unserializable)",
+    "unserializable / compile_error: the AOT compile itself failed and "
+    "the program serves via lazy JIT)",
     labels=("outcome",),
 )
 _M_LOAD_SECONDS = REGISTRY.histogram(
@@ -109,10 +111,17 @@ class CompileCacheStore:
     def get(
         self,
         program_key: Dict[str, Any],
+        devices: Sequence[Any],
         probe: Optional[Callable[[Any], None]] = None,
     ) -> Optional[Any]:
         """The loaded executable for ``program_key``, or ``None`` (miss /
         stale / invalid — the caller JIT-compiles either way).
+
+        ``devices``: the devices the program was compiled for, in its
+        device-assignment order (one device for a replicated scoring
+        program, the mesh's for a sharded one). jax's loader otherwise
+        assumes EVERY device of the backend and reloads a one-device
+        program as an N-shard one that no call can satisfy.
 
         ``probe``: optional callable run with the loaded executable before
         it is adopted (the engine dispatches a zeros batch through it) — a
@@ -145,7 +154,7 @@ class CompileCacheStore:
                 )
                 self._count(kind, "stale")
                 return None
-            loaded = self._load_entry(path)
+            loaded = self._load_entry(path, devices)
             if probe is not None:
                 probe(loaded)
         except Exception as exc:
@@ -160,14 +169,16 @@ class CompileCacheStore:
         return loaded
 
     @staticmethod
-    def _load_entry(path: str):
+    def _load_entry(path: str, devices: Sequence[Any]):
         from jax.experimental.serialize_executable import deserialize_and_load
 
         with open(os.path.join(path, EXEC_FILE), "rb") as fh:
             payload = fh.read()
         with open(os.path.join(path, TREES_FILE), "rb") as fh:
             in_tree, out_tree = pickle.load(fh)
-        return deserialize_and_load(payload, in_tree, out_tree)
+        return deserialize_and_load(
+            payload, in_tree, out_tree, execution_devices=list(devices)
+        )
 
     # -- write-back ----------------------------------------------------------
     def put(
@@ -233,6 +244,13 @@ class CompileCacheStore:
         self.counters["write"] += 1
         _M_WRITES.labels("ok").inc()
         return True
+
+    def count_compile_failure(self) -> None:
+        """The caller's AOT compile for this store failed and it fell back
+        to lazy JIT: nothing was written, and the program recompiles every
+        boot — the same degradation as a failed write, counted as one."""
+        self.counters["write_error"] += 1
+        _M_WRITES.labels("compile_error").inc()
 
     # -- maintenance (the `gordo cache` verbs) -------------------------------
     def entries(self) -> List[Dict[str, Any]]:
@@ -326,15 +344,23 @@ def resolve_store(
     """The ONE resolution rule for where the serving compile cache lives,
     shared by the server, the CLI, and the builder export so they can
     never warm different roots: explicit path beats the
-    ``GORDO_COMPILE_CACHE_STORE`` env var beats the models-root default
-    (``<models_root>/.compile-cache`` — hidden, so the model scan rule
-    never mistakes it for a machine). ``"off"`` at any level disables;
-    no path resolvable → ``None`` (cache off, today's compile-on-boot)."""
+    ``GORDO_COMPILE_CACHE_STORE`` env var beats the default. The default
+    (only with a ``models_root``, i.e. a models-tree server or build)
+    follows JAX's own cache placement: ``$JAX_COMPILATION_CACHE_DIR/
+    serving-aot`` when the operator placed that cache, else
+    ``<models_root>/.compile-cache`` (hidden, so the model scan rule never
+    mistakes it for a machine). ``"off"`` at any level disables; no path
+    resolvable → ``None`` (cache off, compile-on-boot)."""
     root = explicit
     if root is None:
         root = os.environ.get(STORE_ENV) or None
     if root is None and models_root:
-        root = os.path.join(models_root, ".compile-cache")
+        placed = os.environ.get(CACHE_DIR_ENV)
+        root = (
+            os.path.join(placed, "serving-aot")
+            if placed
+            else os.path.join(models_root, ".compile-cache")
+        )
     if not root or root == "off":
         return None
     return CompileCacheStore(root)

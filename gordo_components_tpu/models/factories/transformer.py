@@ -1,7 +1,7 @@
 """PatchTST transformer factory — the rebuild's new model kind.
 
 No reference counterpart (the reference zoo stops at LSTM); this covers
-BASELINE.md config 5 ("Transformer/PatchTST anomaly head on a 10k-tag
+BASELINE.json ``configs`` entry 5 ("Transformer/PatchTST anomaly head on a 10k-tag
 plant"). Architecture follows PatchTST (Nie et al., ICLR 2023, public):
 channel-independent patching — each tag's lookback window is split into
 patches, embedded, and run through a shared transformer encoder; a linear

@@ -376,7 +376,7 @@ class LSTMAutoEncoder(BaseFlaxEstimator):
 class LSTMForecast(BaseFlaxEstimator):
     """Window → the ``horizon``-th-ahead row (reference:
     ``KerasLSTMForecast`` is the ``horizon=1`` case; ``horizon=k`` is the
-    direct multi-step forecast of BASELINE.md config 3). ``predict`` row
+    direct multi-step forecast of BASELINE.json ``configs`` entry 3). ``predict`` row
     ``j`` corresponds to input row ``j + lookback_window - 1 + horizon``."""
 
     lookahead = 1
@@ -448,7 +448,7 @@ class MultiStepForecast(LSTMForecast):
 
 class PatchTSTAutoEncoder(LSTMAutoEncoder):
     """Window → window's own last row via the PatchTST transformer kind —
-    the rebuild's new model family (BASELINE.md config 5); same windowing
+    the rebuild's new model family (BASELINE.json ``configs`` entry 5); same windowing
     contract as :class:`LSTMAutoEncoder`."""
 
     def __init__(self, kind: str = "patchtst", **kwargs: Any):

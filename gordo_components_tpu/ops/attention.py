@@ -2,7 +2,7 @@
 
 The reference has no attention anywhere (its models are MLP/LSTM
 autoencoders — SURVEY.md §6.7), but the rebuild's Transformer/PatchTST
-model kind (BASELINE.md config 5) needs it, and long lookback windows on
+model kind (BASELINE.json ``configs`` entry 5) needs it, and long lookback windows on
 10k-tag plants motivate sequence sharding.
 
 ``ring_attention`` is the ICI-native long-context path: Q stays sharded

@@ -23,8 +23,8 @@ Exactness and autodiff:
   standard flash backward recurrence — so gradients are exact and peak
   memory stays O(S x block_k), never O(S²).
 
-Off-TPU the kernel runs in Pallas interpret mode, so CPU tests execute
-the same code path the TPU lowers.
+On the CPU the kernel runs in Pallas interpret mode, so CPU tests execute
+the same kernel body the TPU lowers.
 
 Scope: non-causal self-attention (the PatchTST encoder is bidirectional;
 nothing in the zoo is autoregressive). Attention-weight dropout is not
@@ -36,7 +36,6 @@ from __future__ import annotations
 
 import functools
 import math
-import warnings
 from typing import Optional
 
 import jax
@@ -44,30 +43,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_warned_interpret_on_accelerator = False
-
 
 def _interpret_mode() -> bool:
-    """Whether to run the Pallas kernel in interpret mode (everywhere but
-    TPU). On CPU that is the intended test path; on a non-TPU *accelerator*
-    (e.g. GPU) interpret mode is orders of magnitude slower than
-    ``dense_attention``, so warn once rather than silently crawl (ADVICE
-    r2) — callers who see the warning should use ``attention_impl='dense'``
-    off-TPU."""
-    global _warned_interpret_on_accelerator
+    """Whether to run the Pallas kernel in interpret mode: on the CPU only,
+    where it is the test path. The kernel is written for Mosaic, so any
+    other non-TPU backend raises instead of crawling through the
+    interpreter — use ``attention_impl='dense'`` there."""
     backend = jax.default_backend()
     if backend == "tpu":
         return False
-    if backend != "cpu" and not _warned_interpret_on_accelerator:
-        _warned_interpret_on_accelerator = True
-        warnings.warn(
-            f"flash_attention: Pallas TPU kernel running in INTERPRET mode "
-            f"on the {backend!r} backend — this is far slower than "
-            "attention_impl='dense'; flash is TPU-only",
-            RuntimeWarning,
-            stacklevel=3,
-        )
-    return True
+    if backend == "cpu":
+        return True
+    raise NotImplementedError(
+        f"flash_attention is a Pallas TPU kernel; the {backend!r} backend "
+        "can only interpret it — use attention_impl='dense'"
+    )
+
 
 # finite stand-in for -inf in the masked-score/online-max recurrence:
 # genuine -inf turns the first block's ``exp(s - m)`` into exp(-inf + inf)
@@ -296,9 +287,8 @@ def flash_attention(
     ``(lcm(block_q, block_k), 128)`` regardless of true size, so a
     many-machine short-window config (e.g. PatchTST at plant scale: 7
     patches x 16-wide heads over batch x tags x heads = 640k rows)
-    materializes ~146x its real footprint — measured as a 21 GB HBM
-    request vs 16 GiB on v5e, a guaranteed compile-time OOM
-    (docs/measurements/bench_tpu_r4_run1.json, round 4). Dense attention
+    materializes ~146x its real footprint: ``bf16[640000,128,128]`` is
+    21 GB against 16 GiB of v5e HBM, a compile-time OOM. Dense attention
     at those shapes keeps the score matrix trivially small. The crossover
     rule is structural (single-tile => dense), not a tuned threshold.
     """

@@ -28,25 +28,6 @@ from .mesh import FLEET_AXIS
 logger = logging.getLogger(__name__)
 
 
-def _already_initialized() -> bool:
-    """``jax.distributed.is_initialized`` appeared after 0.4.x; on older
-    runtimes probe the private singleton instead (conservatively False if
-    even that moved — ``initialize`` then raising is the caller's clear
-    signal, rather than silently skipping a required rendezvous)."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src.distributed import global_state
-
-        return global_state.client is not None
-    except (ImportError, AttributeError):
-        # private module moved too: assume not initialized — a double
-        # initialize then raises loudly rather than silently skipping a
-        # required rendezvous
-        return False
-
-
 def initialize_multihost(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -62,7 +43,7 @@ def initialize_multihost(
     ``jax.devices()``/``process_count()`` first — that would pin a
     single-process runtime).
     """
-    if _already_initialized():
+    if jax.distributed.is_initialized():
         logger.info("jax.distributed already initialized")
         return
     explicit = coordinator_address is not None

@@ -559,37 +559,32 @@ def fleet_program(
     n_features: int,
     n_targets: int,
     mesh=None,
-    donate: bool = False,
 ):
     """The jitted vmap-over-machines program for one bucket shape, cached so
     repeated calls with the same spec/shape reuse the traced+compiled
     executable (``jax.jit`` keys on function identity — without this cache
     every ``train_fleet_arrays`` call would re-trace).
 
-    ``donate=True`` donates the batch buffers to the executable: XLA may
-    reuse their HBM for intermediates, roughly halving peak memory for
-    plant-scale buckets whose ``(M, N, F)`` data approaches the chip limit.
-    The inputs are consumed — callers must not touch them after the call
-    (the builder's slice loop never does; benchmarks re-execute on the same
-    buffers and must keep the default)."""
+    The batch buffers are NOT donated: no output has a batch buffer's
+    shape, so XLA has nothing to alias them to — on a v5e (PR 21) the
+    donation was reported "not usable" for all four inputs and changed
+    nothing but the warning count."""
 
     def build():
         _M_FLEET_PROGRAMS.labels("jit").inc()
         program = jax.vmap(
             make_machine_program(spec, n_rows, n_features, n_targets)
         )
-        donate_argnums = (0, 1, 2, 3) if donate else ()
         if mesh is None:
-            return jax.jit(program, donate_argnums=donate_argnums)
+            return jax.jit(program)
         shard = fleet_sharding(mesh)
         return jax.jit(
             program,
             in_shardings=(shard, shard, shard, shard),
             out_shardings=shard,
-            donate_argnums=donate_argnums,
         )
 
-    key = (spec, n_rows, n_features, n_targets, mesh, donate)
+    key = (spec, n_rows, n_features, n_targets, mesh)
     return _cached(_PROGRAM_CACHE, _PROGRAM_CACHE_MAX, key, build)
 
 
@@ -613,7 +608,6 @@ def fleet_executable(
     n_features: int,
     n_targets: int,
     mesh=None,
-    donate: bool = False,
 ):
     """AOT-compiled fleet executable + its input formats, cached by
     (spec, shape, mesh).
@@ -626,14 +620,10 @@ def fleet_executable(
     vs 0.7 ms program execution, i.e. the relayout would dominate the
     fleet hot loop ~300×.
 
-    Returns ``(compiled, formats)``; ``formats`` is ``None`` when the
-    backend has no layout API (the call path then falls back to plain
-    ``device_put``).
+    Returns ``(compiled, formats)``.
     """
     def build():
-        program = fleet_program(
-            spec, n_rows, n_features, n_targets, mesh=mesh, donate=donate
-        )
+        program = fleet_program(spec, n_rows, n_features, n_targets, mesh=mesh)
         avatars = (
             jax.ShapeDtypeStruct((n_machines, n_rows, n_features), jnp.float32),
             jax.ShapeDtypeStruct((n_machines, n_rows, n_targets), jnp.float32),
@@ -646,13 +636,9 @@ def fleet_executable(
         _M_FLEET_COMPILE_SECONDS.observe(
             time.perf_counter() - compile_started
         )
-        try:
-            formats = compiled.input_formats[0]
-        except (AttributeError, TypeError, IndexError):
-            formats = None
-        return compiled, formats
+        return compiled, compiled.input_formats[0]
 
-    key = (spec, n_machines, n_rows, n_features, n_targets, mesh, donate)
+    key = (spec, n_machines, n_rows, n_features, n_targets, mesh)
     return _cached(_EXEC_CACHE, _EXEC_CACHE_MAX, key, build)
 
 
@@ -663,14 +649,13 @@ def peek_fleet_executable(
     n_features: int,
     n_targets: int,
     mesh=None,
-    donate: bool = False,
 ):
     """The cached ``(compiled, formats)`` for this shape, or ``None`` —
     NEVER compiles. For the ingest prefetcher: it places the next slice's
     batch layout-matched only when the program already exists, because a
     worker-side compile would race the unlocked program cache with the
     main thread and contend the (single) device compile slot."""
-    key = (spec, n_machines, n_rows, n_features, n_targets, mesh, donate)
+    key = (spec, n_machines, n_rows, n_features, n_targets, mesh)
     try:
         return _EXEC_CACHE.get(key)
     except TypeError:
@@ -703,14 +688,9 @@ def put_fleet_batch(batch: MachineBatch, formats=None) -> MachineBatch:
 
 def compiled_flops(compiled) -> Optional[float]:
     """XLA-reported flops of a compiled executable, or ``None`` on backends
-    without cost analysis. The one place that knows ``cost_analysis()``
-    sometimes returns a list (its shape has changed across JAX versions) —
-    bench.py and the accounting below share it instead of re-guessing."""
+    without cost analysis — bench.py and the accounting below share it."""
     try:
-        analysis = compiled.cost_analysis()
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0]
-        return float(analysis["flops"])
+        return float(compiled.cost_analysis()["flops"])
     except Exception:  # lint: allow-swallow(XLA cost introspection is optional; None is the documented unknown result)
         return None
 
@@ -842,20 +822,10 @@ def fleet_flops_accounting(
     }
 
 
-def backend_supports_donation(mesh=None) -> bool:
-    """Whether the target backend honors ``donate_argnums``. XLA:CPU does
-    not — donated buffers are silently copied and every execution emits a
-    ``Some donated buffers were not usable`` warning, drowning real signal
-    in a full test run (VERDICT r3 #8) — so callers gate donation here."""
-    device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
-    return device.platform != "cpu"
-
-
 def train_fleet_arrays(
     spec: FleetSpec,
     batch: MachineBatch,
     mesh=None,
-    donate: bool = False,
 ) -> MachineResult:
     """Train a stacked bucket of machines; returns stacked results.
 
@@ -867,14 +837,7 @@ def train_fleet_arrays(
     Host arrays are device-placed layout-matched via the AOT executable
     (:func:`fleet_executable`); keys uint32 dtype aside, any float inputs
     are accepted as-is.
-
-    ``donate=True`` lets XLA reuse the device-placed batch's HBM for
-    intermediates (the placed copies are consumed; the caller's host
-    arrays are untouched) — the peak-memory lever for plant-scale buckets;
-    see :func:`fleet_program`. Ignored on backends without donation
-    support (:func:`backend_supports_donation`).
     """
-    donate = donate and backend_supports_donation(mesh)
     n_machines, n_rows, n_features = batch.X.shape
     n_targets = batch.y.shape[2]
     if mesh is not None and n_machines % mesh.size != 0:
@@ -884,8 +847,7 @@ def train_fleet_arrays(
             "(build_fleet does this automatically)"
         )
     compiled, formats = fleet_executable(
-        spec, n_machines, n_rows, n_features, n_targets, mesh=mesh,
-        donate=donate,
+        spec, n_machines, n_rows, n_features, n_targets, mesh=mesh
     )
     placed = put_fleet_batch(batch, formats)
     return compiled(placed.X, placed.y, placed.w, placed.keys)

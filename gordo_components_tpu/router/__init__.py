@@ -25,6 +25,7 @@ from .workers import (
     WorkerSpec,
     WorkerSupervisor,
     server_worker_argv,
+    worker_platform_env,
     worker_specs,
 )
 
@@ -196,7 +197,13 @@ def run_fleet_server(
     shard ``i mod mesh_shards`` (only its owned machines stack eagerly;
     the rest serve through the spill fallback rung), and the router's
     placement walks owner-shard workers first — one knob drives both
-    sides of the layout, so they can never disagree."""
+    sides of the layout, so they can never disagree.
+
+    More than one worker is a CPU or multi-host topology: a chip belongs to
+    one process, so N workers on one chip host cannot all hold it (ROADMAP
+    R9 defines one-chip replicas in one process). See
+    :func:`.workers.worker_platform_env` for how a worker that cannot get
+    the device is made to fail instead of serving from the CPU."""
     import signal
     import threading
 
@@ -219,6 +226,7 @@ def run_fleet_server(
             server_worker_argv(
                 spec, models_dir, project=project, extra=extra
             ),
+            env=worker_platform_env(),
         )
 
     app = assemble_fleet(

@@ -93,6 +93,26 @@ def server_worker_argv(
     ]
 
 
+def worker_platform_env() -> Dict[str, str]:
+    """Extra environment that pins a spawned worker's JAX platform.
+
+    A chip belongs to one process, and a worker that cannot get it would
+    serve from the CPU without saying so (JAX only logs the libtpu
+    failure). So when the operator named no platform and this
+    installation carries the TPU runtime, workers are started with
+    ``JAX_PLATFORMS=tpu``: one that cannot get the device dies at boot
+    instead. The router itself never initialises JAX. An operator's
+    ``JAX_PLATFORMS`` (``cpu`` for a CPU tier) is inherited unchanged."""
+    import importlib.util
+
+    if (
+        os.environ.get("JAX_PLATFORMS")
+        or importlib.util.find_spec("libtpu") is None
+    ):
+        return {}
+    return {"JAX_PLATFORMS": "tpu"}
+
+
 class SubprocessWorker:
     """One worker process. ``terminate()`` is the GRACEFUL path: SIGTERM
     (the server drains in-flight requests and quiesces its engine before

@@ -255,9 +255,9 @@ def _make_machine_score(lookback: int, lookahead, apply_fn, precision: str):
 
 def _supports_donation(mesh) -> bool:
     """Whether scoring dispatches may donate their input buffers (XLA:CPU
-    silently copies donated buffers and warns per execution — see
-    parallel.fleet.backend_supports_donation, deliberately not imported at
-    module scope: the engine must not drag the training stack in)."""
+    silently copies donated buffers and warns per execution). A request
+    stack has the shape of the scores that come back, so XLA can alias it:
+    on a v5e (PR 21) these donations compiled without a warning."""
     device = mesh.devices.flat[0] if mesh is not None else jax.devices()[0]
     return device.platform != "cpu"
 
@@ -1263,7 +1263,14 @@ class _Bucket:
             with self._dispatch_lock or contextlib.nullcontext():
                 jax.block_until_ready(loaded(*probe_args()))  # lint: allow-blocking(one-time vet of a deserialized executable; it must complete under the collective-launch lock before adoption, and runs only on boot/reload paths)
 
-        loaded = self._compile_cache.get(ckey, probe=probe)
+        # only the cold program of a sharded bucket spans the mesh; every
+        # other scoring program compiles for the default device alone
+        devices = (
+            list(self.mesh.devices.flat)
+            if kind == "cold" and self.mesh is not None
+            else jax.devices()[:1]
+        )
+        loaded = self._compile_cache.get(ckey, devices, probe=probe)
         if loaded is not None:
             spans.event(
                 "compile_cache", outcome="hit", kind=kind, rows=rows, batch=k
@@ -1278,11 +1285,13 @@ class _Bucket:
         except Exception:
             # an avatar/lowering bug must not take scoring down with it:
             # fall back to the lazy-jit contract (first dispatch compiles,
-            # _fresh_programs accounts it) and skip the write-back
+            # _fresh_programs accounts it) and skip the write-back —
+            # counted as a failed write so the fallback is visible
             logger.exception(
                 "AOT compile for the persistent cache failed (kind=%s "
                 "rows=%d k=%d); serving via lazy JIT", kind, rows, k,
             )
+            self._compile_cache.count_compile_failure()
             self._fresh_programs.add(
                 (rows, k) if kind == "cold" else (kind, rows, k)
             )
@@ -2716,13 +2725,22 @@ class ServingEngine:
             need = bucket.lookback + (bucket.lookahead or 0)
             n = max(rows or 0, need, 1)
             first = bucket.names[0]
-            self.anomaly(first, np.zeros((n, bucket.n_features), np.float32))
-            rows_padded = _round_up_pow2(n, self.min_rows_bucket)
-            bucket.warmup_hot(rows_padded)
-            # megabatch: a no-op when the live request above already
-            # compiled+ran the fused program (full residency), the
-            # first-promotion compile pre-payment otherwise
-            bucket.warmup_mega(rows_padded)
+            try:
+                self.anomaly(
+                    first, np.zeros((n, bucket.n_features), np.float32)
+                )
+                rows_padded = _round_up_pow2(n, self.min_rows_bucket)
+                bucket.warmup_hot(rows_padded)
+                # megabatch: a no-op when the live request above already
+                # compiled+ran the fused program (full residency), the
+                # first-promotion compile pre-payment otherwise
+                bucket.warmup_mega(rows_padded)
+            except Exception as exc:
+                raise RuntimeError(
+                    f"warm-up failed for bucket {bucket.shape_key} "
+                    f"({bucket.precision}, {len(bucket.names)} machine(s), "
+                    f"first {first!r}) at {n} row(s)"
+                ) from exc
         return len(self._buckets)
 
     def close(self) -> None:

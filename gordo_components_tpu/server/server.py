@@ -102,6 +102,12 @@ _M_WIRE_FORMAT = REGISTRY.counter(
     "fallback) — shows whether clients actually adopt the binary plane",
     labels=("format",),
 )
+_M_WARMUPS = REGISTRY.counter(
+    "gordo_server_warmups_total",
+    "Boot-time engine warm-ups by outcome (ok / error): an error boot "
+    "still serves, and its first requests pay the compiles",
+    labels=("outcome",),
+)
 
 _URL_MAP = Map(
     [
@@ -451,11 +457,12 @@ class ModelServer:
 
         ``compile_cache_store``: path of the persistent compile-cache
         root (AOT-serialized scoring executables; ``"off"`` disables).
-        Default: the ``GORDO_COMPILE_CACHE_STORE`` env var, else
-        ``<models_root>/.compile-cache`` when a models_root is given —
-        the same root a fleet build exports into, so first boot is
-        already warm. Single-dir servers without the env var run with
-        the cache off (nothing anchors a sensible root).
+        Default: the ``GORDO_COMPILE_CACHE_STORE`` env var, else — when
+        a models_root is given — ``$JAX_COMPILATION_CACHE_DIR/serving-aot``
+        or ``<models_root>/.compile-cache`` (``compile_cache.
+        resolve_store``): the same root a fleet build exports into, so
+        first boot is already warm. Single-dir servers without the env
+        var run with the cache off (nothing anchors a sensible root).
 
         ``worker_id``: this process's slot in a horizontal fleet (see
         ``router/``). Default: the ``GORDO_WORKER_ID`` env var, else
@@ -1042,7 +1049,7 @@ class ModelServer:
         try:
             state.engine.warmup()
         except Exception:  # warm-up is best-effort; scoring still compiles
-            logger.warning("Post-reload engine warm-up failed", exc_info=True)
+            logger.error("Post-reload engine warm-up failed", exc_info=True)
 
     # -- multi-host mesh serving (§23) ----------------------------------------
     def _mesh_tuple(self) -> Optional[Tuple[int, int]]:
@@ -2335,6 +2342,15 @@ def run_server(
 
     from ..utils.profiling import device_trace
 
+    import jax
+
+    devices = jax.devices()
+    # said once, at boot: JAX falls back to the CPU without failing when
+    # it cannot get the chip, and a 200 does not say which device scored
+    logger.info(
+        "Serving on platform %s (%s), %d device(s)",
+        devices[0].platform, devices[0].device_kind, len(devices),
+    )
     app = build_app(
         model_dirs, project=project, models_root=models_root,
         shard_fleet=shard_fleet, max_inflight=max_inflight,
@@ -2344,15 +2360,17 @@ def run_server(
     # warm each bucket's scoring program BEFORE accepting traffic: the
     # first request must pay dispatch (ms), not XLA compile (tens of s).
     # Against a warmed compile-cache store this is load-not-compile —
-    # zero fresh XLA compiles at boot. Best-effort — one broken bucket
-    # must not keep the healthy machines from serving (its own requests
-    # will surface the error)
+    # zero fresh XLA compiles at boot. Not fatal for a deployment — one
+    # broken bucket must not keep the healthy machines from serving —
+    # but an error (the engine names the bucket), and counted
     try:
         with device_trace(trace_dir):
             warmed = app.engine.warmup()
     except Exception:
-        logger.warning("Serving engine warm-up failed", exc_info=True)
+        _M_WARMUPS.labels("error").inc()
+        logger.error("Serving engine warm-up failed", exc_info=True)
     else:
+        _M_WARMUPS.labels("ok").inc()
         if warmed:
             cache = app.compile_cache
             logger.info(

@@ -653,39 +653,101 @@ def test_four_process_ring_attention_parity():
         ), pid
 
 
-# ------------------------------------------------------------ backend probe
-def test_call_with_timeout_paths():
-    import time as _time
+# ------------------------------------------------- backend start-up rules
+def test_require_accelerator_accepts_requested_cpu(monkeypatch):
+    from gordo_components_tpu.utils.backend import require_accelerator
 
-    from gordo_components_tpu.utils.backend import call_with_timeout
-
-    assert call_with_timeout(lambda: 7, 5.0) == ("ok", 7)
-    status, exc = call_with_timeout(
-        lambda: (_ for _ in ()).throw(RuntimeError("boom")), 5.0
-    )
-    assert status == "error" and isinstance(exc, RuntimeError)
-    assert call_with_timeout(lambda: _time.sleep(20), 0.2) == ("timeout", None)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert require_accelerator("test-script").platform == "cpu"
 
 
-def test_require_live_backend_passes_on_live_cpu():
-    from gordo_components_tpu.utils.backend import require_live_backend
+def test_require_accelerator_refuses_unrequested_cpu(monkeypatch, capsys):
+    """JAX falls back to the CPU without failing when it finds no chip; an
+    entry point that did not ask for the CPU must exit non-zero and name
+    the platform it got."""
+    from gordo_components_tpu.utils.backend import require_accelerator
 
-    require_live_backend("test-script")  # CPU backend is live -> returns
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit) as exc:
+        require_accelerator("test-script")
+    assert exc.value.code == 3
+    err = capsys.readouterr().err
+    assert "test-script" in err and "cpu" in err and "JAX_PLATFORMS" in err
 
 
-def test_enable_persistent_compile_cache_respects_existing_dir():
-    """The bench/entry cache helper must never override a cache dir the
-    operator (or tests/conftest.py, as here) already pinned — and must
-    report the dir actually in effect."""
+@pytest.fixture
+def config_updates(monkeypatch):
+    """Every ``jax.config.update(name, value)`` the code under test makes,
+    recorded instead of applied."""
     import jax as _jax
 
+    updates = []
+    monkeypatch.setattr(
+        _jax.config, "update", lambda name, value: updates.append((name, value))
+    )
+    monkeypatch.delenv("GORDO_COMPILE_CACHE", raising=False)
+    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                       raising=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    return updates
+
+
+def test_compile_cache_placed_by_operator_is_left_alone(
+    config_updates, monkeypatch, tmp_path
+):
     from gordo_components_tpu.utils.backend import (
         enable_persistent_compile_cache,
     )
 
-    if os.environ.get("GORDO_TEST_NO_COMPILE_CACHE", "0") == "1":
-        pytest.skip("cacheless diagnostic run: conftest pinned no dir")
-    before = _jax.config.jax_compilation_cache_dir
-    assert before  # conftest pinned tests/.jax_compilation_cache
-    assert enable_persistent_compile_cache() == before
-    assert _jax.config.jax_compilation_cache_dir == before
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_persistent_compile_cache() == str(tmp_path)
+    assert config_updates == []
+
+
+def test_compile_cache_defaults_to_fixed_checkout_path(
+    config_updates, monkeypatch
+):
+    from gordo_components_tpu.utils.backend import (
+        enable_persistent_compile_cache,
+    )
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    expected = os.path.join(repo_root, ".jax_compilation_cache")
+    assert enable_persistent_compile_cache() == expected
+    assert config_updates == [("jax_compilation_cache_dir", expected)]
+
+
+def test_compile_cache_off_leaves_operator_variable(
+    config_updates, monkeypatch, tmp_path
+):
+    from gordo_components_tpu.utils.backend import (
+        enable_persistent_compile_cache,
+    )
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setenv("GORDO_COMPILE_CACHE", "off")
+    assert enable_persistent_compile_cache() == ""
+    assert config_updates == [("jax_compilation_cache_dir", None)]
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(tmp_path)
+
+
+def test_compile_cache_keeps_every_program_off_the_cpu(
+    config_updates, monkeypatch, tmp_path
+):
+    """On the chip most programs compile in under JAX's one-second bar and
+    would never be stored; unless the CPU was asked for (or the operator
+    set the bar), the helper keeps them all — and still sets no dir."""
+    from gordo_components_tpu.utils.backend import (
+        enable_persistent_compile_cache,
+    )
+
+    keep_all = ("jax_persistent_cache_min_compile_time_secs", 0.0)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.delenv("JAX_PLATFORMS")
+    enable_persistent_compile_cache()
+    assert config_updates == [keep_all]
+    config_updates.clear()
+    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "5")
+    enable_persistent_compile_cache()
+    assert config_updates == []

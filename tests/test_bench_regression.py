@@ -53,7 +53,6 @@ def _measure_exec_s(tmp_path) -> float:
         env={
             "PATH": "/usr/bin:/bin",
             "HOME": str(tmp_path),
-            "BENCH_CPU": "1",
             "BENCH_CONFIGS": "dense_ae_10tag",
             "BENCH_NO_SERVING": "1",
             "JAX_PLATFORMS": "cpu",

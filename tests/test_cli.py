@@ -209,58 +209,56 @@ def _jax_cache_dir():
     return _jax.config.jax_compilation_cache_dir or ""
 
 
-def test_cli_build_commands_enable_compile_cache(runner, tmp_path, monkeypatch):
-    """build/fleet-build persist the XLA compilation cache (resume must not
-    re-pay bucket compiles): default <output-dir>/.jax_compilation_cache,
-    --compile-cache-dir overrides, 'off' disables. Pinned by recording the
-    helper call — the commands are invoked with a bad config so the test
-    exercises only the cache wiring (which runs first), not a full build."""
+def test_cli_compiling_commands_follow_the_one_cache_rule(
+    runner, tmp_path, monkeypatch
+):
+    """build / fleet-build / run-server all call the ONE cache helper
+    before their first compile, and with JAX_COMPILATION_CACHE_DIR placed
+    by the operator none of them sets a cache dir of its own (the old
+    <output-dir>/.jax_compilation_cache default never hit from a fresh
+    output dir). The commands get bad inputs so only the wiring runs."""
+    import jax as _jax
+
     from gordo_components_tpu.utils import backend as backend_mod
 
-    calls = []
+    helper_calls = []
+    real_helper = backend_mod.enable_persistent_compile_cache
     monkeypatch.setattr(
         backend_mod,
         "enable_persistent_compile_cache",
-        lambda cache_dir=None: calls.append(cache_dir) or str(cache_dir),
+        lambda: helper_calls.append(1) or real_helper(),
     )
-    # a cacheless diagnostic run (conftest's GORDO_TEST_NO_COMPILE_CACHE
-    # branch) exports GORDO_COMPILE_CACHE=off, which would short-circuit
-    # the default-derivation this test pins
+    cache_dir_updates = []
+    real_update = _jax.config.update
+
+    def spy(name, value):
+        if name == "jax_compilation_cache_dir":
+            cache_dir_updates.append(value)
+        else:
+            real_update(name, value)
+
+    monkeypatch.setattr(_jax.config, "update", spy)
     monkeypatch.delenv("GORDO_COMPILE_CACHE", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
     out = str(tmp_path / "models")
-    bad = ["--machine-config", "{not valid", "--output-dir", out]
-    assert runner.invoke(gordo, ["fleet-build", *bad]).exit_code != 0
-    assert calls == [os.path.join(out, ".jax_compilation_cache")]
-    calls.clear()
-    custom = str(tmp_path / "cache")
-    assert (
-        runner.invoke(
-            gordo, ["fleet-build", *bad, "--compile-cache-dir", custom]
-        ).exit_code
-        != 0
+    commands = [
+        ["fleet-build", "--machine-config", "{not valid",
+         "--output-dir", out],
+        ["build", "m1", "--model-config", "{not valid",
+         "--data-config", "{}", "--output-dir", out],
+        ["run-server", "--model-dir", str(tmp_path / "no-such-model")],
+    ]
+    for argv in commands:
+        helper_calls.clear()
+        assert runner.invoke(gordo, argv).exit_code != 0, argv
+        assert helper_calls == [1], argv
+    assert cache_dir_updates == []
+    assert not os.path.exists(os.path.join(out, ".jax_compilation_cache"))
+    # --compile-cache-dir (the directory form of the knob) is gone
+    result = runner.invoke(
+        gordo, ["fleet-build", *commands[0][1:], "--compile-cache-dir", "x"]
     )
-    assert calls == [custom]
-    calls.clear()
-    assert (
-        runner.invoke(
-            gordo, ["fleet-build", *bad, "--compile-cache-dir", "off"]
-        ).exit_code
-        != 0
-    )
-    # "off" is passed THROUGH to the helper (which disables and clears any
-    # env-sourced active config), not swallowed CLI-side
-    assert calls == ["off"]
-    calls.clear()
-    # the single-machine build command wires the same helper
-    assert (
-        runner.invoke(
-            gordo,
-            ["build", "m1", "--model-config", "{not valid",
-             "--data-config", "{}", "--output-dir", out],
-        ).exit_code
-        != 0
-    )
-    assert calls == [os.path.join(out, ".jax_compilation_cache")]
+    assert result.exit_code == 2 and "No such option" in result.output
 
 
 @pytest.mark.slow

@@ -99,9 +99,18 @@ def test_entry_name_is_stable_and_key_sensitive():
 
 def test_resolve_store_precedence(tmp_path, monkeypatch):
     monkeypatch.delenv(STORE_ENV, raising=False)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     assert resolve_store() is None
     assert resolve_store(models_root=str(tmp_path)).root == str(
         tmp_path / ".compile-cache"
+    )
+    # the default follows the operator's placement of JAX's own cache (a
+    # models tree built into a fresh directory must still find the store);
+    # without a models tree nothing anchors a store either way
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
+    assert resolve_store() is None
+    assert resolve_store(models_root=str(tmp_path)).root == str(
+        tmp_path / "placed" / "serving-aot"
     )
     monkeypatch.setenv(STORE_ENV, str(tmp_path / "env-root"))
     assert resolve_store(models_root=str(tmp_path)).root == str(
@@ -197,6 +206,32 @@ def test_key_mismatch_reads_stale(fitted_models, tmp_path):
     engine.warmup()
     assert store2.counters["stale"] > 0
     engine.close()
+
+
+def test_one_device_program_reloads_as_one_device_program(tmp_path):
+    """On the 8-virtual-device platform jax's loader defaults to EVERY
+    backend device and reloads a one-device executable as an 8-shard one
+    no call can satisfy; the store loads it for the devices it was
+    compiled for."""
+    import jax
+    import jax.numpy as jnp
+
+    assert len(jax.devices()) == 8
+    compiled = (
+        jax.jit(lambda x: x * 2.0)
+        .lower(jax.ShapeDtypeStruct((4,), jnp.float32))
+        .compile()
+    )
+    store = CompileCacheStore(str(tmp_path / "cc"))
+    key = {"kind": "serving-cold", "probe": "one-device"}
+    assert store.put(key, compiled)
+    loaded = store.get(key, jax.devices()[:1])
+    assert loaded is not None and store.counters["hit"] == 1
+    (arg_sharding,), _ = loaded.input_shardings
+    assert arg_sharding.device_set == {jax.devices()[0]}
+    np.testing.assert_array_equal(
+        np.asarray(loaded(np.ones((4,), np.float32))), np.full((4,), 2.0)
+    )
 
 
 def test_put_never_raises_on_unserializable():

@@ -53,3 +53,69 @@ def test_dryrun_multichip_subprocess_self_provisions():
     (the exact path the single-TPU driver host exercises)."""
     n = jax.device_count() * 2
     graft_entry.dryrun_multichip(n)
+
+
+# -- chip_smoke.py ----------------------------------------------------------
+_REPO_ROOT = Path(__file__).resolve().parent.parent
+
+
+def _chip_smoke(argv, cwd, timeout):
+    import os
+    import subprocess
+
+    env = dict(os.environ)  # conftest's JAX_PLATFORMS=cpu + 8 devices
+    return subprocess.run(
+        [sys.executable, "chip_smoke.py", *argv],
+        env=env, cwd=str(cwd), capture_output=True, text=True,
+        timeout=timeout,
+    )
+
+
+def test_chip_smoke_demands_the_chip():
+    """No TPU and no --rehearse: non-zero within seconds, the platform
+    named, nothing built and no result line."""
+    import time
+
+    started = time.monotonic()
+    proc = _chip_smoke([], _REPO_ROOT, timeout=120)
+    assert proc.returncode == 2, proc.stderr[-2000:]
+    assert time.monotonic() - started < 60
+    assert "JAX found no TPU" in proc.stderr and "cpu:cpu" in proc.stderr
+    assert proc.stdout.strip() == ""
+    assert "stage fleet_build" not in proc.stderr
+
+
+def test_chip_smoke_alone_is_not_the_program(tmp_path):
+    """In a directory that holds chip_smoke.py and nothing else of the
+    repo the script cannot pass, even past the device check."""
+    import shutil
+
+    shutil.copy(_REPO_ROOT / "chip_smoke.py", tmp_path / "chip_smoke.py")
+    proc = _chip_smoke(["--rehearse"], tmp_path, timeout=120)
+    assert proc.returncode == 2, proc.stderr[-2000:]
+    assert "not beside this script" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
+@pytest.mark.slow
+def test_chip_smoke_rehearsal_end_to_end():
+    """The same stages at a tiny size on the CPU (8 virtual devices: the
+    fleet trains sharded over all of them)."""
+    import json
+
+    proc = _chip_smoke(["--rehearse"], _REPO_ROOT, timeout=900)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    summary = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert summary["ok"] is True and summary["rehearsal"] is True
+    assert summary["device"] == {
+        "platform": "cpu", "kind": "cpu", "count": jax.device_count(),
+    }
+    assert set(summary["stages"]) == {
+        "device", "fleet_build", "serve", "safety_nets", "second_boot",
+        "kernel",
+    }
+    assert all(stage["ok"] for stage in summary["stages"].values())
+    assert summary["stages"]["fleet_build"]["trained_on"]["count"] == (
+        jax.device_count()
+    )
+    assert summary["stages"]["second_boot"]["aot_store"]["hit"] > 0

@@ -216,32 +216,35 @@ def test_fleet_on_mesh_sharded():
     )
 
 
-def test_fleet_donation_gated_and_silent_on_cpu():
-    """On CPU donation is unsupported, so the gate in train_fleet_arrays
-    must drop it silently — zero 'donated buffers' warnings in a full run
-    (VERDICT r3 #8)."""
+def test_fleet_program_has_nothing_to_donate():
+    """The batch buffers are not donated because XLA could alias them only
+    to an output of the same shape and dtype, and the fleet program has
+    none (on a v5e the donation came back "not usable" for all four
+    inputs). If a result ever grows a batch-shaped leaf, donation is worth
+    another look — and compiling emits no donation warning meanwhile."""
     import warnings
 
-    from gordo_components_tpu.parallel.fleet import backend_supports_donation
+    from gordo_components_tpu.parallel.fleet import fleet_program
 
-    assert backend_supports_donation() is (jax.devices()[0].platform != "cpu")
     spec, batch = _make_spec_and_batch(2)
+    n_rows, n_features = batch.X.shape[1], batch.X.shape[2]
+    program = fleet_program(spec, n_rows, n_features, batch.y.shape[2])
+    args = (batch.X, batch.y, batch.w, np.asarray(batch.keys))
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
-        donated = train_fleet_arrays(spec, batch, donate=True)
-        jax.block_until_ready(donated)
+        out = jax.eval_shape(program, *args)
+        program.lower(*args)
     assert not [w for w in caught if "donated" in str(w.message)]
+    inputs = {(a.shape, a.dtype) for a in args}
+    outputs = {
+        (leaf.shape, leaf.dtype) for leaf in jax.tree_util.tree_leaves(out)
+    }
+    assert not inputs & outputs
 
 
-@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
-def test_fleet_donation_matches_undonated():
-    """A program COMPILED with donate_argnums (the build_fleet path on TPU —
-    XLA may overlay intermediates on the batch's HBM) must be numerically
-    identical to the undonated program. train_fleet_arrays now gates
-    donation off on CPU, so exercise the donated executable directly via
-    fleet_executable — XLA:CPU copies the buffers (the filtered warning)
-    but still runs the donate-compiled program, keeping the parity check
-    meaningful in CI."""
+def test_fleet_executable_matches_train_fleet_arrays():
+    """The AOT executable fed layout-matched arguments by hand (the path
+    bench.py times) is the program train_fleet_arrays runs."""
     from gordo_components_tpu.parallel.fleet import (
         fleet_executable,
         put_fleet_batch,
@@ -251,16 +254,16 @@ def test_fleet_donation_matches_undonated():
     plain = train_fleet_arrays(spec, batch)
     n_rows, n_features = batch.X.shape[1], batch.X.shape[2]
     compiled, formats = fleet_executable(
-        spec, 2, n_rows, n_features, batch.y.shape[2], donate=True
+        spec, 2, n_rows, n_features, batch.y.shape[2]
     )
     placed = put_fleet_batch(batch, formats)
-    donated = compiled(placed.X, placed.y, placed.w, placed.keys)
+    direct = compiled(placed.X, placed.y, placed.w, placed.keys)
     np.testing.assert_allclose(
-        np.asarray(donated.loss_history), np.asarray(plain.loss_history),
+        np.asarray(direct.loss_history), np.asarray(plain.loss_history),
         rtol=1e-5,
     )
     np.testing.assert_allclose(
-        np.asarray(donated.total_threshold), np.asarray(plain.total_threshold),
+        np.asarray(direct.total_threshold), np.asarray(plain.total_threshold),
         rtol=1e-5,
     )
 
@@ -887,7 +890,7 @@ def test_prepare_slice_places_on_device_when_executable_cached():
         }
         for _ in range(2)
     ]
-    place = (spec, None, False)
+    place = (spec, None)
 
     def is_device(a):
         return isinstance(a, jax.Array)

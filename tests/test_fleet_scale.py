@@ -8,8 +8,7 @@ architectures/bucket shapes (dense 3-tag, dense 5-tag with per-machine
 ``evaluation.n_splits`` overrides, LSTM), two row lengths — plus a kill
 mid-build and a resume, measuring what the judge asked for: wall-clock
 machines/hour at scale, resume-after-kill cost, and the no-op
-full-cache-hit resume cost for all 1024 registry keys. Measured numbers
-land in BASELINE.md ("Round-4" table).
+full-cache-hit resume cost for all 1024 registry keys (printed, CPU).
 
 Slow tier: several minutes of real training + ingest on CPU.
 """
@@ -194,6 +193,5 @@ def test_1024_machine_heterogeneous_kill_resume(tmp_path, monkeypatch):
         f"wall-clock incl. kill/resume; no-op resume of all 1024: "
         f"{noop_s:.2f}s"
     )
-    # generous sanity bound only — CI boxes vary; the real numbers go in
-    # BASELINE.md from a recorded run
+    # generous sanity bound only — CI boxes vary
     assert noop_s < total_s

@@ -225,6 +225,25 @@ def test_placement_table_deterministic():
 
 # -- probe jitter ------------------------------------------------------------
 
+def test_worker_platform_env(monkeypatch):
+    """A worker that cannot get the chip would serve from the CPU without
+    saying so; where the TPU runtime is installed and the operator named
+    no platform, workers are pinned to the TPU so they die at boot
+    instead. An operator's choice (cpu for a CPU tier) is inherited."""
+    import importlib.util
+
+    from gordo_components_tpu.router.workers import worker_platform_env
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert worker_platform_env() == {}
+    monkeypatch.delenv("JAX_PLATFORMS")
+    found = {"libtpu": object()}
+    monkeypatch.setattr(importlib.util, "find_spec", found.get)
+    assert worker_platform_env() == {"JAX_PLATFORMS": "tpu"}
+    found.clear()
+    assert worker_platform_env() == {}
+
+
 def test_jittered_interval_bounds():
     """±10% exactly at the extremes, never outside (the thundering-herd
     satellite): injectable rng pins the bounds instead of sampling."""

@@ -490,6 +490,25 @@ def test_engine_warmup_compiles_bucket_programs(fitted_pair):
     assert engine.stats()["compiled_programs"] == before
 
 
+def test_engine_warmup_failure_names_the_bucket(fitted_pair):
+    """run_server logs a failed warm-up and serves on; the error has to say
+    WHICH bucket could not warm, or the log line is useless on a fleet."""
+    from gordo_components_tpu.resilience import faults
+
+    engine = ServingEngine({name: m for name, (m, _) in fitted_pair.items()})
+    faults.configure("engine-dispatch:*:error")
+    try:
+        with pytest.raises(RuntimeError) as exc:
+            engine.warmup()
+    finally:
+        faults.configure("")
+        engine.close()
+    bucket = engine._buckets[0]
+    assert f"warm-up failed for bucket {bucket.shape_key}" in str(exc.value)
+    assert repr(bucket.names[0]) in str(exc.value)
+    assert exc.value.__cause__ is not None
+
+
 @pytest.mark.slow
 def test_mesh_sharded_engine_forecast_and_target_subset_parity(fitted_subset):
     """Capacity mode x the non-reconstruction lifts: a multi-step forecast
