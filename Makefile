@@ -32,7 +32,8 @@ bench-serving:
 	python bench_serving.py
 
 # needs a TPU (exit 2 without one): fleet-build -> store -> run-server ->
-# second boot -> Pallas flash kernel, one process, one JSON summary.
+# second boot -> Pallas flash kernel, one process; stdout ends with a JSON
+# report line and then the {"ok", "device"} result line.
 # `python chip_smoke.py --rehearse` runs the same stages tiny on the CPU
 chip-smoke:
 	python chip_smoke.py

@@ -20,13 +20,18 @@ belongs to one process), through the entry points a user calls:
                the kernel and its padding mask), against dense attention
 
 Weights are random (seed 0) and every input is generated from the seed.
-The last line of stdout is one JSON object; exit 0 only if every stage
-passed. It reports facts (what ran, what compiled, what was cached) — no
-rate, utilization or latency percentile: this is not the benchmark.
+Stdout ends with two JSON lines; exit 0 only if every stage passed:
+
+    {"report": {"rehearsal": ..., "stages": {...}, "compile": {...}, ...}}
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+The last line is the result and has exactly those keys. The report above it
+states facts (what ran, what compiled, what was cached) — no rate,
+utilization or latency percentile: this is not the benchmark.
 
 ``--rehearse`` runs the same stages at a tiny size on whatever backend
 ``JAX_PLATFORMS`` names (Pallas in interpret mode on the CPU) and marks the
-summary ``"rehearsal": true``.
+report ``"rehearsal": true``.
 """
 
 from __future__ import annotations
@@ -603,7 +608,7 @@ def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument(
         "--rehearse", action="store_true",
-        help="tiny sizes, any backend; the summary says rehearsal: true",
+        help="tiny sizes, any backend; the report says rehearsal: true",
     )
     args = parser.parse_args()
     started = time.perf_counter()
@@ -679,9 +684,7 @@ def main() -> int:
         }
     if ok:
         shutil.rmtree(WORK_DIR, ignore_errors=True)
-    summary = {
-        "ok": ok,
-        "device": device,
+    report = {
         "rehearsal": args.rehearse,
         "stages": stages,
         "compile": meter.summary(),
@@ -693,7 +696,11 @@ def main() -> int:
         "peak_bytes_in_use": peaks,
         "wall_seconds": round(time.perf_counter() - started, 1),
     }
-    print(json.dumps(summary), flush=True)
+    # two lines: the report of what ran, then the result. The result is the
+    # LAST line and has exactly these keys — whoever runs the script parses
+    # it strictly, so every other fact belongs in the report above it
+    print(json.dumps({"report": report}), flush=True)
+    print(json.dumps({"ok": ok, "device": device}), flush=True)
     return 0 if ok else 1
 
 

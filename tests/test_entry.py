@@ -105,11 +105,17 @@ def test_chip_smoke_rehearsal_end_to_end():
 
     proc = _chip_smoke(["--rehearse"], _REPO_ROOT, timeout=900)
     assert proc.returncode == 0, proc.stderr[-4000:]
-    summary = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert summary["ok"] is True and summary["rehearsal"] is True
-    assert summary["device"] == {
-        "platform": "cpu", "kind": "cpu", "count": jax.device_count(),
+    report_line, result_line = proc.stdout.strip().splitlines()[-2:]
+    # the last line is the result, with exactly these keys: its reader
+    # refuses anything more
+    assert json.loads(result_line) == {
+        "ok": True,
+        "device": {
+            "platform": "cpu", "kind": "cpu", "count": jax.device_count(),
+        },
     }
+    summary = json.loads(report_line)["report"]
+    assert summary["rehearsal"] is True
     assert set(summary["stages"]) == {
         "device", "fleet_build", "serve", "safety_nets", "second_boot",
         "kernel",
