@@ -1,0 +1,94 @@
+"""The numbers that decide ``correct``: program against plain reference.
+
+Each is a gap, 0 when the two agree, and has a limit of its own in the
+configuration's file (``correct.limits``); ``PERF.md`` gives the readings
+each limit was set from. A number without a limit is printed and not judged.
+"""
+
+from __future__ import annotations
+
+from typing import Dict, List
+
+import numpy as np
+
+
+def _rel(a, b, floor: float = 1e-12) -> float:
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.max(np.abs(a - b) / np.maximum(np.abs(b), floor)))
+
+
+def flatten(tree, prefix="") -> Dict[str, np.ndarray]:
+    out = {}
+    for key in sorted(tree):
+        value = tree[key]
+        path = f"{prefix}/{key}" if prefix else str(key)
+        if isinstance(value, dict):
+            out.update(flatten(value, path))
+        else:
+            out[path] = np.asarray(value, np.float64)
+    return out
+
+
+def param_change_gap(program_params, ref_params, ref_params0) -> float:
+    """Worst leaf of | ||dp_program|| - ||dp_reference|| | over the larger of
+    the reference's norm for that leaf and its median leaf's: the gap between
+    the norms of the change, not the norm of the difference. A leaf the
+    program left where it started reads 1, one it moved twice as far reads
+    1 too."""
+    prog, ref, ref0 = flatten(program_params), flatten(ref_params), flatten(ref_params0)
+    if sorted(prog) != sorted(ref):
+        return float("inf")
+    ref_norms = {k: float(np.linalg.norm(ref[k] - ref0[k])) for k in ref}
+    median = float(np.median(list(ref_norms.values())))
+    worst = 0.0
+    for key in ref:
+        if prog[key].shape != ref[key].shape:
+            return float("inf")
+        norm = float(np.linalg.norm(prog[key] - ref0[key]))
+        worst = max(worst, abs(norm - ref_norms[key]) / max(ref_norms[key], median, 1e-30))
+    return worst
+
+
+def machine_numbers(program: Dict[str, object], reference: Dict[str, object]) -> Dict[str, float]:
+    """``program``: what the timed job committed for one machine, read back
+    from its artifact, and what the benchmark's dataset saw it fetch.
+    ``reference``: the plain reference's result for the same machine."""
+    numbers = {
+        "rows_gap": abs(int(program["rows"]) - int(reference["rows"])),
+        "x_sum_gap": _rel(program["x_sum"], reference["x_sum"], 1e-6),
+        "scaler_gap": max(
+            _rel(program["input_scale"], reference["input_scale"]),
+            _rel(program["target_scale"], reference["input_scale"]),
+            float(np.max(np.abs(
+                np.asarray(program["input_offset"], np.float64)
+                - np.asarray(reference["input_offset"], np.float64)
+            ))),
+        ),
+        "loss_first_gap": _rel(program["loss_history"][0], reference["loss_history"][0]),
+        "loss_last_gap": _rel(program["loss_history"][-1], reference["loss_history"][-1]),
+        "param_change_gap": param_change_gap(
+            program["params"], reference["params"], reference["params0"]
+        ),
+        "cv_mse_gap": _rel(program["cv_mse"], reference["cv_mse"]),
+        "threshold_gap": _rel(program["total_threshold"], reference["total_threshold"]),
+        "anomaly_gap": _rel(program["anomaly_mean"], reference["anomaly_mean"]),
+    }
+    if len(program["loss_history"]) != len(reference["loss_history"]):
+        numbers["loss_last_gap"] = float("inf")
+    return numbers
+
+
+def worst_of(per_machine: List[Dict[str, float]]) -> Dict[str, float]:
+    keys = per_machine[0].keys()
+    return {k: max(float(m[k]) for m in per_machine) for k in keys}
+
+
+def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> Dict[str, Dict[str, object]]:
+    """``{name: {"value", "limit", "ok"}}``; a number with no limit has
+    ``limit: None`` and is not judged. A value that is not finite fails."""
+    out = {}
+    for name, value in numbers.items():
+        limit = limits.get(name)
+        ok = True if limit is None else bool(np.isfinite(value) and value <= limit)
+        out[name] = {"value": float(value), "limit": limit, "ok": ok}
+    return out
