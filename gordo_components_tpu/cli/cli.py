@@ -113,7 +113,9 @@ _TRACE_DIR_OPT = click.option(
     envvar="GORDO_TRACE_DIR",
     default=None,
     help="write a jax.profiler device trace (TensorBoard/perfetto-loadable) "
-    "of the device work to this directory",
+    "of the device work to this directory; fleet-build traces one whole "
+    "steady slice, host phases included, and writes the job's span "
+    "timeline beside it as fleet_build_timeline.json (Perfetto loads both)",
 )
 
 

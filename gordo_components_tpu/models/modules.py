@@ -21,6 +21,7 @@ from __future__ import annotations
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 _ACTIVATIONS: dict = {
@@ -108,7 +109,10 @@ class LSTMModule(nn.Module):
             cell = nn.OptimizedLSTMCell(
                 n_units, activation_fn=activation(func), dtype=dtype
             )
-            h = nn.RNN(cell)(h)
+            # one scope per layer's scan over the window (autodiff carries
+            # it to the transpose): a device trace tells the layers apart
+            with jax.named_scope(f"lstm_layer_{i}"):
+                h = nn.RNN(cell)(h)
             if self.dropout > 0.0:
                 h = nn.Dropout(rate=self.dropout)(h, deterministic=deterministic)
         last = h[:, -1, :]

@@ -81,11 +81,14 @@ def make_batch_step(
     def batch_step(carry, batch):
         params, opt_state = carry
         xi, yi, wi, ki = batch
-        batch_loss, grads = grad_fn(
-            params, xi, yi, wi, ki if use_dropout else None
-        )
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        # named scopes are op metadata: a device trace tells this step's
+        # ops (and the loop that holds them) from the predict passes'
+        with jax.named_scope("optimizer_step"):
+            batch_loss, grads = grad_fn(
+                params, xi, yi, wi, ki if use_dropout else None
+            )
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return (params, opt_state), (batch_loss, jnp.sum(wi))
 
     return batch_step
@@ -146,9 +149,10 @@ def make_fit_fn(
             return (params, opt_state), epoch_loss
 
         epoch_keys = jax.random.split(key, epochs)
-        (params, _), history = jax.lax.scan(
-            epoch_step, (params, opt_state), epoch_keys
-        )
+        with jax.named_scope("epoch_loop"):
+            (params, _), history = jax.lax.scan(
+                epoch_step, (params, opt_state), epoch_keys
+            )
         return FitResult(params=params, loss_history=history)
 
     return fit

@@ -7,7 +7,7 @@ Three small modules every layer shares:
   primitives (``REGISTRY`` is the one instance telemetry records to).
 - :mod:`.exposition` — Prometheus text-format v0.0.4 rendering +
   validation (``GET /metrics?format=prometheus``).
-- :mod:`.tracing` — contextvar trace/span ids propagated via the
+- :mod:`.tracing` — contextvar trace ids propagated via the
   ``X-Gordo-Trace-Id`` header and stamped onto every log record.
 - :mod:`.spans` — per-request stage timelines (queue_wait / dispatch /
   device_execute / fetch / ...) with explicit span-context capture
@@ -38,7 +38,6 @@ from .tracing import (
     get_trace_id,
     install_log_record_factory,
     new_trace_id,
-    span,
     trace,
 )
 
@@ -62,6 +61,5 @@ __all__ = [
     "new_trace_id",
     "parse_prometheus_text",
     "render_prometheus",
-    "span",
     "trace",
 ]

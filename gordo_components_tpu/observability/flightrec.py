@@ -1,6 +1,13 @@
 """Flight recorder: an always-on bounded buffer of completed request
 timelines.
 
+A fleet build's one timeline a job (``meta`` ``kind="fleet-build"``,
+thousands of spans for a tall fleet, a few hundred bytes each) is
+recorded here too, however the job ends; :meth:`FlightRecorder.latest`
+finds it. A build process records one; a process that both serves and
+builds keeps a build in the ring until 256 later timelines push it out,
+and in the slow reservoir for good, which a build's length earns it.
+
 Post-hoc diagnosability is the point: when an operator asks "why did
 trace 3f2a... take 900 ms at 04:12", the histograms have already averaged
 the answer away. The recorder keeps (1) a ring of the last ``keep``
@@ -119,6 +126,15 @@ class FlightRecorder:
     def get(self, trace_id: str) -> Optional[Timeline]:
         for timeline in self._all():
             if timeline.trace_id == trace_id:
+                return timeline
+        return None
+
+    def latest(self, **meta: Any) -> Optional[Timeline]:
+        """The newest kept timeline whose ``meta`` holds every given
+        pair — ``latest(kind="fleet-build")`` is the last build job of
+        this process, whatever way it ended."""
+        for timeline in self._all():
+            if all(timeline.meta.get(k) == v for k, v in meta.items()):
                 return timeline
         return None
 

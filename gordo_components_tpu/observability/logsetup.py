@@ -19,7 +19,7 @@ TEXT_FORMAT = "%(asctime)s %(levelname)s %(name)s: %(message)s"
 
 
 class JsonFormatter(logging.Formatter):
-    """One JSON object per record; trace/span ids included when active."""
+    """One JSON object per record; the trace id included when active."""
 
     def format(self, record: logging.LogRecord) -> str:
         payload = {
@@ -31,9 +31,6 @@ class JsonFormatter(logging.Formatter):
         trace_id = getattr(record, "trace_id", "")
         if trace_id:
             payload["trace_id"] = trace_id
-        span_id = getattr(record, "span_id", "")
-        if span_id:
-            payload["span_id"] = span_id
         if record.exc_info:
             payload["exc_info"] = self.formatException(record.exc_info)
         return json.dumps(payload, default=str)
@@ -41,8 +38,8 @@ class JsonFormatter(logging.Formatter):
 
 def configure_logging(level: str = "INFO", fmt: str = "text") -> None:
     """Install root logging at ``level`` in ``fmt`` ('text' | 'json') and
-    the trace-id record factory (every record carries ``trace_id`` /
-    ``span_id`` attributes from then on, whatever the handler).
+    the trace-id record factory (every record carries a ``trace_id``
+    attribute from then on, whatever the handler).
 
     ``basicConfig`` WITHOUT ``force``, exactly like the CLI call this
     grew from: a no-op when the root logger already has handlers (a test

@@ -18,15 +18,31 @@ explicit :class:`SpanContext` (:func:`capture` at enqueue,
 the trace id for log records emitted over there (the PR 4 regression:
 collector-side log lines carried no ``X-Gordo-Trace-Id``).
 
+Causality: every span carries an ``id`` and the ``parent`` that caused
+it — the stage that was open in the same bound context when it began
+(:func:`stage` keeps the open stage in a contextvar; :func:`capture`
+carries it across a seam, so :func:`bind` / :func:`record_into` on the
+far side parent under the stage that enqueued the work).
+:meth:`Timeline.self_seconds` is the view built on it: a span's duration
+minus the part of its interval its children cover.
+
+A fleet build is one timeline too (``parallel/build_fleet.py``; its
+``trace_id`` is the job's). Such a timeline asks for ``annotate``: each
+of its stages is also a ``jax.profiler.TraceAnnotation`` of the same
+name, so in any profiler session the span lands on the host plane of the
+xplane that holds the device's ops — one clock for both. Request
+timelines do not annotate.
+
 Overhead contract: a stage is one ``perf_counter`` pair, one histogram
 observe (``gordo_stage_seconds{stage}``), and — when a timeline is bound
-— one lock-guarded list append. No timeline bound (recorder disabled,
-CLI batch jobs) ⇒ the append vanishes and only the histogram remains.
+— two contextvar writes and one lock-guarded list append. No timeline
+bound (recorder disabled) ⇒ only the histogram remains.
 """
 
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
 from contextvars import ContextVar
@@ -73,13 +89,20 @@ def _stage_series(name: str):
 _timeline: ContextVar[Optional["Timeline"]] = ContextVar(
     "gordo_timeline", default=None
 )
+# id of the stage open in this context (0 = none): the parent of the
+# next span begun here
+_open_stage: ContextVar[int] = ContextVar("gordo_open_stage", default=0)
 
 
 class Span:
-    __slots__ = ("name", "start", "duration", "thread", "process", "attrs")
+    __slots__ = ("name", "start", "duration", "thread", "process", "attrs",
+                 "id", "parent")
 
     def __init__(self, name: str, start: float, duration: float,
-                 thread: str, attrs: Dict[str, Any], process: str = ""):
+                 thread: str, attrs: Dict[str, Any], process: str = "",
+                 id: int = 0, parent: int = 0):
+        self.id = id  # unique within its timeline, from 1
+        self.parent = parent  # id of the span that caused it; 0 = a root
         self.name = name
         self.start = start  # seconds since timeline start
         self.duration = duration
@@ -99,10 +122,15 @@ class Timeline:
     """
 
     __slots__ = ("trace_id", "meta", "started_wall", "started", "finished",
-                 "status", "error", "spans", "events", "_lock")
+                 "status", "error", "spans", "events", "annotate", "_ids",
+                 "_lock")
 
     def __init__(self, trace_id: str, **meta: Any):
         self.trace_id = trace_id
+        # True: stage() also opens a jax.profiler.TraceAnnotation of the
+        # span's name (the fleet build's timeline sets it)
+        self.annotate = False
+        self._ids = itertools.count(1)
         self.meta = {k: v for k, v in meta.items() if v is not None}
         self.started_wall = time.time()
         self.started = time.perf_counter()
@@ -114,11 +142,16 @@ class Timeline:
         self._lock = threading.Lock()
 
     # -- recording (any thread) ----------------------------------------------
+    def next_id(self) -> int:
+        return next(self._ids)
+
     def add_span(self, name: str, started: float, duration: float,
-                 **attrs: Any) -> None:
+                 span_id: int = 0, parent: int = 0, **attrs: Any) -> None:
         """``started`` is an absolute ``time.perf_counter()`` reading (the
         recorder converts to timeline-relative) so cross-thread recorders
-        never need the timeline's epoch."""
+        never need the timeline's epoch. ``span_id`` is one
+        :meth:`next_id` handed out when the span began (children name it
+        as their ``parent`` before it is appended); 0 takes a fresh one."""
         if attrs:
             attrs = {k: v for k, v in attrs.items() if v not in (None, "")}
         span = Span(
@@ -127,6 +160,8 @@ class Timeline:
             max(0.0, duration),
             threading.current_thread().name,
             attrs,
+            id=span_id or next(self._ids),
+            parent=parent,
         )
         with self._lock:
             self.spans.append(span)
@@ -142,7 +177,7 @@ class Timeline:
         span = Span(
             name, max(0.0, rel_start), max(0.0, duration),
             thread or threading.current_thread().name, attrs,
-            process=process,
+            process=process, id=next(self._ids),
         )
         with self._lock:
             self.spans.append(span)
@@ -190,6 +225,32 @@ class Timeline:
         out: Dict[str, float] = {}
         for span in spans:
             out[span.name] = out.get(span.name, 0.0) + span.duration
+        return out
+
+    def self_seconds(self) -> Dict[int, float]:
+        """Self time per span id: the span's duration minus the part of
+        its interval that its children (spans naming it as ``parent``,
+        on any thread) cover. What is left is time no finer span
+        accounts for."""
+        with self._lock:
+            spans = list(self.spans)
+        children: Dict[int, List[Span]] = {}
+        for span in spans:
+            if span.parent:
+                children.setdefault(span.parent, []).append(span)
+        out: Dict[int, float] = {}
+        for span in spans:
+            lo, hi = span.start, span.start + span.duration
+            covered, edge = 0.0, lo
+            for child in sorted(
+                children.get(span.id, ()), key=lambda c: c.start
+            ):
+                start = max(child.start, edge)
+                end = min(child.start + child.duration, hi)
+                if end > start:
+                    covered += end - start
+                    edge = end
+            out[span.id] = max(0.0, span.duration - covered)
         return out
 
     # parent stages CONTAIN other stages (score wraps the whole engine
@@ -250,6 +311,8 @@ class Timeline:
                     "start_ms": round(span.start * 1000, 3),
                     "duration_ms": round(span.duration * 1000, 3),
                     "thread": span.thread,
+                    "id": span.id,
+                    **({"parent": span.parent} if span.parent else {}),
                     **({"process": span.process} if span.process else {}),
                     **span.attrs,
                 }
@@ -306,7 +369,11 @@ class Timeline:
                 "cat": "stage",
                 "ts": base_us + span.start * 1e6,
                 "dur": span.duration * 1e6,
-                "args": dict(span.attrs),
+                "args": {
+                    "id": span.id,
+                    **({"parent": span.parent} if span.parent else {}),
+                    **span.attrs,
+                },
             })
         for event in events:
             args = {
@@ -338,19 +405,25 @@ class Timeline:
 
 
 class SpanContext(NamedTuple):
-    """Explicit capture of (trace id, timeline) for crossing the seams
-    contextvars do not survive: the engine's collector-thread handoff and
-    the client's cross-thread asyncio submission."""
+    """Explicit capture of (trace id, timeline, open stage) for crossing
+    the seams contextvars do not survive: the engine's collector-thread
+    handoff, the client's cross-thread asyncio submission, the fleet
+    build's prefetch worker and fetch pool. ``parent`` is the id of the
+    stage that was open at capture: what the far side's spans hang
+    under."""
 
     trace_id: str
     timeline: Optional[Timeline]
+    parent: int = 0
 
 
 EMPTY_CONTEXT = SpanContext("", None)
 
 
 def capture() -> SpanContext:
-    return SpanContext(tracing.get_trace_id(), _timeline.get())
+    return SpanContext(
+        tracing.get_trace_id(), _timeline.get(), _open_stage.get()
+    )
 
 
 @contextlib.contextmanager
@@ -360,9 +433,11 @@ def bind(ctx: SpanContext) -> Iterator[None]:
     timeline. Safe with ``EMPTY_CONTEXT`` (binds nothing extra)."""
     trace_token = tracing.set_trace_id(ctx.trace_id) if ctx.trace_id else None
     timeline_token = _timeline.set(ctx.timeline)
+    open_token = _open_stage.set(ctx.parent)
     try:
         yield
     finally:
+        _open_stage.reset(open_token)
         _timeline.reset(timeline_token)
         if trace_token is not None:
             tracing.reset_trace_id(trace_token)
@@ -385,19 +460,50 @@ def end(token) -> None:
     _timeline.reset(token)
 
 
+def _annotation(name: str):
+    # jax is imported only where a timeline asks for annotations
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
+
+
 @contextlib.contextmanager
-def stage(name: str, **attrs: Any) -> Iterator[None]:
+def stage(name: str, **attrs: Any) -> Iterator[Dict[str, Any]]:
     """Record a named stage: always observes ``gordo_stage_seconds``,
-    and appends a span when a timeline is bound."""
+    and appends a span when a timeline is bound. Yields the span's
+    attrs, so the block can add what it only knows at its end; a stage
+    left by an exception says so (``error``: the exception's type).
+    Stages opened inside it, or on a far side bound to a context
+    captured inside it, name it as their parent."""
     timeline = _timeline.get()
+    if timeline is None:
+        started = time.perf_counter()
+        try:
+            yield attrs
+        finally:
+            _stage_series(name).observe(time.perf_counter() - started)
+        return
+    span_id = timeline.next_id()
+    parent = _open_stage.get()
+    token = _open_stage.set(span_id)
+    annotation = _annotation(name) if timeline.annotate else None
+    if annotation is not None:
+        annotation.__enter__()
     started = time.perf_counter()
     try:
-        yield
+        yield attrs
+    except BaseException as exc:
+        attrs.setdefault("error", type(exc).__name__)
+        raise
     finally:
         duration = time.perf_counter() - started
+        if annotation is not None:
+            annotation.__exit__(None, None, None)
+        _open_stage.reset(token)
         _stage_series(name).observe(duration)
-        if timeline is not None:
-            timeline.add_span(name, started, duration, **attrs)
+        timeline.add_span(
+            name, started, duration, span_id=span_id, parent=parent, **attrs
+        )
 
 
 def event(name: str, **attrs: Any) -> None:
@@ -411,11 +517,14 @@ def record_into(ctx: SpanContext, name: str, started: float,
                 duration: float, **attrs: Any) -> None:
     """Record a span into a CAPTURED context's timeline from any thread —
     how the bucket leader and collector attribute dispatch/device/fetch
-    time to each batched item's own request. Observes the aggregate
+    time to each batched item's own request, under the stage that was
+    open when the context was captured. Observes the aggregate
     histogram exactly once per call, like :func:`stage`."""
     _stage_series(name).observe(max(0.0, duration))
     if ctx.timeline is not None:
-        ctx.timeline.add_span(name, started, duration, **attrs)
+        ctx.timeline.add_span(
+            name, started, duration, parent=ctx.parent, **attrs
+        )
 
 
 def event_into(ctx: SpanContext, name: str, **attrs: Any) -> None:

@@ -140,8 +140,9 @@ def merge_remote(
             continue
         attrs = {
             k: v for k, v in span.items()
+            # ids are the remote timeline's own: not carried over
             if k not in ("name", "start_ms", "duration_ms", "thread",
-                         "process")
+                         "process", "id", "parent")
         }
         timeline.add_span_at(
             name, start, duration,

@@ -1,4 +1,4 @@
-"""Request tracing: contextvar trace/span IDs propagated over HTTP.
+"""Request tracing: a contextvar trace id propagated over HTTP.
 
 The reference correlates nothing across its client → per-model Flask pod
 hop; debugging a slow prediction means grepping two pods' logs by
@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import contextlib
 import logging
-import time
 import uuid
 from contextvars import ContextVar
 from typing import Iterator, Optional
@@ -26,7 +25,6 @@ from typing import Iterator, Optional
 TRACE_HEADER = "X-Gordo-Trace-Id"
 
 _trace_id: ContextVar[str] = ContextVar("gordo_trace_id", default="")
-_span_id: ContextVar[str] = ContextVar("gordo_span_id", default="")
 
 logger = logging.getLogger(__name__)
 
@@ -66,40 +64,12 @@ def trace(trace_id: Optional[str] = None) -> Iterator[str]:
         _trace_id.reset(token)
 
 
-@contextlib.contextmanager
-def span(name: str) -> Iterator[str]:
-    """A named timed unit inside the current trace: binds a fresh span id,
-    logs the duration at DEBUG, and observes it into the registry
-    (``gordo_span_seconds{name}``). Cheap enough for request paths — one
-    contextvar set/reset, one histogram observe, one lazy DEBUG line."""
-    from .registry import REGISTRY
-
-    sid = uuid.uuid4().hex[:8]
-    token = _span_id.set(sid)
-    started = time.perf_counter()
-    try:
-        yield sid
-    finally:
-        elapsed = time.perf_counter() - started
-        _span_id.reset(token)
-        REGISTRY.histogram(
-            "gordo_span_seconds",
-            "Duration of named trace spans",
-            labels=("name",),
-        ).labels(name).observe(elapsed)
-        logger.debug("span %s (%s): %.3f ms", name, sid, elapsed * 1000)
-
-
-def get_span_id() -> str:
-    return _span_id.get()
-
-
 _factory_installed = False
 
 
 def install_log_record_factory() -> None:
-    """Stamp ``record.trace_id`` / ``record.span_id`` onto every log record
-    from the active context. Idempotent; wraps (never replaces) whatever
+    """Stamp ``record.trace_id`` onto every log record from the active
+    context. Idempotent; wraps (never replaces) whatever
     factory is already installed, so it composes with other libraries'
     factories and with repeated ``configure_logging`` calls."""
     global _factory_installed
@@ -111,7 +81,6 @@ def install_log_record_factory() -> None:
     def factory(*args, **kwargs):
         record = previous(*args, **kwargs)
         record.trace_id = _trace_id.get()
-        record.span_id = _span_id.get()
         return record
 
     logging.setLogRecordFactory(factory)

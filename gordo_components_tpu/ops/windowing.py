@@ -111,13 +111,14 @@ def gather_windows(
         collapsed_slice_dims=(),
         start_index_map=(0,),
     )
-    return jax.lax.gather(
-        rows,
-        starts[:, None],
-        dnums,
-        slice_sizes=(lookback_window, n_features),
-        mode="clip",
-    )
+    with jax.named_scope("gather_windows"):
+        return jax.lax.gather(
+            rows,
+            starts[:, None],
+            dnums,
+            slice_sizes=(lookback_window, n_features),
+            mode="clip",
+        )
 
 
 def reconstruction_targets(x: jnp.ndarray, lookback_window: int) -> jnp.ndarray:

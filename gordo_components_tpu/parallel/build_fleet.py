@@ -45,6 +45,8 @@ from ..builder.build_model import (
 from ..models.analysis import Analyzed as _Analyzed
 from ..models.analysis import analyze_model as _analyze_model
 from ..models.transformers import MinMaxScaler, StandardScaler
+from ..observability import spans, tracing
+from ..observability.flightrec import RECORDER
 from ..observability.registry import REGISTRY
 from ..ops.scaling import ScalerParams
 from ..resilience import faults
@@ -58,6 +60,7 @@ from ..store import (
 )
 from ..store import journal as store_journal
 from ..utils import disk_registry
+from ..utils.profiling import device_trace
 from .fleet import (
     FLEET_CV_METRICS,
     FleetSpec,
@@ -79,18 +82,15 @@ _M_BUILD_FETCH = REGISTRY.counter(
     "Fleet-build per-machine data-fetch outcomes (ok / retry / failed)",
     labels=("outcome",),
 )
-_M_MACHINE_BUILD_SECONDS = REGISTRY.gauge(
-    "gordo_fleet_machine_build_seconds",
-    "Amortized build duration of each machine's latest fleet build "
-    "(slice wall-clock / machines in slice)",
-    labels=("machine",),
-)
 
 # sliced builds round the padded row axis up to a multiple of this, so
 # heterogeneous-history slices collapse onto few compiled shapes
 _ROW_QUANTUM = 256
 
 MANIFEST_FILE = "fleet_manifest.json"
+# the job's span timeline as Chrome trace-event JSON, written beside the
+# device trace when a trace dir is given (Perfetto loads both)
+TIMELINE_FILE = "fleet_build_timeline.json"
 
 # exit code for a tripped multi-host watchdog: EX_TEMPFAIL — deliberately
 # NOT the permanent-failure codes the CLI maps config/data errors to
@@ -122,6 +122,7 @@ def _fetch_machine_data(item: dict, retries: int, backoff: float) -> Optional[st
     name = item["machine"].name
     last_error: Optional[str] = None
     for attempt in range(max(0, retries) + 1):
+        item["fetch_retries"] = attempt
         if attempt:
             _M_BUILD_FETCH.labels("retry").inc()
             time.sleep(backoff * 2 ** (attempt - 1))
@@ -200,26 +201,38 @@ def _prepare_slice(
     Every shape input is an explicit argument (not a closure over bucket-loop
     locals): the call runs on another thread, and late-bound locals would
     silently go stale if a future ever crossed a bucket boundary (ADVICE r2).
+
+    Stages: ``fleet.fetch`` per machine (on the fetch pool's threads),
+    ``fleet.assemble``, and ``fleet.place`` when the batch is device-placed
+    here. Returns ``(X, y, w, n_rows)``.
     """
     lo, hi = span if span is not None else (0, n_padded)
     local_items = slice_items[lo:min(hi, len(slice_items))]
-    fetch_started = time.perf_counter()
+    # the fetch pool's threads inherit no context: each fetch binds this
+    # one, so its span hangs under the stage open here (fleet.prepare)
+    # and its log lines carry the job's trace id
+    seam = spans.capture()
 
     def fetch_one(item: dict) -> None:
         # per-machine failure isolation: a machine whose fetch fails after
         # retries trains as zero-weight padding (fold masks already handle
         # empty machines) and is reported failed — it must not take the
         # other N-1 machines of the slice down with it
-        error = _fetch_machine_data(item, fetch_retries, fetch_backoff)
-        if error is not None:
-            logger.error(
-                "Isolating machine %r from fleet build: %s",
-                item["machine"].name, error,
-            )
-            item["build_error"] = error
-            item["X"] = np.zeros((0, n_features), np.float32)
-            item["y"] = np.zeros((0, n_targets), np.float32)
-            item["dataset_metadata"] = {}
+        with spans.bind(seam), spans.stage(
+            "fleet.fetch", machine=item["machine"].name
+        ) as fetched:
+            error = _fetch_machine_data(item, fetch_retries, fetch_backoff)
+            if error is not None:
+                logger.error(
+                    "Isolating machine %r from fleet build: %s",
+                    item["machine"].name, error,
+                )
+                item["build_error"] = error
+                item["X"] = np.zeros((0, n_features), np.float32)
+                item["y"] = np.zeros((0, n_targets), np.float32)
+                item["dataset_metadata"] = {}
+            fetched["rows"] = len(item["X"])
+            fetched["retries"] = item.pop("fetch_retries", 0)
 
     # items the width probe already fetched are skipped
     to_fetch = [item for item in local_items if "X" not in item]
@@ -240,37 +253,53 @@ def _prepare_slice(
         for item in to_fetch:
             fetch_one(item)
 
-    # max(…, 1): an all-isolated slice (every fetch failed) still needs a
-    # nonzero row axis for the padded program
-    n_rows = max(max((len(item["X"]) for item in local_items), default=1), 1)
-    if quantize_rows:
-        # quantize the row axis so slices with slightly different history
-        # lengths share one (n_padded, n_rows, F) shape and the bucket
-        # reuses a single compiled executable; padded rows are zero-weight
-        # and masked everywhere (fold masks run on real-sample ranks)
-        n_rows = -(-n_rows // _ROW_QUANTUM) * _ROW_QUANTUM
-    X = np.zeros((hi - lo, n_rows, n_features), np.float32)
-    y = np.zeros((hi - lo, n_rows, n_targets), np.float32)
-    w = np.zeros((hi - lo, n_rows), np.float32)
-    for i, item in enumerate(local_items):
-        rows = len(item["X"])
-        # RIGHT-aligned by convention (rows end at the bucket's latest
-        # timestamp). CV correctness does not depend on placement: fold
-        # masks are computed on real-sample ranks
-        # (fleet.timeseries_fold_masks), invariant to where padding sits
-        X[i, n_rows - rows :] = item["X"]
-        y[i, n_rows - rows :] = item["y"]
-        w[i, n_rows - rows :] = 1.0
+    with spans.stage("fleet.assemble"):
+        # max(…, 1): an all-isolated slice (every fetch failed) still needs
+        # a nonzero row axis for the padded program
+        n_rows = max(
+            max((len(item["X"]) for item in local_items), default=1), 1
+        )
+        if quantize_rows:
+            # quantize the row axis so slices with slightly different
+            # history lengths share one (n_padded, n_rows, F) shape and the
+            # bucket reuses a single compiled executable; padded rows are
+            # zero-weight and masked everywhere (fold masks run on
+            # real-sample ranks)
+            n_rows = -(-n_rows // _ROW_QUANTUM) * _ROW_QUANTUM
+        X = np.zeros((hi - lo, n_rows, n_features), np.float32)
+        y = np.zeros((hi - lo, n_rows, n_targets), np.float32)
+        w = np.zeros((hi - lo, n_rows), np.float32)
+        for i, item in enumerate(local_items):
+            rows = len(item["X"])
+            # RIGHT-aligned by convention (rows end at the bucket's latest
+            # timestamp). CV correctness does not depend on placement: fold
+            # masks are computed on real-sample ranks
+            # (fleet.timeseries_fold_masks), invariant to where padding sits
+            X[i, n_rows - rows :] = item["X"]
+            y[i, n_rows - rows :] = item["y"]
+            w[i, n_rows - rows :] = 1.0
     if place is not None and span is None:
         spec, mesh = place
         hit = peek_fleet_executable(
             spec, n_padded, n_rows, n_features, n_targets, mesh=mesh
         )
         if hit is not None:
-            X, y, w = (
-                jax.device_put(a, f) for a, f in zip((X, y, w), hit[1][:3])
-            )
-    return X, y, w, n_rows, time.perf_counter() - fetch_started
+            with spans.stage("fleet.place"):
+                X, y, w = (
+                    jax.device_put(a, f)
+                    for a, f in zip((X, y, w), hit[1][:3])
+                )
+    return X, y, w, n_rows
+
+
+def _prepare_bound(seam: spans.SpanContext, bucket: int, sl: int, *args):
+    """The prefetch worker's side of the seam: :func:`_prepare_slice` as
+    the stage ``fleet.prepare`` of the job's timeline, under the context
+    the main thread captured when it submitted the slice."""
+    with spans.bind(seam), spans.stage(
+        "fleet.prepare", bucket=bucket, slice=sl
+    ):
+        return _prepare_slice(*args)
 
 
 def _local_machine_span(mesh, n_padded: int) -> Tuple[int, int]:
@@ -963,7 +992,16 @@ def build_fleet(
     any point leaves each machine either whole or absent — never torn.
     Remaining machines are bucketed by (model config, data shape)
     and each bucket trains as one compiled program, sharded over ``mesh``.
-    ``profile_dir`` wraps the device work in a ``jax.profiler`` trace.
+    ``profile_dir``: one ``jax.profiler`` session around one whole steady
+    slice (the second of the first bucket, else the first), host phases
+    included, and the job's span timeline as ``fleet_build_timeline.json``
+    beside it.
+
+    **Spans**: the job is one ``observability.spans.Timeline`` (``fleet.job``
+    → ``fleet.preamble``, ``fleet.bucket`` → ``fleet.slice`` and its phases
+    on this thread, ``fleet.prepare`` and its fetches on the prefetch
+    worker's; docs/ARCHITECTURE.md §13), handed to the flight recorder
+    (``meta`` ``kind="fleet-build"``) however the job ends.
 
     Buckets larger than ``slice_size`` train in slices: every slice is padded
     to the same machine count (so the compiled executable is reused across
@@ -987,10 +1025,72 @@ def build_fleet(
     process writes/reads its own shards), layered on the per-machine
     registry resume.
     """
-    import os
+    with tracing.trace(tracing.current_or_new()) as trace_id:
+        timeline, token = spans.begin(
+            trace_id, kind="fleet-build", service="gordo fleet-build",
+            machines=len(machines),
+        )
+        timeline.annotate = True
+        error = ""
+        try:
+            with spans.stage("fleet.job", machines=len(machines)):
+                return _build_fleet(
+                    machines, output_dir, model_register_dir, mesh, seed,
+                    n_splits, profile_dir, slice_size, fetch_retries,
+                    fetch_backoff, precision_default, precision_map,
+                )
+        except BaseException as exc:  # the benchmark ends a job with one
+            error = f"{type(exc).__name__}: {exc}"
+            raise
+        finally:
+            spans.end(token)
+            timeline.finish(status="error" if error else "ok", error=error)
+            # kept in memory, written at the end, readable after any ending
+            RECORDER.record(timeline)
+            if profile_dir:
+                _write_timeline(timeline, profile_dir)
+            logger.info(
+                "Fleet build %s: %d machines in %.1fs; phases: %s",
+                timeline.status,
+                len(machines),
+                timeline.duration,
+                {
+                    name: round(seconds, 3)
+                    for name, seconds in sorted(
+                        timeline.stage_seconds().items()
+                    )
+                },
+            )
 
-    from ..utils.profiling import PhaseTimer, device_trace
 
+def _write_timeline(timeline: spans.Timeline, trace_dir: str) -> None:
+    """Best effort: the timeline is a diagnostic, and this runs while a
+    job's own exception may be propagating."""
+    path = os.path.join(trace_dir, TIMELINE_FILE)
+    try:
+        os.makedirs(trace_dir, exist_ok=True)
+        with open(path, "w") as fh:
+            json.dump(timeline.to_chrome_trace(), fh, default=str)
+        logger.info("Fleet build timeline written to %s", path)
+    except OSError:
+        logger.warning("Could not write %s", path, exc_info=True)
+
+
+def _build_fleet(
+    machines: List[FleetMachineConfig],
+    output_dir: str,
+    model_register_dir: Optional[str],
+    mesh,
+    seed: int,
+    n_splits: int,
+    profile_dir: Optional[str],
+    slice_size: Optional[int],
+    fetch_retries: Optional[int],
+    fetch_backoff: Optional[float],
+    precision_default: Optional[str],
+    precision_map: Optional[Dict[str, str]],
+) -> Dict[str, str]:
+    """The job itself, inside :func:`build_fleet`'s ``fleet.job`` stage."""
     if slice_size is not None and slice_size < 1:
         # validated BEFORE any dataset probing or cache scanning, so an
         # invalid value errors even on a fully-cached (no-op) build
@@ -1033,473 +1133,443 @@ def build_fleet(
             jax.process_count(),
         )
 
-    timer = PhaseTimer()
-    started = time.perf_counter()
-    results: Dict[str, str] = {}
-    pending: List[Tuple[FleetMachineConfig, str, int, Optional[bool]]] = []
-    ignored_eval: Dict[str, List[str]] = {}
-    # resumable-build WAL: one fsync'd record per machine lifecycle event
-    # (started / committed / failed); a re-run replays it (unioned with any
-    # multi-host siblings) so committed machines are skipped even when no
-    # registry is configured, and torn ones are provably redone
-    journal = store_journal.BuildJournal(
-        store_journal.journal_path(output_dir, jax.process_index())
-    )
-    journal_states = store_journal.replay(output_dir)
-    journal_counts = {"resumed": 0, "torn": 0, "rebuilt": 0}
-    for machine in machines:
-        eff_splits, eff_cv_parallel, ignored = _effective_splits(
-            machine, n_splits
+
+    with spans.stage("fleet.preamble") as preamble:
+        results: Dict[str, str] = {}
+        pending: List[Tuple[FleetMachineConfig, str, int, Optional[bool]]] = []
+        ignored_eval: Dict[str, List[str]] = {}
+        # resumable-build WAL: one fsync'd record per machine lifecycle event
+        # (started / committed / failed); a re-run replays it (unioned with any
+        # multi-host siblings) so committed machines are skipped even when no
+        # registry is configured, and torn ones are provably redone
+        journal = store_journal.BuildJournal(
+            store_journal.journal_path(output_dir, jax.process_index())
         )
-        if ignored:
-            ignored_eval[machine.name] = ignored
-        # cv_parallel is deliberately NOT part of the cache key: it is an
-        # execution strategy (vmapped vs scanned fold fits), numerically
-        # equivalent by tests/test_fleet.py::test_cv_parallel_matches_scan —
-        # flipping it must resume from existing artifacts, not retrain. The
-        # mode that actually trained an artifact is recorded in its fleet
-        # metadata block for provenance.
-        evaluation_config = {"n_splits": eff_splits, "cv_mode": "fleet"}
-        cache_key = calculate_model_key(
-            machine.name,
-            machine.model_config,
-            machine.data_config,
-            evaluation_config=evaluation_config,
-            # §19: re-precisioning a machine is a cache miss — a cached
-            # f32 artifact must not satisfy an int8 build (and vice
-            # versa); f32 keeps every pre-ladder key valid
-            precision=precision_of(machine.name),
-        )
-        cached: Optional[str] = None
-        if model_register_dir:
-            # dangling pointers already read as None inside get_value
-            cached = disk_registry.get_value(model_register_dir, cache_key)
-        if cached is None:
-            # no registry (or no entry): the journal's committed record is
-            # the fallback resume source — but only for the SAME config
-            # (cache_key match), else a config change would resurrect a
-            # stale artifact
-            record = journal_states.get(machine.name)
-            if (
-                record is not None
-                and record.get("event") == store_journal.EVENT_COMMITTED
-                and record.get("cache_key") == cache_key
-                and os.path.isdir(str(record.get("model_dir", "")))
-            ):
-                cached = str(record["model_dir"])
-        if cached is not None:
-            # trust nothing unverified: a registered-but-torn artifact
-            # (crash between artifact and registry durability) must
-            # rebuild, not serve half a model later. Structural check
-            # only (deep=False): a fully-cached thousand-machine resume
-            # must stay O(stats) — the serving load() pays the hash pass
-            try:
-                verify_artifact(resolve_artifact_dir(cached), deep=False)
-            except StoreError as exc:
-                logger.warning(
-                    "Fleet resume: artifact for %r fails verification "
-                    "(%s); rebuilding", machine.name, exc,
-                )
-                journal_counts["torn"] += 1
-            else:
-                cached_precision = cached_artifact_precision(cached)
-                if cached_precision != precision_of(machine.name):
-                    # registry/journal values are the machine's SHARED
-                    # output dir — a later re-precision build swapped
-                    # CURRENT under this key's entry, so a hit alone
-                    # must not resurrect the other rung (§19)
+        journal_states = store_journal.replay(output_dir)
+        journal_counts = {"resumed": 0, "torn": 0, "rebuilt": 0}
+        for machine in machines:
+            eff_splits, eff_cv_parallel, ignored = _effective_splits(
+                machine, n_splits
+            )
+            if ignored:
+                ignored_eval[machine.name] = ignored
+            # cv_parallel is deliberately NOT part of the cache key: it is an
+            # execution strategy (vmapped vs scanned fold fits), numerically
+            # equivalent by tests/test_fleet.py::test_cv_parallel_matches_scan —
+            # flipping it must resume from existing artifacts, not retrain. The
+            # mode that actually trained an artifact is recorded in its fleet
+            # metadata block for provenance.
+            evaluation_config = {"n_splits": eff_splits, "cv_mode": "fleet"}
+            cache_key = calculate_model_key(
+                machine.name,
+                machine.model_config,
+                machine.data_config,
+                evaluation_config=evaluation_config,
+                # §19: re-precisioning a machine is a cache miss — a cached
+                # f32 artifact must not satisfy an int8 build (and vice
+                # versa); f32 keeps every pre-ladder key valid
+                precision=precision_of(machine.name),
+            )
+            cached: Optional[str] = None
+            if model_register_dir:
+                # dangling pointers already read as None inside get_value
+                cached = disk_registry.get_value(model_register_dir, cache_key)
+            if cached is None:
+                # no registry (or no entry): the journal's committed record is
+                # the fallback resume source — but only for the SAME config
+                # (cache_key match), else a config change would resurrect a
+                # stale artifact
+                record = journal_states.get(machine.name)
+                if (
+                    record is not None
+                    and record.get("event") == store_journal.EVENT_COMMITTED
+                    and record.get("cache_key") == cache_key
+                    and os.path.isdir(str(record.get("model_dir", "")))
+                ):
+                    cached = str(record["model_dir"])
+            if cached is not None:
+                # trust nothing unverified: a registered-but-torn artifact
+                # (crash between artifact and registry durability) must
+                # rebuild, not serve half a model later. Structural check
+                # only (deep=False): a fully-cached thousand-machine resume
+                # must stay O(stats) — the serving load() pays the hash pass
+                try:
+                    verify_artifact(resolve_artifact_dir(cached), deep=False)
+                except StoreError as exc:
                     logger.warning(
-                        "Fleet resume: artifact for %r serves precision "
-                        "%s but this build pins %s; rebuilding",
-                        machine.name, cached_precision,
-                        precision_of(machine.name),
+                        "Fleet resume: artifact for %r fails verification "
+                        "(%s); rebuilding", machine.name, exc,
                     )
+                    journal_counts["torn"] += 1
                 else:
-                    logger.info(
-                        "Fleet cache hit for %r -> %s", machine.name, cached
-                    )
-                    results[machine.name] = cached
-                    journal_counts["resumed"] += 1
-                    _M_FLEET_MACHINES.labels("cached").inc()
-                    continue
-        pending.append((machine, cache_key, eff_splits, eff_cv_parallel))
-    if ignored_eval:
-        sample = dict(list(ignored_eval.items())[:5])
-        logger.warning(
-            "Fleet builder ignores unsupported evaluation keys on %d "
-            "machine(s) (cv_mode is always 'fleet' here): %s%s",
-            len(ignored_eval),
-            sample,
-            " ..." if len(ignored_eval) > 5 else "",
-        )
+                    cached_precision = cached_artifact_precision(cached)
+                    if cached_precision != precision_of(machine.name):
+                        # registry/journal values are the machine's SHARED
+                        # output dir — a later re-precision build swapped
+                        # CURRENT under this key's entry, so a hit alone
+                        # must not resurrect the other rung (§19)
+                        logger.warning(
+                            "Fleet resume: artifact for %r serves precision "
+                            "%s but this build pins %s; rebuilding",
+                            machine.name, cached_precision,
+                            precision_of(machine.name),
+                        )
+                    else:
+                        logger.info(
+                            "Fleet cache hit for %r -> %s", machine.name, cached
+                        )
+                        results[machine.name] = cached
+                        journal_counts["resumed"] += 1
+                        _M_FLEET_MACHINES.labels("cached").inc()
+                        continue
+            pending.append((machine, cache_key, eff_splits, eff_cv_parallel))
+        if ignored_eval:
+            sample = dict(list(ignored_eval.items())[:5])
+            logger.warning(
+                "Fleet builder ignores unsupported evaluation keys on %d "
+                "machine(s) (cv_mode is always 'fleet' here): %s%s",
+                len(ignored_eval),
+                sample,
+                " ..." if len(ignored_eval) > 5 else "",
+            )
 
-    manifest: Dict[str, Dict[str, Any]] = {
-        name: {"status": "cached", "model_dir": path}
-        for name, path in results.items()
-    }
-    _write_manifest(
-        output_dir, manifest, [m.name for m, *_ in pending],
-        journal_counts=journal_counts,
-    )
-
-    # ---- bucket by (model config, feature/target width) BEFORE fetching:
-    # widths come from the dataset's declared columns, so peak host memory
-    # is one bucket's data, not the whole fleet's ---------------------------
-    buckets: Dict[str, List[dict]] = {}
-    for machine, cache_key, eff_splits, eff_cv_parallel in pending:
-        dataset = _dataset_from_config(machine.data_config)
-        item: dict = {
-            "machine": machine,
-            "cache_key": cache_key,
-            "dataset": dataset,
+        manifest: Dict[str, Dict[str, Any]] = {
+            name: {"status": "cached", "model_dir": path}
+            for name, path in results.items()
         }
-        if hasattr(dataset, "_columns_for"):
-            n_features = len(dataset._columns_for(dataset.tag_list))
-            n_targets = len(dataset._columns_for(dataset.target_tag_list))
-        elif multihost:  # non-TimeSeriesDataset: widths require a fetch —
-            # and multi-host bucketing must stay identical on every
-            # process, so a probe failure aborts (job-level retry) rather
-            # than diverging the collective program
-            X_probe, y_probe = dataset.get_data()
-            n_features, n_targets = X_probe.shape[1], y_probe.shape[1]
-            item["X"] = np.asarray(getattr(X_probe, "values", X_probe), np.float32)
-            item["y"] = np.asarray(getattr(y_probe, "values", y_probe), np.float32)
-            item["dataset_metadata"] = dataset.get_metadata()
-        else:  # single-host width probe: fetch with retry, isolating a
-            # terminally-failing machine BEFORE it ever buckets
-            error = _fetch_machine_data(item, fetch_retries, fetch_backoff)
-            if error is not None:
-                logger.error(
-                    "Isolating machine %r from fleet build (width probe): %s",
-                    machine.name, error,
-                )
-                manifest[machine.name] = {"status": "failed", "error": error}
-                journal.record(
-                    machine.name, store_journal.EVENT_FAILED, error=error
-                )
-                _M_FLEET_MACHINES.labels("failed").inc()
-                continue
-            n_features, n_targets = item["X"].shape[1], item["y"].shape[1]
-        item["F"], item["T"] = n_features, n_targets
-        item["n_splits"] = eff_splits
-        # resolve the fold-execution mode NOW (None → the remat-derived
-        # default, readable straight off the config dict) so a machine whose
-        # explicit override merely restates the default still buckets — and
-        # batches — with its unannotated twins; different resolved modes are
-        # different compiled programs and bucket separately
-        item["cv_parallel"] = (
-            eff_cv_parallel
-            if eff_cv_parallel is not None
-            else _derived_cv_parallel(machine.model_config)
-        )
-        sig = json.dumps(
-            {
-                "model_config": machine.model_config,
-                "F": n_features,
-                "T": n_targets,
-                "n_splits": item["n_splits"],
-                "cv_parallel": item["cv_parallel"],
-            },
-            sort_keys=True,
-            default=str,
-        )
-        buckets.setdefault(sig, []).append(item)
-
-    if any(
-        entry.get("status") == "failed" for entry in manifest.values()
-    ):
-        # probe-isolated machines must land in the on-disk manifest even
-        # when every remaining machine is cached (no slice write follows)
         _write_manifest(
-            output_dir, manifest,
-            [m.name for m, *_ in pending if m.name not in manifest],
+            output_dir, manifest, [m.name for m, *_ in pending],
             journal_counts=journal_counts,
         )
 
-    master_key = jax.random.PRNGKey(seed)
-    checkpointer = _SliceCheckpointer(output_dir, mesh=mesh)
-    watchdog = _SliceWatchdog(multihost)
-    prefetcher = ThreadPoolExecutor(
-        max_workers=1, thread_name_prefix="fleet-prefetch"
+        # ---- bucket by (model config, feature/target width) BEFORE fetching:
+        # widths come from the dataset's declared columns, so peak host memory
+        # is one bucket's data, not the whole fleet's ---------------------------
+        buckets: Dict[str, List[dict]] = {}
+        for machine, cache_key, eff_splits, eff_cv_parallel in pending:
+            dataset = _dataset_from_config(machine.data_config)
+            item: dict = {
+                "machine": machine,
+                "cache_key": cache_key,
+                "dataset": dataset,
+            }
+            if hasattr(dataset, "_columns_for"):
+                n_features = len(dataset._columns_for(dataset.tag_list))
+                n_targets = len(dataset._columns_for(dataset.target_tag_list))
+            elif multihost:  # non-TimeSeriesDataset: widths require a fetch —
+                # and multi-host bucketing must stay identical on every
+                # process, so a probe failure aborts (job-level retry) rather
+                # than diverging the collective program
+                X_probe, y_probe = dataset.get_data()
+                n_features, n_targets = X_probe.shape[1], y_probe.shape[1]
+                item["X"] = np.asarray(getattr(X_probe, "values", X_probe), np.float32)
+                item["y"] = np.asarray(getattr(y_probe, "values", y_probe), np.float32)
+                item["dataset_metadata"] = dataset.get_metadata()
+            else:  # single-host width probe: fetch with retry, isolating a
+                # terminally-failing machine BEFORE it ever buckets
+                error = _fetch_machine_data(item, fetch_retries, fetch_backoff)
+                if error is not None:
+                    logger.error(
+                        "Isolating machine %r from fleet build (width probe): %s",
+                        machine.name, error,
+                    )
+                    manifest[machine.name] = {"status": "failed", "error": error}
+                    journal.record(
+                        machine.name, store_journal.EVENT_FAILED, error=error
+                    )
+                    _M_FLEET_MACHINES.labels("failed").inc()
+                    continue
+                n_features, n_targets = item["X"].shape[1], item["y"].shape[1]
+            item["F"], item["T"] = n_features, n_targets
+            item["n_splits"] = eff_splits
+            # resolve the fold-execution mode NOW (None → the remat-derived
+            # default, readable straight off the config dict) so a machine whose
+            # explicit override merely restates the default still buckets — and
+            # batches — with its unannotated twins; different resolved modes are
+            # different compiled programs and bucket separately
+            item["cv_parallel"] = (
+                eff_cv_parallel
+                if eff_cv_parallel is not None
+                else _derived_cv_parallel(machine.model_config)
+            )
+            sig = json.dumps(
+                {
+                    "model_config": machine.model_config,
+                    "F": n_features,
+                    "T": n_targets,
+                    "n_splits": item["n_splits"],
+                    "cv_parallel": item["cv_parallel"],
+                },
+                sort_keys=True,
+                default=str,
+            )
+            buckets.setdefault(sig, []).append(item)
+
+        if any(
+            entry.get("status") == "failed" for entry in manifest.values()
+        ):
+            # probe-isolated machines must land in the on-disk manifest even
+            # when every remaining machine is cached (no slice write follows)
+            _write_manifest(
+                output_dir, manifest,
+                [m.name for m, *_ in pending if m.name not in manifest],
+                journal_counts=journal_counts,
+            )
+
+        master_key = jax.random.PRNGKey(seed)
+        pending_names = [machine.name for machine, *_ in pending]
+        checkpointer = _SliceCheckpointer(output_dir, mesh=mesh)
+        watchdog = _SliceWatchdog(multihost)
+        prefetcher = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="fleet-prefetch"
+        )
+        preamble["cached"] = len(results)
+        preamble["buckets"] = len(buckets)
+    logger.info(
+        "Fleet build: %d machines, %d cached, %d to build in %d bucket(s)",
+        len(machines), len(results), len(pending), len(buckets),
     )
+
     try:
         for b, (sig, items) in enumerate(sorted(buckets.items())):
             bucket_started = time.perf_counter()
-            model_config = items[0]["machine"].model_config
-            probe = pipeline_from_definition(model_config)
-            analyzed = _analyze_model(probe)
-            n_features = items[0]["F"]
-            n_targets = items[0]["T"]
-            bucket_splits = items[0]["n_splits"]
-            spec = _spec_for(
-                analyzed,
-                n_features,
-                n_targets,
-                bucket_splits,
-                cv_parallel=items[0]["cv_parallel"],
-            )
+            with spans.stage(
+                "fleet.bucket", bucket=b, machines=len(items)
+            ) as bucket:
+                model_config = items[0]["machine"].model_config
+                probe = pipeline_from_definition(model_config)
+                analyzed = _analyze_model(probe)
+                n_features = items[0]["F"]
+                n_targets = items[0]["T"]
+                bucket_splits = items[0]["n_splits"]
+                spec = _spec_for(
+                    analyzed,
+                    n_features,
+                    n_targets,
+                    bucket_splits,
+                    cv_parallel=items[0]["cv_parallel"],
+                )
 
-            # ---- slice the bucket: each slice is an independent failure domain
-            # with its own data fetch, train call, and artifact writes. All
-            # slices share one padded machine count so the compiled executable
-            # is reused (fleet_program caches on spec+shape) --------------------
-            n_real = len(items)
-            eff = n_real if not slice_size else min(slice_size, n_real)
-            n_padded = pad_to_multiple(eff, mesh.size) if mesh is not None else eff
-            slices = [items[s : s + eff] for s in range(0, n_real, eff)]
-            logger.info(
-                "Fleet bucket %d/%d: %d machines in %d slice(s) of %d "
-                "(padded %d), F=%d",
-                b + 1,
-                len(buckets),
-                n_real,
-                len(slices),
-                eff,
-                n_padded,
-                n_features,
-            )
-            quantize_rows = len(slices) > 1
-            span = _local_machine_span(mesh, n_padded) if multihost else None
-            # single-host transfer overlap (see _prepare_slice): the worker
-            # device-places a prepared slice when the bucket's executable
-            # already exists. Memory-constrained (remat) buckets keep the
-            # batch on host until their own turn — their peak-HBM budget
-            # has no room for a second slice's buffers
-            place = (
-                (spec, mesh)
-                if (not multihost and spec.widen_predict)
-                else None
-            )
-            prepared = prefetcher.submit(
-                _prepare_slice,
-                slices[0], n_padded, n_features, n_targets, quantize_rows,
-                span, place, fetch_retries, fetch_backoff,
-            )
-            for s, slice_items in enumerate(slices):
-                # armed only multi-host + GORDO_SLICE_TIMEOUT_S: if THIS
-                # iteration stalls past the budget (dead peer -> blocked
-                # collective), the process exits EXIT_RETRYABLE for the
-                # job layer to restart; disarmed at iteration end below
-                # and in the outer finally
-                watchdog.start(b, s)
-                slice_started = time.perf_counter()
-                X, y, w, n_rows, fetch_s = prepared.result()
-                timer.add("data_fetch", fetch_s)
-                if s + 1 < len(slices):
-                    prepared = prefetcher.submit(
-                        _prepare_slice,
-                        slices[s + 1], n_padded, n_features, n_targets,
-                        quantize_rows, span, place, fetch_retries,
+                # ---- slice the bucket: each slice is an independent failure
+                # domain with its own data fetch, train call, and artifact
+                # writes. All slices share one padded machine count so the
+                # compiled executable is reused (fleet_program caches on
+                # spec+shape) ---------------------------------------------------
+                n_real = len(items)
+                eff = n_real if not slice_size else min(slice_size, n_real)
+                n_padded = (
+                    pad_to_multiple(eff, mesh.size) if mesh is not None else eff
+                )
+                slices = [items[s : s + eff] for s in range(0, n_real, eff)]
+                bucket["slices"] = len(slices)
+                logger.info(
+                    "Fleet bucket %d/%d: %d machines in %d slice(s) of %d "
+                    "(padded %d), F=%d",
+                    b + 1,
+                    len(buckets),
+                    n_real,
+                    len(slices),
+                    eff,
+                    n_padded,
+                    n_features,
+                )
+                span = _local_machine_span(mesh, n_padded) if multihost else None
+                # single-host transfer overlap (see _prepare_slice): the
+                # worker device-places a prepared slice when the bucket's
+                # executable already exists. Memory-constrained (remat)
+                # buckets keep the batch on host until their own turn — their
+                # peak-HBM budget has no room for a second slice's buffers
+                place = (
+                    (spec, mesh)
+                    if (not multihost and spec.widen_predict)
+                    else None
+                )
+                # the prefetch worker inherits no context: it binds this
+                # one, so every fleet.prepare hangs under this bucket's
+                # stage (beside the slices it overlaps, not inside one)
+                seam = spans.capture()
+
+                def prefetch(s: int):
+                    return prefetcher.submit(
+                        _prepare_bound, seam, b, s,
+                        slices[s], n_padded, n_features, n_targets,
+                        len(slices) > 1, span, place, fetch_retries,
                         fetch_backoff,
                     )
-                keys = jax.random.split(
-                    jax.random.fold_in(jax.random.fold_in(master_key, b), s),
-                    n_padded,
-                )
 
-                if multihost:
-                    # main thread only (see _prepare_slice): agree on the
-                    # global row width, then lift the process-local shards
-                    # into one global batch — ingest stayed process-local
-                    # and overlapped, only this assembly is synchronous
-                    from jax.experimental import multihost_utils
-
-                    from .mesh import fleet_sharding
-
-                    n_rows_global = int(
-                        multihost_utils.process_allgather(
-                            np.asarray([n_rows])
-                        ).max()
-                    )
-                    if n_rows_global != n_rows:
-                        # leading pad keeps every machine right-aligned
-                        pad = ((0, 0), (n_rows_global - n_rows, 0))
-                        X = np.pad(X, pad + ((0, 0),))
-                        y = np.pad(y, pad + ((0, 0),))
-                        w = np.pad(w, pad)
-                        n_rows = n_rows_global
-                    sharding = fleet_sharding(mesh)
-                    lo, hi = span
-                    batch = MachineBatch(
-                        X=jax.make_array_from_process_local_data(sharding, X),
-                        y=jax.make_array_from_process_local_data(sharding, y),
-                        w=jax.make_array_from_process_local_data(sharding, w),
-                        keys=jax.make_array_from_process_local_data(
-                            sharding, np.asarray(keys)[lo:hi]
-                        ),
-                    )
-                else:
-                    batch = MachineBatch(X=X, y=y, w=w, keys=keys)
-
-                ckpt_key = checkpointer.slice_key(slice_items)
-                trained_on = None  # a restored slice trained in another run
-                result = checkpointer.try_restore(
-                    ckpt_key,
-                    lambda: _abstract_result(
-                        spec, n_padded, n_rows, n_features, n_targets
-                    ),
-                )
-                if result is None:
-                    with timer.phase("train"), device_trace(profile_dir):
-                        result = train_fleet_arrays(spec, batch, mesh=mesh)
-                        trained_on = _device_summary(result.loss_history)
-                        if not multihost:
-                            result = jax.device_get(result)
-                    # async: orbax writes in the background while the
-                    # artifact loop below runs (multi-host: a COLLECTIVE
-                    # save of the sharded result); finalize() joins + deletes
-                    checkpointer.save_async(ckpt_key, result)
-                if multihost:
-                    # restored or trained, the result is globally sharded:
-                    # pull only this process's machine block to host
-                    result = _gather_local_block(result)
-                slice_duration = time.perf_counter() - slice_started
-
-                if multihost:
-                    lo, hi = span
-                    # this process's machines only; result rows are the
-                    # local block, so indices shift by lo
-                    indexed_items = [
-                        (i - lo, item)
-                        for i, item in enumerate(slice_items)
-                        if lo <= i < hi
-                    ]
-                else:
-                    indexed_items = list(enumerate(slice_items))
-
-                with timer.phase("artifacts"):
-                    # ---- per-machine artifacts (same format as the single path),
-                    # written before the next slice trains so a kill loses at most
-                    # the in-flight slice ------------------------------------------
-                    for i, item in indexed_items:
-                        machine = item["machine"]
-                        if "build_error" in item:
-                            # isolated at fetch: trained as zero-weight
-                            # padding; no artifact, no registry key — the
-                            # next run retries it from scratch
-                            manifest[machine.name] = {
-                                "status": "failed",
-                                "error": item["build_error"],
-                                "bucket": b,
-                                "slice": s,
-                            }
-                            journal.record(
-                                machine.name,
-                                store_journal.EVENT_FAILED,
-                                error=item["build_error"],
-                            )
-                            _M_FLEET_MACHINES.labels("failed").inc()
-                            continue
-                        model = pipeline_from_definition(machine.model_config)
-                        _install_result(
-                            model, result, i, n_features, n_targets, bucket_splits
-                        )
-                        model_dir = os.path.join(output_dir, machine.name)
-                        # same metadata contract as the single-machine builder
-                        # (consumers read these keys uniformly off the shared
-                        # registry); per-machine durations are the slice's amortized
-                        # share
-                        amortized = slice_duration / max(len(slice_items), 1)
-                        metadata = {
-                            "name": machine.name,
-                            "gordo_components_tpu_version": __version__,
-                            "model": {
-                                "model_config": machine.model_config,
-                                "model_builder_metadata": (
-                                    model.get_metadata()
-                                    if hasattr(model, "get_metadata")
-                                    else {}
+                prepared = prefetch(0)
+                # --trace-dir: ONE profiler session per job, around one whole
+                # steady slice (the first of a job holds the compile), from
+                # its wait for the prefetch to its checkpoint's end — host
+                # phases and the idle gaps between them included
+                traced_slice = min(1, len(slices) - 1) if b == 0 else None
+                for s, slice_items in enumerate(slices):
+                    # armed only multi-host + GORDO_SLICE_TIMEOUT_S: if THIS
+                    # iteration stalls past the budget (dead peer -> blocked
+                    # collective), the process exits EXIT_RETRYABLE for the
+                    # job layer to restart; disarmed at iteration end below
+                    # and in the outer finally
+                    watchdog.start(b, s)
+                    with device_trace(
+                        profile_dir if s == traced_slice else None
+                    ), spans.stage(
+                        "fleet.slice", bucket=b, slice=s,
+                        machines=len(slice_items),
+                    ) as sliced:
+                        slice_started = time.perf_counter()
+                        with spans.stage("fleet.prefetch_wait"):
+                            X, y, w, n_rows = prepared.result()
+                        if s + 1 < len(slices):
+                            prepared = prefetch(s + 1)
+                        with spans.stage(
+                            "fleet.ingest", step="assemble",
+                            placed_by_prefetch=isinstance(X, jax.Array),
+                        ) as ingested:
+                            keys = jax.random.split(
+                                jax.random.fold_in(
+                                    jax.random.fold_in(master_key, b), s
                                 ),
-                                "cross_validation": _cv_metadata(result, i, bucket_splits),
-                                "model_training_duration_s": amortized,
-                                "model_creation_date": time.strftime(
-                                    "%Y-%m-%d %H:%M:%S%z"
-                                ),
-                                "cache_key": item["cache_key"],
-                                "fleet": {
-                                    "bucket": b,
-                                    "bucket_size": n_real,
-                                    "slice": s,
-                                    "slice_size": len(slice_items),
-                                    "slice_duration_s": slice_duration,
-                                    # fold-execution mode that trained this
-                                    # artifact (provenance; not in the cache
-                                    # key — see evaluation_config above)
-                                    "cv_parallel": bool(spec.cv_parallel),
-                                    # where the trained arrays lived, read
-                                    # off their sharding — JAX hands back
-                                    # the CPU without failing when it
-                                    # cannot get the chip, and the artifact
-                                    # should say which one trained it
-                                    "devices": trained_on,
-                                },
-                            },
-                            "dataset": item["dataset_metadata"],
-                            "build_duration_s": amortized,
-                            "user_defined": dict(machine.metadata),
-                            # §19: the manifest pin the serving layers read
-                            "precision": precision_of(machine.name),
-                        }
-                        # WAL first, then the atomic generation commit,
-                        # then registry + committed record: a crash at any
-                        # point leaves either no trace (redo) or a whole,
-                        # verifiable artifact (skip) — never a torn dir a
-                        # resume would trust
-                        journal.record(
-                            machine.name,
-                            store_journal.EVENT_STARTED,
-                            cache_key=item["cache_key"],
-                            bucket=b,
-                            slice=s,
-                        )
-                        commit_generation(
-                            model_dir,
-                            lambda staging: write_artifact_files(
-                                model, staging, metadata=metadata,
-                                precision=precision_of(machine.name),
-                            ),
-                            name=machine.name,
-                        )
-                        if model_register_dir:
-                            disk_registry.write_key(
-                                model_register_dir, item["cache_key"], model_dir
+                                n_padded,
                             )
-                        journal.record(
-                            machine.name,
-                            store_journal.EVENT_COMMITTED,
-                            cache_key=item["cache_key"],
-                            model_dir=model_dir,
-                        )
-                        journal_counts["rebuilt"] += 1
-                        results[machine.name] = model_dir
-                        _M_FLEET_MACHINES.labels("completed").inc()
-                        _M_MACHINE_BUILD_SECONDS.labels(machine.name).set(
-                            amortized
-                        )
-                        manifest[machine.name] = {
-                            "status": "completed",
-                            "model_dir": model_dir,
+                            if multihost:
+                                batch, n_rows = _global_batch(
+                                    X, y, w, n_rows, keys, mesh, span
+                                )
+                            else:
+                                batch = MachineBatch(X=X, y=y, w=w, keys=keys)
+                            ingested["bytes"] = X.nbytes + y.nbytes + w.nbytes
+                        sliced["n_rows"] = n_rows
+
+                        ckpt_key = checkpointer.slice_key(slice_items)
+                        trained_on = None  # a restored slice trained in another run
+                        with spans.stage("fleet.checkpoint_restore") as restore:
+                            result = checkpointer.try_restore(
+                                ckpt_key,
+                                lambda: _abstract_result(
+                                    spec, n_padded, n_rows, n_features, n_targets
+                                ),
+                            )
+                            restore["hit"] = result is not None
+                        if result is None:
+                            # stages fleet.program, fleet.ingest (the
+                            # device_put) and fleet.execute, which ends when
+                            # the result is ready on the device
+                            result = train_fleet_arrays(spec, batch, mesh=mesh)
+                            trained_on = _device_summary(result.loss_history)
+                            if not multihost:
+                                with spans.stage("fleet.result_fetch") as fetched:
+                                    result = jax.device_get(result)
+                                    fetched["bytes"] = sum(
+                                        leaf.nbytes for leaf in
+                                        jax.tree_util.tree_leaves(result)
+                                    )
+                            # async: orbax writes in the background while the
+                            # artifact loop below runs (multi-host: a
+                            # COLLECTIVE save of the sharded result);
+                            # finalize() joins + deletes
+                            with spans.stage("fleet.checkpoint_save"):
+                                checkpointer.save_async(ckpt_key, result)
+                        if multihost:
+                            # restored or trained, the result is globally
+                            # sharded: pull only this process's machine block
+                            # to host
+                            with spans.stage("fleet.result_fetch"):
+                                result = _gather_local_block(result)
+                        slice_duration = time.perf_counter() - slice_started
+
+                        if multihost:
+                            lo, hi = span
+                            # this process's machines only; result rows are
+                            # the local block, so indices shift by lo
+                            indexed_items = [
+                                (i - lo, item)
+                                for i, item in enumerate(slice_items)
+                                if lo <= i < hi
+                            ]
+                        else:
+                            indexed_items = list(enumerate(slice_items))
+                        provenance = {
                             "bucket": b,
+                            "bucket_size": n_real,
                             "slice": s,
+                            "slice_size": len(slice_items),
+                            "slice_duration_s": slice_duration,
+                            # fold-execution mode that trained this artifact
+                            # (provenance; not in the cache key — see
+                            # evaluation_config above)
+                            "cv_parallel": bool(spec.cv_parallel),
+                            # where the trained arrays lived, read off their
+                            # sharding — JAX hands back the CPU without
+                            # failing when it cannot get the chip, and the
+                            # artifact should say which one trained it
+                            "devices": trained_on,
                         }
-                    _write_manifest(
-                        output_dir,
-                        manifest,
-                        [name for name in (m.name for m, *_ in pending) if name not in manifest],
-                        journal_counts=journal_counts,
-                    )
-                with timer.phase("checkpoint_wait"):
-                    # artifacts durable → join the async save, drop the ckpt
-                    # (multi-host: barrier, then process 0 deletes)
-                    checkpointer.finalize(ckpt_key)
-                for item in slice_items:  # free before the next slice fetches
-                    item.pop("X", None)
-                    item.pop("y", None)
-                watchdog.stop()  # this slice made liveness; next start()
-                # re-arms with a fresh budget
-            bucket_duration = time.perf_counter() - bucket_started
+
+                        # ---- per-machine artifacts (same format as the
+                        # single path), written before the next slice trains
+                        # so a kill loses at most the in-flight slice --------
+                        with spans.stage("fleet.commit_loop"):
+                            for i, item in indexed_items:
+                                name = item["machine"].name
+                                with spans.stage(
+                                    "fleet.commit", machine=name
+                                ) as commit:
+                                    if "build_error" in item:
+                                        # isolated at fetch: trained as
+                                        # zero-weight padding; no artifact, no
+                                        # registry key — the next run retries
+                                        # it from scratch
+                                        manifest[name] = {
+                                            "status": "failed",
+                                            "error": item["build_error"],
+                                            "bucket": b,
+                                            "slice": s,
+                                        }
+                                        journal.record(
+                                            name,
+                                            store_journal.EVENT_FAILED,
+                                            error=item["build_error"],
+                                        )
+                                        _M_FLEET_MACHINES.labels("failed").inc()
+                                        commit["outcome"] = "failed"
+                                        continue
+                                    model_dir = _commit_machine(
+                                        item, result, i,
+                                        (n_features, n_targets, bucket_splits),
+                                        provenance, output_dir,
+                                        model_register_dir,
+                                        precision_of(name), journal,
+                                    )
+                                    journal_counts["rebuilt"] += 1
+                                    results[name] = model_dir
+                                    _M_FLEET_MACHINES.labels("completed").inc()
+                                    manifest[name] = {
+                                        "status": "completed",
+                                        "model_dir": model_dir,
+                                        "bucket": b,
+                                        "slice": s,
+                                    }
+                                    commit["outcome"] = "completed"
+                        with spans.stage("fleet.manifest"):
+                            _write_manifest(
+                                output_dir,
+                                manifest,
+                                [n for n in pending_names if n not in manifest],
+                                journal_counts=journal_counts,
+                            )
+                        with spans.stage("fleet.checkpoint_wait"):
+                            # artifacts durable → join the async save, drop
+                            # the ckpt (multi-host: barrier, then process 0
+                            # deletes)
+                            checkpointer.finalize(ckpt_key)
+                        for item in slice_items:  # free before the next fetch
+                            item.pop("X", None)
+                            item.pop("y", None)
+                    watchdog.stop()  # this slice made liveness; next start()
+                    # re-arms with a fresh budget
             logger.info(
-                "Fleet bucket %d/%d done in %.1fs", b + 1, len(buckets), bucket_duration
+                "Fleet bucket %d/%d done in %.1fs",
+                b + 1, len(buckets), time.perf_counter() - bucket_started,
             )
 
     finally:
@@ -1507,14 +1577,108 @@ def build_fleet(
         prefetcher.shutdown(wait=True, cancel_futures=True)
         checkpointer.join()
     checkpointer.close()
-    # phase totals land in the same registry serving scrapes, under the
-    # fleet prefix so single-machine and fleet builds stay distinguishable
-    timer.publish(prefix="gordo_fleet_build")
-    logger.info(
-        "Fleet build: %d machines in %.1fs (%d cached); phases: %s",
-        len(machines),
-        time.perf_counter() - started,
-        len(machines) - len(pending),
-        timer.report(),
-    )
     return results
+
+
+def _global_batch(X, y, w, n_rows: int, keys, mesh, span: Tuple[int, int]):
+    """Multi-host, main thread only (see :func:`_prepare_slice`): agree on
+    the global row width, then lift the process-local shards into one
+    global batch — ingest stayed process-local and overlapped, only this
+    assembly is synchronous. Returns ``(batch, n_rows)``."""
+    from jax.experimental import multihost_utils
+
+    from .mesh import fleet_sharding
+
+    n_rows_global = int(
+        multihost_utils.process_allgather(np.asarray([n_rows])).max()
+    )
+    if n_rows_global != n_rows:
+        # leading pad keeps every machine right-aligned
+        pad = ((0, 0), (n_rows_global - n_rows, 0))
+        X = np.pad(X, pad + ((0, 0),))
+        y = np.pad(y, pad + ((0, 0),))
+        w = np.pad(w, pad)
+        n_rows = n_rows_global
+    sharding = fleet_sharding(mesh)
+    lo, hi = span
+    batch = MachineBatch(
+        X=jax.make_array_from_process_local_data(sharding, X),
+        y=jax.make_array_from_process_local_data(sharding, y),
+        w=jax.make_array_from_process_local_data(sharding, w),
+        keys=jax.make_array_from_process_local_data(
+            sharding, np.asarray(keys)[lo:hi]
+        ),
+    )
+    return batch, n_rows
+
+
+def _commit_machine(
+    item: dict,
+    result,
+    i: int,
+    shape: Tuple[int, int, int],
+    provenance: Dict[str, Any],
+    output_dir: str,
+    model_register_dir: Optional[str],
+    precision: str,
+    journal,
+) -> str:
+    """Machine ``i`` of a trained slice → its artifact, durable: the model
+    graph with the slice's result installed, metadata, WAL record, atomic
+    generation commit, registry key. Returns the model dir."""
+    machine = item["machine"]
+    n_features, n_targets, n_splits = shape
+    model = pipeline_from_definition(machine.model_config)
+    _install_result(model, result, i, n_features, n_targets, n_splits)
+    model_dir = os.path.join(output_dir, machine.name)
+    # same metadata contract as the single-machine builder (consumers read
+    # these keys uniformly off the shared registry); per-machine durations
+    # are the slice's amortized share
+    amortized = provenance["slice_duration_s"] / max(provenance["slice_size"], 1)
+    metadata = {
+        "name": machine.name,
+        "gordo_components_tpu_version": __version__,
+        "model": {
+            "model_config": machine.model_config,
+            "model_builder_metadata": (
+                model.get_metadata() if hasattr(model, "get_metadata") else {}
+            ),
+            "cross_validation": _cv_metadata(result, i, n_splits),
+            "model_training_duration_s": amortized,
+            "model_creation_date": time.strftime("%Y-%m-%d %H:%M:%S%z"),
+            "cache_key": item["cache_key"],
+            "fleet": provenance,
+        },
+        "dataset": item["dataset_metadata"],
+        "build_duration_s": amortized,
+        "user_defined": dict(machine.metadata),
+        # §19: the manifest pin the serving layers read
+        "precision": precision,
+    }
+    # WAL first, then the atomic generation commit, then registry +
+    # committed record: a crash at any point leaves either no trace (redo)
+    # or a whole, verifiable artifact (skip) — never a torn dir a resume
+    # would trust
+    journal.record(
+        machine.name,
+        store_journal.EVENT_STARTED,
+        cache_key=item["cache_key"],
+        bucket=provenance["bucket"],
+        slice=provenance["slice"],
+    )
+    commit_generation(
+        model_dir,
+        lambda staging: write_artifact_files(
+            model, staging, metadata=metadata, precision=precision
+        ),
+        name=machine.name,
+    )
+    if model_register_dir:
+        disk_registry.write_key(model_register_dir, item["cache_key"], model_dir)
+    journal.record(
+        machine.name,
+        store_journal.EVENT_COMMITTED,
+        cache_key=item["cache_key"],
+        model_dir=model_dir,
+    )
+    return model_dir

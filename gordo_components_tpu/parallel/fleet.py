@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.train import FitResult, make_fit_fn, make_predict_fn
+from ..observability import spans
 from ..observability.registry import REGISTRY
 from ..ops import windowing
 from ..ops.scaling import ScalerParams
@@ -428,7 +429,8 @@ def make_machine_program(
             fits = jax.vmap(
                 lambda wv, kv: fit_local(params0, inputs, targets, wv, kv)
             )(all_w, all_keys)
-            preds = jax.vmap(predict_all)(fits.params)  # (K+1, P, T)
+            with jax.named_scope("cv_predict"):
+                preds = jax.vmap(predict_all)(fits.params)  # (K+1, P, T)
             preds_raw = (preds - sy.offset) / sy.scale
             errs_all = jnp.abs(raw_targets[None] - preds_raw)
             fold_errors, err_final = errs_all[:-1], errs_all[-1]
@@ -466,7 +468,8 @@ def make_machine_program(
                     res = fit_local(
                         params0, inputs, targets, wt * train_mask, fold_key
                     )
-                    pred = predict_all(res.params)
+                    with jax.named_scope("cv_predict"):
+                        pred = predict_all(res.params)
                     pred_raw = (pred - sy.offset) / sy.scale
                     err = jnp.abs(raw_targets - pred_raw)
                     # rank-space folds guarantee a nonempty train region
@@ -502,38 +505,40 @@ def make_machine_program(
             # source when CV is off, and the per-machine fallback when no CV
             # fold covered this machine's data (short machine in a tall
             # bucket)
-            pred_final = predict_all(final.params)
+            with jax.named_scope("final_predict"):
+                pred_final = predict_all(final.params)
             pred_final_raw = (pred_final - sy.offset) / sy.scale
             err_final = jnp.abs(raw_targets - pred_final_raw)
-        mask_final = (wt > 0)[:, None]
-        fmin = jnp.min(jnp.where(mask_final, err_final, jnp.inf), axis=0)
-        fmax = jnp.max(jnp.where(mask_final, err_final, -jnp.inf), axis=0)
+        with jax.named_scope("error_scaler_thresholds"):
+            mask_final = (wt > 0)[:, None]
+            fmin = jnp.min(jnp.where(mask_final, err_final, jnp.inf), axis=0)
+            fmax = jnp.max(jnp.where(mask_final, err_final, -jnp.inf), axis=0)
 
-        use_cv = jnp.sum(fold_test_masks) > 0
-        emin = jnp.where(use_cv, emin, fmin)
-        emax = jnp.where(use_cv, emax, fmax)
-        emin = jnp.where(jnp.isfinite(emin), emin, 0.0)
-        emax = jnp.where(jnp.isfinite(emax), emax, 1.0)
-        span = emax - emin
-        e_scale = 1.0 / jnp.where(span < _EPS, 1.0, span)
-        error_scaler = ScalerParams(scale=e_scale, offset=-emin * e_scale)
+            use_cv = jnp.sum(fold_test_masks) > 0
+            emin = jnp.where(use_cv, emin, fmin)
+            emax = jnp.where(use_cv, emax, fmax)
+            emin = jnp.where(jnp.isfinite(emin), emin, 0.0)
+            emax = jnp.where(jnp.isfinite(emax), emax, 1.0)
+            span = emax - emin
+            e_scale = 1.0 / jnp.where(span < _EPS, 1.0, span)
+            error_scaler = ScalerParams(scale=e_scale, offset=-emin * e_scale)
 
-        # thresholds: 99th percentile of scaled residuals — out-of-fold when
-        # CV covered this machine, final-model residuals otherwise
-        errs = jnp.concatenate([fold_errors, err_final[None]])  # (K+1, P, T)
-        fallback_mask = wt * jnp.where(use_cv, 0.0, 1.0)
-        masks = jnp.concatenate(
-            [fold_test_masks, fallback_mask[None]]
-        )  # (K+1, P)
-        scaled = errs * error_scaler.scale + error_scaler.offset
-        scaled = jnp.where((masks > 0)[:, :, None], scaled, jnp.nan)
-        tag_thresholds = jnp.nan_to_num(
-            jnp.nanpercentile(scaled.reshape(-1, n_targets), 99, axis=0)
-        )
-        norms = jnp.linalg.norm(
-            jnp.nan_to_num(scaled), axis=-1
-        ) + jnp.where(masks > 0, 0.0, jnp.nan)
-        total_threshold = jnp.nan_to_num(jnp.nanpercentile(norms, 99))
+            # thresholds: 99th percentile of scaled residuals — out-of-fold
+            # when CV covered this machine, final-model residuals otherwise
+            errs = jnp.concatenate([fold_errors, err_final[None]])  # (K+1, P, T)
+            fallback_mask = wt * jnp.where(use_cv, 0.0, 1.0)
+            masks = jnp.concatenate(
+                [fold_test_masks, fallback_mask[None]]
+            )  # (K+1, P)
+            scaled = errs * error_scaler.scale + error_scaler.offset
+            scaled = jnp.where((masks > 0)[:, :, None], scaled, jnp.nan)
+            tag_thresholds = jnp.nan_to_num(
+                jnp.nanpercentile(scaled.reshape(-1, n_targets), 99, axis=0)
+            )
+            norms = jnp.linalg.norm(
+                jnp.nan_to_num(scaled), axis=-1
+            ) + jnp.where(masks > 0, 0.0, jnp.nan)
+            total_threshold = jnp.nan_to_num(jnp.nanpercentile(norms, 99))
 
         return MachineResult(
             params=final.params,
@@ -837,6 +842,13 @@ def train_fleet_arrays(
     Host arrays are device-placed layout-matched via the AOT executable
     (:func:`fleet_executable`); keys uint32 dtype aside, any float inputs
     are accepted as-is.
+
+    The three things it does are three stages (``observability.spans``):
+    ``fleet.program`` (executable memo hit, or lower + compile),
+    ``fleet.ingest`` (the layout-matched ``device_put``; a no-op for
+    arrays the prefetch worker already placed) and ``fleet.execute``
+    (dispatch until the result is ready on the device, so the call
+    returns a finished result).
     """
     n_machines, n_rows, n_features = batch.X.shape
     n_targets = batch.y.shape[2]
@@ -846,8 +858,24 @@ def train_fleet_arrays(
             f"{mesh.size}; pad with zero-weight machines "
             "(build_fleet does this automatically)"
         )
-    compiled, formats = fleet_executable(
-        spec, n_machines, n_rows, n_features, n_targets, mesh=mesh
-    )
-    placed = put_fleet_batch(batch, formats)
-    return compiled(placed.X, placed.y, placed.w, placed.keys)
+    shape = (n_machines, n_rows, n_features, n_targets)
+    with spans.stage("fleet.program") as found:
+        found["memo_hit"] = (
+            peek_fleet_executable(spec, *shape, mesh=mesh) is not None
+        )
+        lookup_started = time.perf_counter()
+        compiled, formats = fleet_executable(spec, *shape, mesh=mesh)
+        # lower + compile (or the persistent cache's load) on a miss
+        found["compile_s"] = (
+            0.0 if found["memo_hit"]
+            else time.perf_counter() - lookup_started
+        )
+    with spans.stage("fleet.ingest", step="device_put"):
+        placed = put_fleet_batch(batch, formats)
+    with spans.stage("fleet.execute"):
+        result = compiled(placed.X, placed.y, placed.w, placed.keys)
+        # the span ends where the device does. Every output is the one
+        # program's and they become ready together, and every caller
+        # reads them next, so the wait serialises nothing
+        jax.block_until_ready(result)
+    return result

@@ -896,7 +896,7 @@ def test_prepare_slice_places_on_device_when_executable_cached():
         return isinstance(a, jax.Array)
 
     # fresh shape, nothing compiled -> stays host-side even with place
-    X, y, w, n_rows, _ = _prepare_slice(
+    X, y, w, n_rows = _prepare_slice(
         [dict(i) for i in items], 2, 3, 3, False, None, place
     )
     if peek_fleet_executable(spec, 2, n_rows, 3, 3) is None:
@@ -905,7 +905,7 @@ def test_prepare_slice_places_on_device_when_executable_cached():
     # compile the executable, then the SAME call must come back placed
     # (unless this backend exposes no input formats — then it stays host)
     compiled, formats = fleet_executable(spec, 2, n_rows, 3, 3)
-    X2, y2, w2, n_rows2, _ = _prepare_slice(
+    X2, y2, w2, n_rows2 = _prepare_slice(
         [dict(i) for i in items], 2, 3, 3, False, None, place
     )
     assert n_rows2 == n_rows
@@ -948,7 +948,7 @@ def test_prepare_slice_fetches_machines_concurrently():
 
     items = [_item(SlowDataset(float(i)), f"c-{i}") for i in range(4)]
     started = _time.perf_counter()
-    X, y, w, n_rows, fetch_s = _prepare_slice(items, 4, 3, 3, False)
+    X, y, w, n_rows = _prepare_slice(items, 4, 3, 3, False)
     wall = _time.perf_counter() - started
     assert wall < 0.6, f"serial fetch? {wall:.2f}s"
     for i in range(4):
@@ -960,7 +960,7 @@ def test_prepare_slice_fetches_machines_concurrently():
             raise RuntimeError("lake exploded")
 
     items = [_item(SlowDataset(7.0), "ok-m"), _item(BoomDataset(1.0), "boom-m")]
-    X, y, w, n_rows, _ = _prepare_slice(
+    X, y, w, n_rows = _prepare_slice(
         items, 2, 3, 3, False, None, None, 0,  # fetch_retries=0: no backoff
     )
     assert "build_error" not in items[0]

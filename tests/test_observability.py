@@ -262,16 +262,6 @@ def test_log_record_factory_stamps_trace_id(caplog):
     assert outside.trace_id == ""
 
 
-def test_span_records_duration_histogram():
-    with tracing.trace():
-        with tracing.span("test.unit"):
-            pass
-    stats = REGISTRY.histogram(
-        "gordo_span_seconds", labels=("name",)
-    ).stats()
-    assert stats[("test.unit",)]["count"] >= 1
-
-
 def test_json_formatter_includes_trace_fields():
     tracing.install_log_record_factory()
     with tracing.trace("feedface00000000"):

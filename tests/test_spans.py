@@ -144,7 +144,12 @@ def test_flight_recorder_ring_is_bounded_but_reservoirs_persist():
     recorder.record(_finished_timeline("slow-one", duration=9.0))
     recorder.record(_finished_timeline("bad-one", error="HTTP 503"))
     for i in range(10):
-        recorder.record(_finished_timeline(f"fast-{i}", duration=0.001))
+        # each a step slower than the one before: which of them the slow
+        # reservoir's second place ends up holding is then the last, not
+        # whichever the clock's jitter made longest
+        recorder.record(
+            _finished_timeline(f"fast-{i}", duration=0.01 * (i + 1))
+        )
     body = recorder.summaries(limit=50)
     assert body["recorded"] == 12
     assert body["kept"] == 4  # ring holds only the newest 4
