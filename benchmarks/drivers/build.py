@@ -218,6 +218,8 @@ def read_artifact(model_dir: str, probe: np.ndarray) -> Dict[str, Any]:
         "input_scale": np.asarray(parts.input_scaler.params_.scale),
         "input_offset": np.asarray(parts.input_scaler.params_.offset),
         "target_scale": np.asarray(parts.target_scaler.params_.scale),
+        "error_scale": np.asarray(parts.detector.scaler.params_.scale),
+        "error_offset": np.asarray(parts.detector.scaler.params_.offset),
         "cv_mse": np.asarray(
             [s["scores"]["mean_squared_error"] for s in cv["splits"]], np.float64
         ),
@@ -292,7 +294,7 @@ def reference_results(
         raws.append(raw)
         slice_index, position = divmod(index, slice_size)
         keys.append(np.asarray(program_keys(run["seed"], slice_index, slice_size)[position]))
-    dtype = jnp.float32 if dtype is None else dtype
+    dtype = jnp.dtype(jnp.float32 if dtype is None else dtype)
     build, anomaly = ref_build.make_build(
         model, n_rows, config["tags"], dtype=dtype, fault=fault
     )
@@ -324,23 +326,99 @@ def reference_results(
     return results
 
 
+def replay_anomaly(run: Dict[str, Any], built: List[Dict[str, Any]], probes) -> None:
+    """``anomaly_replayed`` of each built machine: the mean anomaly score of
+    its probe rows as the plain reference's arithmetic (float32 at
+    ``highest``) gives it from that machine's OWN committed parameters and
+    scalers. Beside the machine's ``anomaly_mean`` (the loaded model's
+    ``anomaly()``) it holds the serving side of an artifact to plain
+    arithmetic on the same weights: one forward pass apart, where two
+    trainings would be a whole fit apart. Whether the weights are the right
+    ones is the other numbers' to say, from the reference's own training."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.reference import build as ref_build
+
+    config = run["config"]
+    probes = np.asarray(probes, np.float32)
+    _, anomaly = ref_build.make_build(
+        config["reference_model"], probes.shape[1], config["tags"], dtype=jnp.float32
+    )
+    keys = ("params", "input_scale", "input_offset", "error_scale", "error_offset")
+    stacked = jax.tree_util.tree_map(
+        lambda *leaves: np.stack([np.asarray(leaf, np.float32) for leaf in leaves]),
+        *[{k: machine[k] for k in keys} for machine in built],
+    )
+    with jax.default_matmul_precision("highest"):
+        out = jax.device_get(jax.jit(jax.vmap(anomaly))(stacked, probes))
+    for machine, value in zip(built, out):
+        machine["anomaly_replayed"] = float(value)
+
+
+# What readings and tests put in the program's place: the plain reference
+# itself, built another way. Each name maps to ``reference_results``' own
+# arguments.
+STAND_INS = {
+    # the same mathematics at the precision the configuration states
+    # (float32, matmuls at JAX's default: one bf16 pass on the chip), in
+    # code, batching and reduction order that share nothing with the
+    # program's: what a sound rewrite of the program may read. No fault.
+    "sound_default_precision": {"precision": None},
+    # the control: the nearest precision below the configuration's
+    "control_bf16": {"dtype": "bfloat16", "precision": None},
+    # the faults ``reference/build.py`` can plant
+    "half_batch": {"fault": "half_batch"},
+    "state_unchanged": {"fault": "state_unchanged"},
+}
+
+
+def stand_in_numbers(
+    run: Dict[str, Any], sample: List[int], n_rows: int,
+    references: List[Dict[str, Any]], variant: str,
+) -> List[Dict[str, float]]:
+    """Per-machine numbers of one stand-in against the reference."""
+    from benchmarks.reference import compare
+
+    if variant not in STAND_INS:
+        raise SystemExit(f"bench: no stand-in or fault named {variant!r}")
+    stood_in = reference_results(run, sample, n_rows, **STAND_INS[variant])
+    replay_anomaly(run, stood_in, [s["probe"] for s in stood_in])
+    return [compare.machine_numbers(a, b) for a, b in zip(stood_in, references)]
+
+
+def correct_rules(run: Dict[str, Any]) -> Dict[str, Any]:
+    """The configuration's ``correct`` block. A rehearsal mix states what
+    belongs to its size and not the cell's: how many machines it compares,
+    and a limit where a planted fault shows less in its short history than
+    in the cell's (on the CPU sound runs read 1e-7, so there is room)."""
+    rules, traffic = dict(run["config"]["correct"]), run["traffic"]
+    rules["check_machines"] = traffic.get("check_machines", rules["check_machines"])
+    rules["limits"] = {**rules["limits"], **traffic.get("limits", {})}
+    return rules
+
+
+def sample_of(run: Dict[str, Any], committed: List[int]) -> List[int]:
+    """The machines a run compares: drawn from the seed among those the job
+    committed inside the window."""
+    count = int(correct_rules(run)["check_machines"])
+    rng = np.random.default_rng(run["seed"])
+    return sorted(rng.choice(committed, size=min(count, len(committed)), replace=False).tolist())
+
+
 def check(run: Dict[str, Any], committed: List[int], out_dir: str, fetches: Dict[str, Dict]) -> Dict[str, Any]:
     """Compare a sample of the machines the job committed inside the window,
     drawn from the seed, with the plain reference."""
-    import jax.numpy as jnp
-
     from benchmarks.reference import compare
 
-    config = run["config"]
-    count = int(run["traffic"].get("check_machines", config["correct"]["check_machines"]))
-    rng = np.random.default_rng(run["seed"])
-    sample = sorted(rng.choice(committed, size=min(count, len(committed)), replace=False).tolist())
+    rules = correct_rules(run)
+    sample = sample_of(run, committed)
     names = machine_names(run)
     started = time.perf_counter()
     n_rows = padded_rows(fetches)
     references = reference_results(run, sample, n_rows)
     reference_s = time.perf_counter() - started
-    per_machine = []
+    programs = []
     for index, reference in zip(sample, references):
         name = names[index]
         program = read_artifact(os.path.join(out_dir, name), reference["probe"])
@@ -348,24 +426,19 @@ def check(run: Dict[str, Any], committed: List[int], out_dir: str, fetches: Dict
         program["x_sum"] = fetches[name]["sum"]
         if program["x_shape"][0] != program["rows"]:
             program["rows"] = -1  # the artifact and the fetch disagree
-        per_machine.append(compare.machine_numbers(program, reference))
-    numbers = compare.worst_of(per_machine)
-    judged = compare.judge(numbers, config["correct"]["limits"])
-    variants = {}
+        programs.append(program)
+    replay_anomaly(run, programs, [r["probe"] for r in references])
+    per_machine = [compare.machine_numbers(a, b) for a, b in zip(programs, references)]
+    for index, numbers in zip(sample, per_machine):
+        log(f"machine {names[index]}: " + " ".join(f"{k}={v:.6g}" for k, v in numbers.items()))
+    judged = compare.judge_sample(per_machine, rules)
+    variants, variants_per_machine = {}, {}
     for variant in run.get("variants", ()):
-        # the reference put in the program's place: in the precision below
-        # the configuration's (the control), or with a fault planted
-        kwargs = (
-            {"dtype": jnp.bfloat16, "precision": None} if variant == "control_bf16"
-            else {"fault": variant}
-        )
-        stood_in = reference_results(run, sample, n_rows, **kwargs)
-        variants[variant] = compare.judge(compare.worst_of([
-            compare.machine_numbers(stand_in, reference)
-            for stand_in, reference in zip(stood_in, references)
-        ]), config["correct"]["limits"])
+        variants_per_machine[variant] = stand_in_numbers(run, sample, n_rows, references, variant)
+        variants[variant] = compare.judge_sample(variants_per_machine[variant], rules)
     return {
         "variants": variants,
+        "variants_per_machine": variants_per_machine,
         "sample": [names[i] for i in sample],
         "judged": judged,
         "per_machine": per_machine,
@@ -529,14 +602,19 @@ def run_cell(run: Dict[str, Any]) -> Dict[str, Any]:
 
     deadline.enter("check against the plain reference")
     checked = check(run, committed, out_dir, fetches) if committed else None
-    correct = (
-        checked is not None
-        and all(entry["ok"] for entry in checked["judged"].values())
-        and not failed
-        and compile_inside["cache_misses"] == 0
-    )
+    # every number that decides ``correct``, each beside its limit
+    judged = dict(checked["judged"]) if checked else {}
+    for name, value in (("failed_machines", len(failed)),
+                        ("window_compiles", compile_inside["cache_misses"])):
+        judged[name] = {"value": float(value), "limit": 0, "ok": value == 0}
+    correct = checked is not None and all(entry["ok"] for entry in judged.values())
+    if failed:
+        log(f"FAILED RUN: {len(failed)} of {len(in_window)} machines of the window did "
+            f"not complete: {[names[i] for i in failed[:8]]}")
     if compile_inside["cache_misses"]:
         log(f"FAILED RUN: {compile_inside['cache_misses']} program(s) compiled inside the window")
+    if checked is None:
+        log("FAILED RUN: no machine of the window completed, so nothing was compared")
     values = {
         "machines_per_hour": machines / window_s * 3600.0,
         "setup_s": setup_s,
@@ -544,7 +622,8 @@ def run_cell(run: Dict[str, Any]) -> Dict[str, Any]:
     return {
         "correct": correct, "attempted": len(in_window), "failed": len(failed),
         "values": values, "view": view, "device": device,
-        "breakdown": breakdown, "checked": checked, "window_s": window_s,
+        "breakdown": breakdown, "checked": checked, "judged": judged,
+        "window_s": window_s,
     }
 
 

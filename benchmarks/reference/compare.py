@@ -1,8 +1,10 @@
 """The numbers that decide ``correct``: program against plain reference.
 
-Each is a gap, 0 when the two agree, and has a limit of its own in the
-configuration's file (``correct.limits``); ``PERF.md`` gives the readings
-each limit was set from. A number without a limit is printed and not judged.
+Each is a gap, 0 when the two agree, read on every sampled machine. The
+configuration's file gives the limits: ``correct.limits`` for a number's
+worst machine, ``correct.median_limits`` for its median one; ``PERF.md``
+gives the readings each limit was set from. A number without a limit is
+printed and not judged.
 """
 
 from __future__ import annotations
@@ -52,7 +54,10 @@ def param_change_gap(program_params, ref_params, ref_params0) -> float:
 def machine_numbers(program: Dict[str, object], reference: Dict[str, object]) -> Dict[str, float]:
     """``program``: what the timed job committed for one machine, read back
     from its artifact, and what the benchmark's dataset saw it fetch.
-    ``reference``: the plain reference's result for the same machine."""
+    ``reference``: the plain reference's result for the same machine.
+    ``anomaly_gap`` is between two trainings' scores; ``anomaly_replay_gap``
+    between the program's score and the reference's arithmetic on the
+    program's own committed parameters (``program["anomaly_replayed"]``)."""
     numbers = {
         "rows_gap": abs(int(program["rows"]) - int(reference["rows"])),
         "x_sum_gap": _rel(program["x_sum"], reference["x_sum"], 1e-6),
@@ -72,15 +77,30 @@ def machine_numbers(program: Dict[str, object], reference: Dict[str, object]) ->
         "cv_mse_gap": _rel(program["cv_mse"], reference["cv_mse"]),
         "threshold_gap": _rel(program["total_threshold"], reference["total_threshold"]),
         "anomaly_gap": _rel(program["anomaly_mean"], reference["anomaly_mean"]),
+        "anomaly_replay_gap": _rel(program["anomaly_mean"], program["anomaly_replayed"]),
     }
     if len(program["loss_history"]) != len(reference["loss_history"]):
         numbers["loss_last_gap"] = float("inf")
     return numbers
 
 
-def worst_of(per_machine: List[Dict[str, float]]) -> Dict[str, float]:
-    keys = per_machine[0].keys()
-    return {k: max(float(m[k]) for m in per_machine) for k in keys}
+def judge_sample(per_machine: List[Dict[str, float]], rules: Dict[str, object]):
+    """``judge`` of the sampled machines' numbers by the configuration's
+    ``correct`` block: each number's WORST machine against ``limits``, and,
+    for the numbers ``median_limits`` names, the MEDIAN machine against that
+    limit too, as ``<name>.median``. A number that two trainings make (a
+    loss, a change of the parameters, a threshold) has a tail from machine to
+    machine that no limit can sit above and still catch a lost precision: its
+    median is held tightly, and its worst machine only to what a machine
+    gone wrong alone would read."""
+    def across(names, pick):
+        return {k: float(pick([float(m[k]) for m in per_machine])) for k in names}
+
+    out = judge(across(per_machine[0], np.max), rules["limits"])
+    medians = rules.get("median_limits", {})
+    for name, entry in judge(across(medians, np.median), medians).items():
+        out[f"{name}.median"] = entry
+    return out
 
 
 def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> Dict[str, Dict[str, object]]:
@@ -92,3 +112,9 @@ def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> Dict[str, Dict
         ok = True if limit is None else bool(np.isfinite(value) and value <= limit)
         out[name] = {"value": float(value), "limit": limit, "ok": ok}
     return out
+
+
+def failed_first(judged: Dict[str, Dict[str, object]]) -> Dict[str, Dict[str, object]]:
+    """The same entries, those over their limit first: a record that keeps
+    only the start of the line then says which number it was."""
+    return dict(sorted(judged.items(), key=lambda kv: bool(kv[1]["ok"])))

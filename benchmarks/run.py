@@ -36,6 +36,7 @@ def main() -> int:
         sys.stderr.write("bench: the program is not in this directory; nothing to run\n")
         return 2
     from benchmarks import harness
+    from benchmarks.reference import compare
 
     loaded = harness.load_cell(args.workload)
     device = harness.device_or_exit(int(loaded["cell"]["chips"]))
@@ -58,17 +59,23 @@ def main() -> int:
         values = dict(outcome["values"])
         if not on_chip:
             values = {"setup_s": values["setup_s"]}
-    checked = outcome["checked"] or {"judged": {}, "sample": []}
-    compared = {
-        k: {"value": v["value"], "limit": v["limit"]}
-        for k, v in checked["judged"].items()
-    }
+    checked = outcome["checked"] or {"sample": []}
+    # the numbers over their limit first, on stderr and in the line: a
+    # record that keeps only part of either still says which it was
+    judged = compare.failed_first(outcome["judged"])
+    compared = {k: {"value": v["value"], "limit": v["limit"]} for k, v in judged.items()}
     harness.log(
         f"checked {checked['sample']} in {checked.get('check_s', 0):.1f}s "
         f"(reference {checked.get('reference_s', 0):.1f}s)"
     )
-    for key, entry in compared.items():
-        harness.log(f"compared {key} = {entry['value']:.6g} (limit {entry['limit']})")
+    for key, entry in judged.items():
+        harness.log(
+            f"{'compared' if entry['ok'] else 'FAILED NUMBER'} {key} = "
+            f"{entry['value']:.6g} (limit {entry['limit']})"
+        )
+    over = [key for key, entry in judged.items() if not entry["ok"]]
+    if over:
+        harness.log(f"NOT CORRECT: over their limits: {', '.join(over)}")
     sys.stderr.flush()
     print(harness.result_line(
         bench, outcome["correct"], outcome["attempted"], outcome["failed"],

@@ -3,7 +3,10 @@
 PROGRAM (not in the reference), then drives a whole run. Used by
 ``test_correct.py``, which expects ``correct`` to come out false.
 
-    python benchmarks/tests/faulty_run.py <fault> --workload ... --seed ...
+    python benchmarks/tests/faulty_run.py <fault>[,<fault>] --workload ... --seed ...
+
+Faults that touch different answers (``answer_altered,score_altered``) can be
+planted together, which reads both on the chip in one run.
 """
 
 import os
@@ -60,13 +63,25 @@ def plant(fault: str) -> None:
             detector.total_threshold_ = 1.25 * detector.total_threshold_
 
         build_fleet._install_result = install
+    elif fault == "score_altered":
+        # the other answer, altered where the loaded model produces it: every
+        # total anomaly score a quarter higher than its tags' scores give
+        diff = importlib.import_module("gordo_components_tpu.models.anomaly.diff")
+        original = diff.DiffBasedAnomalyDetector.anomaly
+
+        def anomaly(self, X, y=None):
+            frame = original(self, X, y)
+            frame["total-anomaly-score"] = 1.25 * frame["total-anomaly-score"]
+            return frame
+
+        diff.DiffBasedAnomalyDetector.anomaly = anomaly
     elif fault != "none":
         raise SystemExit(f"unknown fault {fault!r}")
 
 
 if __name__ == "__main__":
-    fault = sys.argv.pop(1)
-    plant(fault)
+    for fault in sys.argv.pop(1).split(","):
+        plant(fault)
     from benchmarks import run
 
     sys.exit(run.main())

@@ -1,13 +1,18 @@
 #!/usr/bin/env python3
 """Readings for the limits of ``correct``: in ONE process (set-up is long),
 for each seed, a short run of the cell through the driver, whose check also
-puts the control (the reference in bfloat16) and the planted faults in the
+puts the sound stand-in (the reference at the configuration's own precision),
+the control (the reference in bfloat16) and the planted faults in the
 program's place and judges each by the configuration's limits, as a run is
-judged: ``fails`` names the numbers over their limit, and a control or a
-fault that fails none has not been caught. One JSON line per seed on stdout.
+judged: ``fails`` names the numbers over their limit; a control or a fault
+that fails none has not been caught, and a sound stand-in that fails one says
+the limit sits inside what a sound rewrite reads. One JSON line per seed on
+stdout, each variant with its machines' own numbers (``per_machine``), so
+that another form of a number (worst of fewer, the median) can be read off
+the same call.
 
     python benchmarks/tools/readings.py --workload <name> --seeds 1,2,3 \\
-        --seconds 5 --variants control_bf16,half_batch
+        --seconds 5 --variants sound_default_precision,control_bf16,half_batch
 
 ``--reference-only`` leaves the job out: the control and the faults are the
 reference put in the program's place, so their readings need no build, only
@@ -36,7 +41,7 @@ def main() -> int:
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seeds", required=True)
     parser.add_argument("--seconds", type=float, default=5.0)
-    parser.add_argument("--variants", default="control_bf16,half_batch")
+    parser.add_argument("--variants", default="sound_default_precision,control_bf16,half_batch")
     parser.add_argument("--slice-size", type=int, default=None,
                         help="try another slice size than the configuration's")
     parser.add_argument("--fleet-machines", type=int, default=None,
@@ -78,10 +83,7 @@ def main() -> int:
             "memory_peak_bytes": outcome["device"].get("memory_peak_bytes"),
             "program": {k: v["value"] for k, v in checked["judged"].items()},
             "variants": {
-                name: {
-                    "fails": [k for k, v in judged.items() if not v["ok"]],
-                    "numbers": {k: v["value"] for k, v in judged.items()},
-                }
+                name: reading(judged, checked["variants_per_machine"][name])
                 for name, judged in checked["variants"].items()
             },
             "window_s": outcome["window_s"],
@@ -90,9 +92,15 @@ def main() -> int:
     os._exit(0)
 
 
+def reading(judged, per_machine):
+    return {
+        "fails": [k for k, v in judged.items() if not v["ok"]],
+        "numbers": {k: v["value"] for k, v in judged.items()},
+        "per_machine": per_machine,
+    }
+
+
 def reference_only(loaded, args) -> int:
-    import jax.numpy as jnp
-    import numpy as np
     import pandas as pd
 
     from benchmarks.drivers import build
@@ -100,33 +108,21 @@ def reference_only(loaded, args) -> int:
     from gordo_components_tpu.utils.backend import enable_persistent_compile_cache
 
     enable_persistent_compile_cache()
-    traffic, limits = loaded["traffic"], loaded["config"]["correct"]["limits"]
+    traffic, rules = loaded["traffic"], build.correct_rules(loaded)
     rows = int(traffic["history_days"]) * 86_400 * 10**9 // int(pd.Timedelta(traffic["resolution"]).value)
     n_rows = -(-rows // 256) * 256
     for seed in [int(s) for s in args.seeds.split(",")]:
         started = time.perf_counter()
         run = {**loaded, "seed": seed}
         slice_size, _ = build.sizes(run)
-        count = int(loaded["config"]["correct"]["check_machines"])
         # a run's sample: machines of the window's slice, drawn from the seed
-        sample = sorted(np.random.default_rng(seed).choice(
-            np.arange(slice_size, 2 * slice_size), size=count, replace=False).tolist())
+        sample = build.sample_of(run, list(range(slice_size, 2 * slice_size)))
         references = build.reference_results(run, sample, n_rows)
         reference_s = time.perf_counter() - started
         variants = {}
         for variant in [v for v in args.variants.split(",") if v]:
-            kwargs = (
-                {"dtype": jnp.bfloat16, "precision": None} if variant == "control_bf16"
-                else {"fault": variant}
-            )
-            stood_in = build.reference_results(run, sample, n_rows, **kwargs)
-            judged = compare.judge(compare.worst_of([
-                compare.machine_numbers(a, b) for a, b in zip(stood_in, references)
-            ]), limits)
-            variants[variant] = {
-                "fails": [k for k, v in judged.items() if not v["ok"]],
-                "numbers": {k: v["value"] for k, v in judged.items()},
-            }
+            per_machine = build.stand_in_numbers(run, sample, n_rows, references, variant)
+            variants[variant] = reading(compare.judge_sample(per_machine, rules), per_machine)
         print(json.dumps({
             "seed": seed, "workload": args.workload, "sample": sample,
             "reference_s": reference_s, "wall_s": time.perf_counter() - started,
