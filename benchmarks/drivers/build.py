@@ -20,6 +20,7 @@ the count of resolved machines reaches a multiple of the slice size.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import shutil
@@ -295,7 +296,7 @@ def reference_results(
         slice_index, position = divmod(index, slice_size)
         keys.append(np.asarray(program_keys(run["seed"], slice_index, slice_size)[position]))
     dtype = jnp.dtype(jnp.float32 if dtype is None else dtype)
-    build, anomaly = ref_build.make_build(
+    build, anomaly, initial = ref_build.make_build(
         model, n_rows, config["tags"], dtype=dtype, fault=fault
     )
     probe_rows = int(traffic["probe_rows"])
@@ -306,17 +307,31 @@ def reference_results(
         return result
 
     probes = np.stack([raw[-probe_rows:] for raw in raws])
-    fn = jax.jit(jax.vmap(one))
     args = (np.stack(stacked_X), np.stack(stacked_w), np.stack(keys), probes)
-    if precision is None:
-        out = fn(*args)
-    else:
-        with jax.default_matmul_precision(precision):
-            out = fn(*args)
-    out = jax.device_get(out)
+    with jax.default_matmul_precision(precision) if precision else contextlib.nullcontext():
+        started = time.perf_counter()
+        lowered = jax.jit(jax.vmap(one)).lower(*args)
+        lowered_at = time.perf_counter()
+        compiled = lowered.compile()
+        compiled_at = time.perf_counter()
+        room_on_device(compiled, "the plain reference's build")
+        out = jax.device_get(compiled(*args))
+        # what every fit started from: a program of its own, so that the
+        # build holds one copy of a model less
+        out["params0"] = jax.device_get(jax.jit(jax.vmap(initial))(args[2]))
+    log(f"the plain reference's build: traced and lowered in {lowered_at - started:.1f}s, "
+        f"compiled or loaded in {compiled_at - lowered_at:.1f}s, ran and fetched in "
+        f"{time.perf_counter() - compiled_at:.1f}s")
     results = []
     for i, raw in enumerate(raws):
-        result = jax.tree_util.tree_map(lambda a: np.asarray(a[i], np.float64), out)
+        # the machine's scalars and vectors are widened; its parameters stay
+        # float32 leaves (a whole tree is never widened on the host)
+        result = {
+            key: np.asarray(value[i], np.float64)
+            for key, value in out.items() if key not in ("params", "params0")
+        }
+        for key in ("params", "params0"):
+            result[key] = jax.tree_util.tree_map(lambda a: a[i], out[key])
         # an autoencoder's targets are its inputs: one scaler stands for both
         result["target_scale"] = result["input_scale"]
         result["rows"] = len(raw)
@@ -324,6 +339,35 @@ def reference_results(
         result["probe"] = raw[-probe_rows:]
         results.append(result)
     return results
+
+
+def room_on_device(compiled, what: str) -> None:
+    """Say on stderr what the device holds and what ``compiled`` will ask of
+    it; where the two do not fit the device's limit, end the run here with
+    both numbers named, not later with the allocator's text. A backend that
+    keeps no such figures (the CPU) is not asked."""
+    import jax
+
+    stats = jax.local_devices()[0].memory_stats() or {}
+    analysis = compiled.memory_analysis()
+    if analysis is None or "bytes_limit" not in stats:
+        return
+    needs = (
+        analysis.argument_size_in_bytes + analysis.output_size_in_bytes
+        + analysis.temp_size_in_bytes - analysis.alias_size_in_bytes
+    )
+    in_use, limit = int(stats.get("bytes_in_use", 0)), int(stats["bytes_limit"])
+    log(
+        f"{what}: the device holds {in_use} bytes in use of {limit}; the compiled "
+        f"program needs {needs} (arguments {analysis.argument_size_in_bytes}, outputs "
+        f"{analysis.output_size_in_bytes}, temporaries {analysis.temp_size_in_bytes})"
+    )
+    if in_use + needs > limit:
+        log(
+            f"NO ROOM for {what}: {needs} bytes needed beside {in_use} in use "
+            f"are {in_use + needs - limit} over the device's {limit}; no result"
+        )
+        raise SystemExit(8)
 
 
 def replay_anomaly(run: Dict[str, Any], built: List[Dict[str, Any]], probes) -> None:
@@ -342,7 +386,7 @@ def replay_anomaly(run: Dict[str, Any], built: List[Dict[str, Any]], probes) -> 
 
     config = run["config"]
     probes = np.asarray(probes, np.float32)
-    _, anomaly = ref_build.make_build(
+    _, anomaly, _ = ref_build.make_build(
         config["reference_model"], probes.shape[1], config["tags"], dtype=jnp.float32
     )
     keys = ("params", "input_scale", "input_offset", "error_scale", "error_offset")

@@ -23,7 +23,22 @@ def _spec(config, n_features):
     return _spec_for(analyzed, n_features, n_features, config["n_splits"])
 
 
-@pytest.mark.parametrize("name", ["dense-ae-10tag", "lstm-ae-50tag"])
+# ours over the program's figure, as read at this shape (my CPU runs, PR 29)
+# with a band about it. Ours counts matrix products alone; XLA's count, which
+# the program reads, adds the elementwise work (activations, Adam, the loss):
+# a flat model's figure is a little over ours. XLA counts a scan's body once
+# whatever its trip count: the program's accounting corrects that for the
+# training loop but not for the LSTM's scans over the window (PERF.md, Open
+# questions), so for a windowed model its figure is low: by about the window
+# length (14.4 to 24 times at 24 rows) until PR 28, whose weights' and inputs'
+# gradients are products over the stacked window outside those scans and are
+# counted whole, and by 2.748 times since. Which of the two counts is right
+# is not this test's to say: ``test_forward_flops_are_the_products_the_forward_
+# pass_multiplies`` holds ours to the kind's own forward pass.
+RATIO_TO_THE_PROGRAMS = {"dense-ae-10tag": (0.847, 0.7, 1.0), "lstm-ae-50tag": (2.748, 2.2, 3.3)}
+
+
+@pytest.mark.parametrize("name", sorted(RATIO_TO_THE_PROGRAMS))
 def test_flops_agree_with_the_programs_accounting(name):
     from gordo_components_tpu.parallel.fleet import fleet_flops_accounting
 
@@ -34,15 +49,31 @@ def test_flops_agree_with_the_programs_accounting(name):
     ours = flops_bytes.slice_counts(config["reference_model"], machines, rows, tags)
     assert theirs is not None
     assert ours["train_steps"] == theirs["train_steps"]
-    # ours counts matrix products alone and is the floor; XLA's count adds
-    # the elementwise work (activations, Adam, the loss). XLA counts a scan's
-    # body once whatever its trip count: the program's accounting corrects
-    # that for the training loop but not for the LSTM's scan over the window,
-    # so for a windowed model its figure is low by about the window length
-    # (PERF.md, Open questions); the recurrent layers do run every time step.
-    inner = config["reference_model"]["lookback"]
-    assert ours["flops"] <= inner * theirs["total_flops"]
-    assert ours["flops"] >= 0.6 * inner * theirs["total_flops"]
+    read, low, high = RATIO_TO_THE_PROGRAMS[name]
+    ratio = ours["flops"] / theirs["total_flops"]
+    assert low <= ratio <= high, (ratio, read)
+
+
+def _product_flops(jaxpr) -> float:
+    """2·m·n·k of every ``dot_general`` of a jaxpr, a ``scan``'s body times
+    its length: the products a function multiplies, whoever compiles it."""
+    import math
+
+    total = 0.0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            (contract, _), _ = eqn.params["dimension_numbers"]
+            lhs = eqn.invars[0].aval.shape
+            total += 2.0 * math.prod(eqn.outvars[0].aval.shape) * math.prod(
+                lhs[d] for d in contract
+            )
+            continue
+        times = eqn.params.get("length", 1) if eqn.primitive.name == "scan" else 1
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                total += times * _product_flops(inner)
+    return total
 
 
 def test_least_seconds_names_its_bound():
@@ -71,12 +102,76 @@ def test_every_model_kind_is_found_by_name_and_is_whole(kind):
              "out_func": "linear", "lookback": 5, "epochs": 1, "batch_size": 8,
              "n_splits": 2, "learning_rate": 1e-3}
     module = models.for_kind(model)
-    lookback, target_offset = module.layout(model)
-    assert lookback >= 1 and 0 <= target_offset
+    # what a kind has to bring, and what it may: nothing else is looked for
+    for name in models.REQUIRED:
+        assert callable(getattr(module, name)), f"{kind} lacks {name}"
+    brought = [name for name in models.OPTIONAL if hasattr(module, name)]
+    lay = models.layout(model)
+    assert lay.lookback >= 1 and 0 <= lay.target_offset and lay.rows_out >= 1
+    n_samples = lay.n_samples(64)
+    assert lay.lead(64) + (n_samples - 1) * lay.rows_out + lay.reach == 63
     params = module.init(model, jax.random.PRNGKey(0), 7, 7)
-    out = module.apply(model, params, jnp.ones((3, lookback, 7), jnp.float32))
-    assert out.shape == (3, 7)
+    windows = jnp.ones((3, lay.lookback, 7), jnp.float32)
+    out = module.apply(model, params, windows)
+    assert out.shape == ((3, 7) if lay.rows_out == 1 else (3, lay.rows_out, 7))
     flops = module.forward_flops(model, 7)
-    assert 0 < flops["first_layer"] < flops["total"]
+    # the first layer's input gradient is left out of a training sample's
+    # count; only a kind that counts its own (an embedding's look-up has no
+    # product) may state none
+    assert ("train_flops" in brought or 0 < flops["first_layer"]) and (
+        0 <= flops["first_layer"] < flops["total"]
+    )
+    # the defaults stand in for what the kind leaves out
+    if "loss" in brought:
+        assert module.loss(model, params, windows, out).shape == (3,)
+    if "train_flops" not in brought:
+        assert models.train_flops(model, 7) == 3.0 * flops["total"] - flops["first_layer"]
+    if "state_bytes" not in brought:
+        assert models.state_bytes(model, 7) == 0.0
     counts = flops_bytes.slice_counts(model, 2, 64, 7)
     assert counts["flops"] > 0 and counts["bytes"] > 0
+    steps = -(-n_samples // 8)
+    assert counts["bytes"] == 2 * 3 * (
+        2 * 64 * 4.0 * 7 + steps * models.state_bytes(model, 7)
+    )
+
+
+def product_flops_a_sample(module, model, n_features: int, batch: int = 3) -> float:
+    import jax
+    import jax.numpy as jnp
+
+    lookback = module.layout(model)[0]
+    params = module.init(model, jax.random.PRNGKey(0), n_features, n_features)
+    windows = jnp.ones((batch, lookback, n_features), jnp.float32)
+    traced = jax.make_jaxpr(lambda p, w: module.apply(model, p, w))(params, windows)
+    return _product_flops(traced.jaxpr) / batch
+
+
+@pytest.mark.parametrize("name", sorted(RATIO_TO_THE_PROGRAMS))
+def test_forward_flops_are_the_products_the_forward_pass_multiplies(name):
+    """A second witness for the count ``train_step_mfu`` and
+    ``fleet_train_roofline`` are made from, one that shares nothing with
+    XLA's cost analysis: the ``dot_general``s of the kind's own ``apply``,
+    read off its jaxpr at the configuration's own widths, a scan's body times
+    its length. Exactly equal for a kind of plain products."""
+    from benchmarks.reference import models
+
+    with open(os.path.join(HERE, "configs", f"{name}.json")) as fh:
+        config = json.load(fh)
+    model, tags = config["reference_model"], config["tags"]
+    module = models.for_kind(model)
+    flops = module.forward_flops(model, tags)
+    assert flops["total"] == product_flops_a_sample(module, model, tags)
+    assert 0 < flops["first_layer"] < flops["total"]
+    assert models.train_flops(model, tags) == 3.0 * flops["total"] - flops["first_layer"]
+
+
+def test_the_kinds_that_were_there_bring_nothing_optional():
+    """``lstm`` and ``dense`` run the defaults: the squared error, every
+    window a row apart, three times the forward pass, no state in the bytes."""
+    from benchmarks.reference import models
+
+    for kind in ("lstm", "dense"):
+        module = models.for_kind({"kind": kind})
+        assert [n for n in models.OPTIONAL if hasattr(module, n)] == []
+        assert len(module.layout({"lookback": 5})) == 2
