@@ -23,13 +23,14 @@ from .models import (
     MultiStepForecast,
     PatchTSTAutoEncoder,
     PatchTSTForecast,
+    MoEMLAForecast,
     KerasAutoEncoder,
     KerasLSTMAutoEncoder,
     KerasLSTMForecast,
 )
 
 # import for the registration side effects — every factory registers its kind
-from .factories import feedforward, lstm, transformer  # noqa: F401
+from .factories import feedforward, lstm, moe_mla, transformer  # noqa: F401
 
 __all__ = [
     "GordoBase",
@@ -43,6 +44,7 @@ __all__ = [
     "MultiStepForecast",
     "PatchTSTAutoEncoder",
     "PatchTSTForecast",
+    "MoEMLAForecast",
     "KerasAutoEncoder",
     "KerasLSTMAutoEncoder",
     "KerasLSTMForecast",

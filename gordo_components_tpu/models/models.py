@@ -62,6 +62,8 @@ class BaseFlaxEstimator(GordoBase):
     forecast)."""
 
     lookahead: Optional[int] = None  # class-level contract
+    # the smallest row bucket ``predict`` pads its samples up to
+    predict_bucket_min = 256
 
     def __init__(self, kind: str, **kwargs: Any):
         self.kind = kind
@@ -85,20 +87,29 @@ class BaseFlaxEstimator(GordoBase):
             return 1
         return int(self.factory_kwargs.get("lookback_window", 1))
 
+    @property
+    def rows_out(self) -> int:
+        """Rows a sample is judged against; samples lie that many rows apart
+        (``ops.windowing``). 1: every window, judged against one row."""
+        return 1
+
     def _prepare_inputs(self, X: np.ndarray) -> np.ndarray:
         if self.lookahead is None:
             return X
         return np.asarray(
-            windowing.sliding_windows(X, self.lookback_window, self.lookahead)
+            windowing.sliding_windows(
+                X, self.lookback_window, self.lookahead, self.rows_out
+            )
         )
 
     def _prepare_targets(self, y: np.ndarray) -> np.ndarray:
+        """Per sample: ``(samples, F)``, or ``(samples, rows_out, F)``."""
         if self.lookahead is None:
             return y
-        if self.lookahead == 0:
-            return windowing.reconstruction_targets(y, self.lookback_window)
-        return windowing.forecast_targets(
-            y, self.lookback_window, self.lookahead
+        return np.asarray(
+            windowing.sample_targets(
+                y, self.lookback_window, self.lookahead, self.rows_out
+            )
         )
 
     # -- compiled-program identity -----------------------------------------
@@ -192,7 +203,7 @@ class BaseFlaxEstimator(GordoBase):
             # not windows (same scheme as the fleet program; numerically
             # identical to materialized windows)
             L, la = self.lookback_window, self.lookahead
-            n_samples = windowing.n_windows(len(X), L, la)
+            n_samples = windowing.n_windows(len(X), L, la, self.rows_out)
             if n_samples <= 0:
                 raise ValueError(
                     f"Need at least lookback_window+lookahead={L + la} rows "
@@ -218,7 +229,8 @@ class BaseFlaxEstimator(GordoBase):
                 lambda: jax.jit(fit_windowed),
             )
             starts, yp, w = pad_to_batches(
-                np.arange(n_samples), targets, self.batch_size
+                windowing.window_starts(len(X), L, la, self.rows_out),
+                targets, self.batch_size,
             )
             result = fit_fn(
                 params,
@@ -255,18 +267,25 @@ class BaseFlaxEstimator(GordoBase):
     def predict(self, X) -> np.ndarray:
         """Predictions aligned per the windowing contract: flat models return
         one row per input row; windowed models return
-        ``n - lookback_window + 1 - lookahead`` rows (see
+        ``n - lookback_window + 1 - lookahead`` rows, or the ``rows_out``
+        rows of each sample, one sample after another (see
         :func:`~gordo_components_tpu.ops.windowing.window_output_index`)."""
         self._check_fitted()
         X = _as_float32(X)
         inputs = self._prepare_inputs(X)
         n = inputs.shape[0]
-        bucket = _round_up_bucket(n)
+        bucket = _round_up_bucket(n, self.predict_bucket_min)
         if bucket != n:
             pad = np.zeros((bucket - n, *inputs.shape[1:]), inputs.dtype)
             inputs = np.concatenate([inputs, pad])
-        out = self._predict_jit(self.params_, jnp.asarray(inputs))
-        return np.asarray(jax.device_get(out))[:n]
+        if not isinstance(jax.tree_util.tree_leaves(self.params_)[0], jax.Array):
+            # a loaded or just-built model keeps its parameters where they
+            # came from (the host) until it first predicts
+            self.params_ = jax.device_put(self.params_)
+        out = np.asarray(
+            jax.device_get(self._predict_jit(self.params_, jnp.asarray(inputs)))
+        )[:n]
+        return out.reshape(-1, out.shape[-1])
 
     def score(self, X, y=None) -> float:
         """Explained variance of predictions vs the contract-aligned targets
@@ -274,7 +293,10 @@ class BaseFlaxEstimator(GordoBase):
         self._check_fitted()
         X = _as_float32(X)
         y_arr = X if y is None else _as_float32(y)
-        return explained_variance_score(self._prepare_targets(y_arr), self.predict(X))
+        targets = self._prepare_targets(y_arr)
+        return explained_variance_score(
+            targets.reshape(-1, targets.shape[-1]), self.predict(X)
+        )
 
     # -- introspection / persistence ----------------------------------------
     def get_params(self, deep: bool = True) -> Dict[str, Any]:
@@ -348,7 +370,11 @@ class BaseFlaxEstimator(GordoBase):
         self.history_ = list(state.get("history", []))
         self.fit_duration_ = state.get("fit_duration")
         self._spec = self._make_spec(self.n_features_, self.n_features_out_)
-        self.params_ = jax.tree_util.tree_map(jnp.asarray, state["params"])
+        # kept where they are (a fleet build's commit and a load hand over
+        # host arrays): ``predict`` places them on the device when it first
+        # needs them, so a model of gigabytes is committed without a round
+        # trip through the device
+        self.params_ = state["params"]
         self._predict_jit = self._build_predict_jit()
         return self
 
@@ -464,6 +490,32 @@ class PatchTSTForecast(LSTMForecast):
     def __init__(self, kind: str = "patchtst", **kwargs: Any):
         kwargs.setdefault("lookback_window", 32)
         super().__init__(kind, **kwargs)
+
+
+class MoEMLAForecast(LSTMForecast):
+    """Window → the ``lookback_window`` rows that FOLLOW each of its rows: a
+    decoder over sensor values read as tokens (the ``moe_mla_decoder``
+    kind), every tag a sequence of its own. A sample reads rows ``i ..
+    i+L-1`` and predicts rows ``i+1 .. i+L``; samples lie ``L`` rows apart,
+    laid from the end, so every row from the first sample's second on is
+    predicted once and ``predict`` returns them in order (tail-aligned, as
+    every windowed estimator's). Trains on the module's own loss."""
+
+    predict_bucket_min = 1  # a sample is a whole window of tokens a tag
+
+    def __init__(self, kind: str = "moe_mla_decoder", **kwargs: Any):
+        kwargs.setdefault("lookback_window", 64)
+        kwargs.pop("horizon", None)  # the next rows, always
+        super().__init__(kind, horizon=1, **kwargs)
+
+    @property
+    def rows_out(self) -> int:
+        return self.lookback_window
+
+    def get_params(self, deep: bool = True) -> Dict[str, Any]:
+        params = super().get_params(deep)
+        params.pop("horizon")
+        return params
 
 
 # Aliases so ported reference configs resolve (the serializer rewrites

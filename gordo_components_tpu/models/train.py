@@ -35,25 +35,38 @@ _LOSSES = {
 }
 
 
-def make_loss_fn(apply_fn: Callable, loss: str = "mse") -> Callable:
-    """Weighted per-sample loss: (params, x, y, w, key) → scalar.
+# the loss of a module that brings its own: ``apply(variables, x, targets=y,
+# method="sample_losses") -> ((batch,) losses, counters)``
+MODULE_LOSS = "module"
 
-    ``w`` masks padding rows; the mean is over real rows only.
+
+def make_loss_fn(apply_fn: Callable, loss: str = "mse") -> Callable:
+    """Weighted per-sample loss: (params, x, y, w, key) → (scalar, counters).
+
+    ``w`` masks padding rows; the mean is over real rows only. ``counters``
+    is what the module counts while it computes its own loss (a dict of
+    arrays the fit sums over its steps); empty for an elementwise loss.
     """
-    if loss not in _LOSSES:
-        raise ValueError(f"Unknown loss {loss!r}; supported: {sorted(_LOSSES)}")
-    elementwise = _LOSSES[loss]
+    if loss != MODULE_LOSS and loss not in _LOSSES:
+        raise ValueError(
+            f"Unknown loss {loss!r}; supported: {sorted(_LOSSES) + [MODULE_LOSS]}"
+        )
 
     def loss_fn(params, x, y, w, dropout_key):
-        pred = apply_fn(
-            {"params": params},
-            x,
+        kwargs = dict(
             deterministic=dropout_key is None,
             rngs=None if dropout_key is None else {"dropout": dropout_key},
         )
-        per_sample = jnp.mean(elementwise(pred - y), axis=-1)
+        if loss == MODULE_LOSS:
+            per_sample, counters = apply_fn(
+                {"params": params}, x, targets=y, method="sample_losses", **kwargs
+            )
+        else:
+            pred = apply_fn({"params": params}, x, **kwargs)
+            per_sample = jnp.mean(_LOSSES[loss](pred - y), axis=-1)
+            counters = {}
         wsum = jnp.maximum(jnp.sum(w), 1.0)
-        return jnp.sum(per_sample * w) / wsum
+        return jnp.sum(per_sample * w) / wsum, counters
 
     return loss_fn
 
@@ -61,6 +74,8 @@ def make_loss_fn(apply_fn: Callable, loss: str = "mse") -> Callable:
 class FitResult(NamedTuple):
     params: Any
     loss_history: jnp.ndarray  # (epochs,) weighted mean loss per epoch
+    counters: Any = None  # the loss's counters, summed over the fit's steps
+    opt_state: Any = None  # the optimizer's state after the last step
 
 
 def make_batch_step(
@@ -70,13 +85,13 @@ def make_batch_step(
     use_dropout: bool = False,
 ) -> Callable:
     """One mini-batch SGD step: ``((params, opt_state), (x, y, w, key)) →
-    ((params, opt_state), (loss, wsum))`` — the scanned body of
+    ((params, opt_state), (loss, wsum, counters))`` — the scanned body of
     :func:`make_fit_fn`, exposed so FLOP accounting can compile exactly the
     step the training loop runs (XLA's ``cost_analysis`` counts a scan body
     ONCE regardless of trip count, so whole-program flops undercount
     training loops; see ``parallel.fleet.fleet_flops_accounting``)."""
     loss_fn = make_loss_fn(apply_fn, loss)
-    grad_fn = jax.value_and_grad(loss_fn)
+    grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def batch_step(carry, batch):
         params, opt_state = carry
@@ -84,12 +99,12 @@ def make_batch_step(
         # named scopes are op metadata: a device trace tells this step's
         # ops (and the loop that holds them) from the predict passes'
         with jax.named_scope("optimizer_step"):
-            batch_loss, grads = grad_fn(
+            (batch_loss, counters), grads = grad_fn(
                 params, xi, yi, wi, ki if use_dropout else None
             )
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
-        return (params, opt_state), (batch_loss, jnp.sum(wi))
+        return (params, opt_state), (batch_loss, jnp.sum(wi), counters)
 
     return batch_step
 
@@ -120,10 +135,13 @@ def make_fit_fn(
         apply_fn, optimizer, loss=loss, use_dropout=use_dropout
     )
 
-    def fit(params, X, y, w, key) -> FitResult:
+    def fit(params, X, y, w, key, opt_state=None) -> FitResult:
+        """``opt_state``: the optimizer's state to start from (a caller that
+        keeps the training state in buffers of its own); fresh by default."""
         n = X.shape[0]
         steps = n // batch_size
-        opt_state = optimizer.init(params)
+        if opt_state is None:
+            opt_state = optimizer.init(params)
 
         def epoch_step(carry, epoch_key):
             params, opt_state = carry
@@ -137,23 +155,32 @@ def make_fit_fn(
             wb = w[perm].reshape(steps, batch_size)
             drop_keys = jax.random.split(drop_key, steps)
 
-            (params, opt_state), (batch_losses, batch_wsums) = jax.lax.scan(
-                batch_step,
-                (params, opt_state),
-                (Xb, yb, wb, drop_keys),
-                unroll=min(unroll, steps) if steps else 1,
+            (params, opt_state), (batch_losses, batch_wsums, counters) = (
+                jax.lax.scan(
+                    batch_step,
+                    (params, opt_state),
+                    (Xb, yb, wb, drop_keys),
+                    unroll=min(unroll, steps) if steps else 1,
+                )
             )
             epoch_loss = jnp.sum(batch_losses * batch_wsums) / jnp.maximum(
                 jnp.sum(batch_wsums), 1.0
             )
-            return (params, opt_state), epoch_loss
+            return (params, opt_state), (epoch_loss, counters)
 
         epoch_keys = jax.random.split(key, epochs)
         with jax.named_scope("epoch_loop"):
-            (params, _), history = jax.lax.scan(
+            (params, opt_state), (history, counters) = jax.lax.scan(
                 epoch_step, (params, opt_state), epoch_keys
             )
-        return FitResult(params=params, loss_history=history)
+        return FitResult(
+            params=params, loss_history=history,
+            # (epochs, steps, ...) a counter: the fit's total
+            counters=jax.tree_util.tree_map(
+                lambda c: jnp.sum(c, axis=(0, 1)), counters
+            ),
+            opt_state=opt_state,
+        )
 
     return fit
 

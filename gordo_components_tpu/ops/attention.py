@@ -27,16 +27,24 @@ from jax.sharding import Mesh, PartitionSpec
 
 
 def dense_attention(
-    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, scale: Optional[float] = None
+    q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+    scale: Optional[float] = None, causal: bool = False,
 ) -> jnp.ndarray:
     """Reference scaled-dot-product attention.
 
-    Shapes: q/k/v ``(..., seq, heads, head_dim)`` → ``(..., seq, heads,
-    head_dim)`` (the flax convention, so modules can swap implementations).
+    Shapes: q/k ``(..., seq, heads, head_dim)``, v ``(..., seq, heads,
+    value_dim)`` → ``(..., seq, heads, value_dim)`` (the flax convention,
+    so modules can swap implementations). ``causal``: a query sees the keys
+    up to its own position.
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("...qhd,...khd->...hqk", q, k) * scale
+    if causal:
+        seq = q.shape[-3]
+        logits = jnp.where(
+            jnp.tril(jnp.ones((seq, seq), bool)), logits, -jnp.inf
+        )
     weights = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("...hqk,...khd->...qhd", weights, v)
 

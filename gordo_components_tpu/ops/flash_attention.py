@@ -26,8 +26,10 @@ Exactness and autodiff:
 On the CPU the kernel runs in Pallas interpret mode, so CPU tests execute
 the same kernel body the TPU lowers.
 
-Scope: non-causal self-attention (the PatchTST encoder is bidirectional;
-nothing in the zoo is autoregressive). Attention-weight dropout is not
+Scope: self-attention, bidirectional (the PatchTST encoder) or ``causal``
+(the decoder kind: key blocks past a query block's last row are neither
+computed nor fetched again), with a value width of its own (latent
+attention's keys are wider than its values). Attention-weight dropout is not
 representable (weights are never materialized) — callers fall back to the
 dense path for that, as with ring attention.
 """
@@ -76,9 +78,10 @@ def _pad_to(n: int, multiple: int) -> int:
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, scale: float, seq_len: int, block_k: int, n_k: int, masked: bool
+    *, scale: float, seq_len: int, block_q: int, block_k: int, n_k: int,
+    masked: bool, causal: bool
 ):
-    ki = pl.program_id(2)
+    qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -86,31 +89,46 @@ def _fwd_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)  # (bq, D)
-    k = k_ref[0].astype(jnp.float32)  # (bk, D)
-    v = v_ref[0].astype(jnp.float32)
-    s = (
-        jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        * scale
-    )  # (bq, bk) — scores live in VMEM only
-    if masked:  # the padded tail (from EITHER block size) carries phantom
-        # keys — mask any key position at or beyond the true sequence length
+    def _fold():
+        q = q_ref[0].astype(jnp.float32)  # (bq, D)
+        k = k_ref[0].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0].astype(jnp.float32)  # (bk, Dv)
+        s = (
+            jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            * scale
+        )  # (bq, bk) — scores live in VMEM only
         kpos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < seq_len, s, _MASK)
+        if masked:  # the padded tail (from EITHER block size) carries
+            # phantom keys — mask any key position at or beyond the true
+            # sequence length
+            s = jnp.where(kpos < seq_len, s, _MASK)
+        if causal:
+            qpos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0
+            )
+            s = jnp.where(kpos <= qpos, s, _MASK)
 
-    m_prev = m_scr[...][:, :1]  # (bq, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_new = l_scr[...][:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-    pv = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    acc_scr[...] = acc_scr[...] * corr + pv
-    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        m_prev = m_scr[...][:, :1]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_scr[...][:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        acc_scr[...] = acc_scr[...] * corr + pv
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    if causal:
+        # a key block that starts past this query block's last row is all
+        # mask: its fold is skipped (its fetch too: see the index maps)
+        pl.when(ki * block_k <= qi * block_q + block_q - 1)(_fold)
+    else:
+        _fold()
 
     @pl.when(ki == n_k - 1)
     def _finish():
@@ -121,61 +139,75 @@ def _fwd_kernel(
 
 
 def _flash_fwd_3d(
-    q3, k3, v3, scale: float, block_q: int, block_k: int, vma=None
+    q3, k3, v3, scale: float, block_q: int, block_k: int, vma=None,
+    causal: bool = False,
 ):
-    """q3/k3/v3: ``(BH, S, D)`` → ``(out (BH, S, D), lse (BH, S))``.
+    """q3/k3: ``(BH, S, D)``, v3: ``(BH, S, Dv)`` → ``(out (BH, S, Dv),
+    lse (BH, S))``.
 
     ``vma``: mesh axes the operands vary over, required when the kernel
     runs inside a ``shard_map`` body (the ring composition) — pallas_call
     must declare its outputs' varying axes there."""
     bh, seq, d = q3.shape
+    dv = v3.shape[-1]
     # a common multiple of BOTH block sizes: padding to max() alone leaves
     # trailing key blocks unvisited when block_k does not divide it
     # (n_k floor-divides), silently dropping real keys from the softmax
     s_pad = _pad_to(seq, math.lcm(block_q, block_k))
-    d_pad = _pad_to(d, _LANES)
+    d_pad, dv_pad = _pad_to(d, _LANES), _pad_to(dv, _LANES)
     pad = [(0, 0), (0, s_pad - seq), (0, d_pad - d)]
-    q3, k3, v3 = (jnp.pad(a, pad) for a in (q3, k3, v3))
+    q3, k3 = (jnp.pad(a, pad) for a in (q3, k3))
+    v3 = jnp.pad(v3, pad[:2] + [(0, dv_pad - dv)])
     n_q, n_k = s_pad // block_q, s_pad // block_k
     kernel = functools.partial(
         _fwd_kernel,
         scale=scale,
         seq_len=seq,
+        block_q=block_q,
         block_k=block_k,
         n_k=n_k,
         masked=s_pad != seq,
+        causal=causal,
     )
+    if causal:
+        # a skipped key block names the last block this query block needs:
+        # an index that does not change is not fetched again
+        def kv_index(b, qi, ki):
+            return (b, jnp.minimum(ki, (qi * block_q + block_q - 1) // block_k), 0)
+    else:
+        def kv_index(b, qi, ki):
+            return (b, ki, 0)
     out, lse8 = pl.pallas_call(
         kernel,
         grid=(bh, n_q, n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d_pad), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, d_pad), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((1, block_k, d_pad), kv_index),
+            pl.BlockSpec((1, block_k, dv_pad), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d_pad), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((1, block_q, dv_pad), lambda b, qi, ki: (b, qi, 0)),
             # lse per q row, broadcast over 8 sublanes to satisfy tiling
             pl.BlockSpec((1, 8, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s_pad, d_pad), q3.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, s_pad, dv_pad), q3.dtype, vma=vma),
             jax.ShapeDtypeStruct((bh, 8, s_pad), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d_pad), jnp.float32),
+            pltpu.VMEM((block_q, dv_pad), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=_interpret_mode(),
     )(q3, k3, v3)
-    return out[:, :seq, :d], lse8[:, 0, :seq]
+    return out[:, :seq, :dv], lse8[:, 0, :seq]
 
 
-def _bwd_3d(scale, block_k, res, do, dlse=None):
+def _bwd_3d(scale, block_k, res, do, dlse=None, causal=False):
     """Blockwise flash backward (pure JAX, exact): scan over key blocks
     using the saved logsumexp; peak memory O(S x block_k).
 
@@ -185,6 +217,7 @@ def _bwd_3d(scale, block_k, res, do, dlse=None):
     ``∂lse_i/∂s_ij = p_ij``), and it never touches ``dv``."""
     q3, k3, v3, out, lse = res
     bh, seq, d = q3.shape
+    dv = v3.shape[-1]
     qf = q3.astype(jnp.float32)
     dof = do.astype(jnp.float32)
     s_pad = _pad_to(seq, block_k)
@@ -192,16 +225,26 @@ def _bwd_3d(scale, block_k, res, do, dlse=None):
     kp = jnp.pad(k3.astype(jnp.float32), padk)
     vp = jnp.pad(v3.astype(jnp.float32), padk)
     kpos = jnp.arange(s_pad)
-    valid = (kpos < seq).astype(jnp.float32)
+    valid = kpos < seq
+    if causal:  # (S, S_pad): a query sees the keys up to its own row
+        valid = valid[None, :] & (kpos[None, :] <= jnp.arange(seq)[:, None])
+    else:
+        valid = valid[None, :]
     k_blocks = kp.reshape(bh, s_pad // block_k, block_k, d).swapaxes(0, 1)
-    v_blocks = vp.reshape(bh, s_pad // block_k, block_k, d).swapaxes(0, 1)
-    m_blocks = valid.reshape(s_pad // block_k, 1, 1, block_k)
+    v_blocks = vp.reshape(bh, s_pad // block_k, block_k, dv).swapaxes(0, 1)
+    m_blocks = (
+        valid.astype(jnp.float32)
+        .reshape(-1, s_pad // block_k, block_k)
+        .swapaxes(0, 1)[:, None]
+    )  # (n_k, 1, S or 1, bk)
     d_i = jnp.sum(dof * out.astype(jnp.float32), axis=-1)  # (BH, S)
 
     def step(dq_acc, blk):
-        k_b, v_b, mask = blk  # (BH, bk, D), (1, 1, bk)
+        k_b, v_b, mask = blk  # (BH, bk, D), (BH, bk, Dv), (1, S or 1, bk)
         s = jnp.einsum("bqd,bkd->bqk", qf, k_b) * scale
-        p = jnp.exp(s - lse[..., None]) * mask  # (BH, S, bk)
+        # masked before the exponential: a key no query may see can score
+        # far above the row's logsumexp, and inf * 0 is not 0
+        p = jnp.exp(jnp.where(mask > 0, s - lse[..., None], _MASK))  # (BH, S, bk)
         dv_b = jnp.einsum("bqk,bqd->bkd", p, dof)
         dp = jnp.einsum("bqd,bkd->bqk", dof, v_b)
         dresid = dp - d_i[..., None]
@@ -216,23 +259,25 @@ def _bwd_3d(scale, block_k, res, do, dlse=None):
         step, jnp.zeros_like(qf), (k_blocks, v_blocks, m_blocks)
     )
     dk = dk_s.swapaxes(0, 1).reshape(bh, s_pad, d)[:, :seq]
-    dv = dv_s.swapaxes(0, 1).reshape(bh, s_pad, d)[:, :seq]
-    return dq.astype(q3.dtype), dk.astype(k3.dtype), dv.astype(v3.dtype)
+    dv_ = dv_s.swapaxes(0, 1).reshape(bh, s_pad, dv)[:, :seq]
+    return dq.astype(q3.dtype), dk.astype(k3.dtype), dv_.astype(v3.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_3d(q3, k3, v3, scale, block_q, block_k):
-    out, _ = _flash_fwd_3d(q3, k3, v3, scale, block_q, block_k)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_3d(q3, k3, v3, scale, block_q, block_k, causal):
+    out, _ = _flash_fwd_3d(q3, k3, v3, scale, block_q, block_k, causal=causal)
     return out
 
 
-def _flash_3d_fwd(q3, k3, v3, scale, block_q, block_k):
-    out, lse = _flash_fwd_3d(q3, k3, v3, scale, block_q, block_k)
+def _flash_3d_fwd(q3, k3, v3, scale, block_q, block_k, causal):
+    out, lse = _flash_fwd_3d(
+        q3, k3, v3, scale, block_q, block_k, causal=causal
+    )
     return out, (q3, k3, v3, out, lse)
 
 
-def _flash_3d_bwd(scale, block_q, block_k, res, do):
-    return _bwd_3d(scale, block_k, res, do)
+def _flash_3d_bwd(scale, block_q, block_k, causal, res, do):
+    return _bwd_3d(scale, block_k, res, do, causal=causal)
 
 
 _flash_3d.defvjp(_flash_3d_fwd, _flash_3d_bwd)
@@ -271,12 +316,15 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = _DEF_BLOCK_Q,
     block_k: int = _DEF_BLOCK_K,
+    causal: bool = False,
 ) -> jnp.ndarray:
     """Exact blockwise attention; drop-in for :func:`dense_attention`.
 
-    Shapes follow the flax convention: q/k/v ``(..., seq, heads,
-    head_dim)`` → ``(..., seq, heads, head_dim)``. Worth using when the
-    patch/sequence axis is long (the score matrix would be large).
+    Shapes follow the flax convention: q/k ``(..., seq, heads, head_dim)``
+    and v ``(..., seq, heads, value_dim)`` → ``(..., seq, heads,
+    value_dim)``. Worth using when the patch/sequence axis is long (the
+    score matrix would be large). ``causal``: a query sees the keys up to
+    its own position.
 
     **Short sequences fall back to** :func:`~gordo_components_tpu.ops.
     attention.dense_attention`: when the whole sequence fits in one
@@ -299,15 +347,17 @@ def flash_attention(
         from .attention import dense_attention  # lazy: avoids an import
         # cycle (attention.py imports this module inside its flash hop)
 
-        return dense_attention(q, k, v, scale)
+        return dense_attention(q, k, v, scale, causal=causal)
     bh = heads
     for dim in batch:  # python shape math — jnp would trace it
         bh *= int(dim)
 
     def to3d(a):
         moved = jnp.moveaxis(a, -2, -3)  # (..., heads, seq, head_dim)
-        return moved.reshape(bh, seq, head_dim)
+        return moved.reshape(bh, seq, a.shape[-1])
 
-    out3 = _flash_3d(to3d(q), to3d(k), to3d(v), float(scale), block_q, block_k)
-    out = out3.reshape(*batch, heads, seq, head_dim)
+    out3 = _flash_3d(
+        to3d(q), to3d(k), to3d(v), float(scale), block_q, block_k, bool(causal)
+    )
+    out = out3.reshape(*batch, heads, seq, v.shape[-1])
     return jnp.moveaxis(out, -3, -2)

@@ -25,6 +25,17 @@ Given ``x`` with ``n`` rows and ``lookback_window = L``:
   ALL of rows ``[i+L, i+L+k)`` — the ``(count, k, F)`` stacked variant for
   models that predict the whole horizon jointly.
 
+- **Several rows a sample** (``rows_out = R > 1``, with ``lookahead = k``):
+  a sample still reads ``L`` rows, and is judged against the ``R``
+  consecutive rows that END at row ``first + L - 1 + k``. Samples lie ``R``
+  rows apart, so that no row is predicted twice and none between two samples
+  is left out, and they are laid FROM THE END (the real rows of a padded
+  machine sit there): the last sample's last target is the last row, and the
+  ``(n - L - k) mod R`` rows in front that no whole step reaches belong to no
+  sample. Usable samples: ``(n - L - k) // R + 1``. ``R = 1`` is every case
+  above. A next-rows decoder is ``k = 1, R = L``: rows ``i .. i+L-1`` in, rows
+  ``i+1 .. i+L`` out.
+
 ``window_output_index`` maps prediction rows back to input-row indices so
 the server/anomaly layers can attach the correct timestamps.
 """
@@ -36,25 +47,45 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def n_windows(n_rows: int, lookback_window: int, lookahead: int = 0) -> int:
+def n_windows(
+    n_rows: int, lookback_window: int, lookahead: int = 0, rows_out: int = 1
+) -> int:
     """Number of usable windows for ``n_rows`` of input.
 
     ``lookahead=0`` → reconstruction (target = last row of window);
     ``lookahead=k >= 1`` → direct ``k``-step forecast (target = the
-    ``k``-th row after the window's last).
+    ``k``-th row after the window's last). ``rows_out=R`` → the samples lie
+    ``R`` rows apart (each judged against ``R`` rows).
     """
     if lookback_window < 1:
         raise ValueError(f"lookback_window must be >= 1, got {lookback_window}")
     if not isinstance(lookahead, (int, np.integer)) or lookahead < 0:
         raise ValueError(f"lookahead must be an int >= 0, got {lookahead}")
-    return max(0, n_rows - lookback_window + 1 - lookahead)
+    if rows_out < 1 or rows_out > lookback_window + lookahead:
+        raise ValueError(
+            f"rows_out must lie in 1..lookback_window+lookahead "
+            f"({lookback_window + lookahead}: a sample's first target is not "
+            f"in front of its first row), got {rows_out}"
+        )
+    reach = n_rows - lookback_window - lookahead
+    return 0 if reach < 0 else reach // rows_out + 1
+
+
+def window_starts(
+    n_rows: int, lookback_window: int, lookahead: int = 0, rows_out: int = 1
+) -> np.ndarray:
+    """First row of each sample's window: ``rows_out`` apart, laid from the
+    end (``arange(count)`` where ``rows_out`` is 1)."""
+    count = n_windows(n_rows, lookback_window, lookahead, rows_out)
+    lead = max(n_rows - lookback_window - lookahead, 0) % rows_out
+    return lead + rows_out * np.arange(count)
 
 
 def sliding_windows(
-    x: jnp.ndarray, lookback_window: int, lookahead: int = 0
+    x: jnp.ndarray, lookback_window: int, lookahead: int = 0, rows_out: int = 1
 ) -> jnp.ndarray:
-    """``(n, F) → (n - L + 1 - lookahead, L, F)`` sliding windows as a static
-    gather.
+    """``(n, F) → (count, L, F)`` sliding windows as a static gather
+    (``count = n - L + 1 - lookahead`` where ``rows_out`` is 1).
 
     ``lookahead`` trims trailing windows so the result zips exactly with the
     matching target fn — ``lookahead=0`` ⇄ :func:`reconstruction_targets`,
@@ -65,13 +96,14 @@ def sliding_windows(
     to a single gather that fuses into downstream ops.
     """
     n = x.shape[0]
-    count = n_windows(n, lookback_window, lookahead)
+    count = n_windows(n, lookback_window, lookahead, rows_out)
     if count <= 0:
         raise ValueError(
             f"Need at least lookback_window+lookahead={lookback_window + lookahead} "
             f"rows, got {n}"
         )
-    idx = np.arange(count)[:, None] + np.arange(lookback_window)[None, :]
+    starts = window_starts(n, lookback_window, lookahead, rows_out)
+    idx = starts[:, None] + np.arange(lookback_window)[None, :]
     return x[idx]
 
 
@@ -163,14 +195,29 @@ def multi_step_targets(
     return x[idx]
 
 
+def sample_targets(
+    x: jnp.ndarray, lookback_window: int, lookahead: int = 0, rows_out: int = 1
+) -> jnp.ndarray:
+    """Each sample's target rows: ``(count, F)`` where ``rows_out`` is 1 (the
+    reconstruction / forecast slices above), else ``(count, rows_out, F)``."""
+    if rows_out == 1:
+        if lookahead == 0:
+            return reconstruction_targets(x, lookback_window)
+        return forecast_targets(x, lookback_window, lookahead)
+    idx = window_output_index(x.shape[0], lookback_window, lookahead, rows_out)
+    return x[idx.reshape(-1, rows_out)]
+
+
 def window_output_index(
-    n_rows: int, lookback_window: int, lookahead: int = 0
+    n_rows: int, lookback_window: int, lookahead: int = 0, rows_out: int = 1
 ) -> np.ndarray:
     """Input-row index each prediction row corresponds to.
 
-    Reconstruction: ``[L-1, …, n-1]``; forecast: ``[L, …, n-1]``. Used to
-    slice timestamps for server responses and anomaly frames.
+    Reconstruction: ``[L-1, …, n-1]``; forecast: ``[L, …, n-1]``; with
+    ``rows_out`` over 1, sample by sample, its ``rows_out`` rows: consecutive
+    rows that end at ``n-1``. Used to slice timestamps for server responses
+    and anomaly frames.
     """
-    count = n_windows(n_rows, lookback_window, lookahead)
-    offset = lookback_window - 1 + lookahead
-    return np.arange(count) + offset
+    starts = window_starts(n_rows, lookback_window, lookahead, rows_out)
+    last = starts + lookback_window - 1 + lookahead
+    return (last[:, None] - (rows_out - 1) + np.arange(rows_out)).reshape(-1)

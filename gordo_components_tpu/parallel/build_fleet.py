@@ -65,7 +65,9 @@ from .fleet import (
     FLEET_CV_METRICS,
     FleetSpec,
     MachineBatch,
+    abstract_state,
     peek_fleet_executable,
+    sequential_fits,
     train_fleet_arrays,
 )
 from .mesh import pad_to_multiple
@@ -362,16 +364,21 @@ def _abstract_result(spec, n_machines, n_rows, n_features, n_targets):
     program — the restore template for orbax (types round-trip exactly)."""
     import jax.numpy as jnp
 
-    from .fleet import make_machine_program, prng_key_width
+    from .fleet import fleet_program, prng_key_width
 
-    program = jax.vmap(make_machine_program(spec, n_rows, n_features, n_targets))
-    return jax.eval_shape(
-        program,
+    avatars = (
         jax.ShapeDtypeStruct((n_machines, n_rows, n_features), jnp.float32),
         jax.ShapeDtypeStruct((n_machines, n_rows, n_targets), jnp.float32),
         jax.ShapeDtypeStruct((n_machines, n_rows), jnp.float32),
         jax.ShapeDtypeStruct((n_machines, prng_key_width()), jnp.uint32),
     )
+    program = fleet_program(spec, n_rows, n_features, n_targets)
+    if not sequential_fits(spec):
+        return jax.eval_shape(program, *avatars)
+    # such a program hands the optimizer's state back beside the result
+    return jax.eval_shape(
+        program, *avatars, abstract_state(spec, n_machines, n_features)
+    )[0]
 
 
 def _leaf_size(a) -> int:
@@ -755,6 +762,9 @@ def _spec_for(
             "single-machine builder for this config"
         )
     dropout = float(model_spec.config.get("dropout", 0.0) or 0.0)
+    # a model that recomputes its activations is trading FLOPs for memory:
+    # its folds run in sequence on one training state (fleet.sequential_fits)
+    # and its slices are sized from the parameter count (_slice_cap)
     memory_constrained = bool(model_spec.config.get("remat", False))
     if cv_parallel is None:
         # derive the fold-execution mode from the model's memory profile: a
@@ -803,7 +813,26 @@ def _spec_for(
         # compile-time cost, so windowed non-remat models keep it even
         # though they don't unroll
         widen_predict=not memory_constrained,
+        rows_out=est.rows_out,
     )
+
+
+def _slice_cap(spec: FleetSpec, n_features: int) -> Optional[int]:
+    """The most machines of a memory-constrained spec a slice may hold, from
+    the parameter count: weights, gradients and the optimizer's moments (the
+    training state and a third of it again) within three quarters of the
+    device's memory; at least one. ``None``: no cap (a spec that is not
+    memory-constrained, or a device that does not say what it holds)."""
+    if not sequential_fits(spec) or spec.widen_predict:
+        return None
+    limit = (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        return None
+    state = sum(
+        leaf.size * leaf.dtype.itemsize
+        for leaf in jax.tree_util.tree_leaves(abstract_state(spec, 1, n_features))
+    )
+    return max(1, int(0.75 * limit // (state * 4 / 3)))
 
 
 def _slice_scaler(stacked: ScalerParams, i: int) -> ScalerParams:
@@ -1357,6 +1386,13 @@ def _build_fleet(
                 # spec+shape) ---------------------------------------------------
                 n_real = len(items)
                 eff = n_real if not slice_size else min(slice_size, n_real)
+                cap = _slice_cap(spec, n_features)
+                if cap is not None and cap < eff:
+                    logger.info(
+                        "Fleet bucket %d: slices of %d, not %d: the training "
+                        "state of more would not fit the device", b + 1, cap, eff,
+                    )
+                    eff = cap
                 n_padded = (
                     pad_to_multiple(eff, mesh.size) if mesh is not None else eff
                 )
@@ -1466,9 +1502,18 @@ def _build_fleet(
                             # async: orbax writes in the background while the
                             # artifact loop below runs (multi-host: a
                             # COLLECTIVE save of the sharded result);
-                            # finalize() joins + deletes
-                            with spans.stage("fleet.checkpoint_save"):
-                                checkpointer.save_async(ckpt_key, result)
+                            # finalize() joins + deletes. A slice of one
+                            # machine has nothing to save that its artifact,
+                            # written next, does not hold
+                            with spans.stage("fleet.checkpoint_save") as saved:
+                                saved["skipped"] = n_padded == 1
+                                if n_padded > 1:
+                                    checkpointer.save_async(ckpt_key, result)
+                        # what the model's own loss counted over each
+                        # machine's final fit (models.train: counters)
+                        for name, counted in (result.counters or {}).items():
+                            if not multihost:
+                                sliced[name] = np.asarray(counted).tolist()
                         if multihost:
                             # restored or trained, the result is globally
                             # sharded: pull only this process's machine block
@@ -1539,6 +1584,10 @@ def _build_fleet(
                                         provenance, output_dir,
                                         model_register_dir,
                                         precision_of(name), journal,
+                                    )
+                                    commit["bytes"] = sum(
+                                        leaf[i].nbytes for leaf in
+                                        jax.tree_util.tree_leaves(result)
                                     )
                                     journal_counts["rebuilt"] += 1
                                     results[name] = model_dir

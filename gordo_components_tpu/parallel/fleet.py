@@ -119,6 +119,10 @@ class FleetSpec(NamedTuple):
     # built without _spec_for must opt in, never inherit 4x-wide predict
     # chunks it didn't budget for.
     widen_predict: bool = False
+    # rows a windowed sample is judged against; its samples lie that many
+    # rows apart (ops.windowing: "several rows a sample"). 1: every window,
+    # a row apart, judged against one row.
+    rows_out: int = 1
 
 
 class MachineBatch(NamedTuple):
@@ -143,6 +147,9 @@ class MachineResult(NamedTuple):
     cv_scores: jnp.ndarray
     tag_thresholds: jnp.ndarray  # (T,) 99th pct of scaled residuals
     total_threshold: jnp.ndarray  # () 99th pct of residual L2 norms
+    # what the model's own loss counted over the final fit's steps (a dict of
+    # arrays; empty for an elementwise loss): span attributes of the slice
+    counters: Any = None
 
 
 FleetResult = MachineResult  # stacked variant returned by train_fleet_arrays
@@ -251,37 +258,67 @@ def timeseries_fold_masks(wt: jnp.ndarray, n_splits: int):
     return masks
 
 
+def _initial_params(spec: FleetSpec, n_features: int) -> Callable:
+    """``init_key → params``: what every fit of a machine starts from."""
+    sample_shape = (
+        (1, n_features) if spec.lookahead is None
+        else (1, spec.lookback_window, n_features)
+    )
+
+    def draw(init_key):
+        return spec.module.init(
+            init_key, jnp.zeros(sample_shape, jnp.float32), deterministic=True
+        )["params"]
+
+    return draw
+
+
+def sequential_fits(spec: FleetSpec) -> bool:
+    """Whether the machine's fits (the CV folds', then the final one) run one
+    after another on ONE training state: every spec but the vmapped-folds
+    one. Such a program takes that state as a fifth, donated argument
+    (:func:`fleet_state` draws it) and hands the optimizer's part back."""
+    return not (spec.n_splits > 0 and spec.cv_parallel)
+
+
 def make_machine_program(
     spec: FleetSpec, n_rows: int, n_features: int, n_targets: int
 ) -> Callable:
     """Pure fn ``(X (N,F), y (N,T), w (N,), key) → MachineResult`` — the
-    whole per-machine build as one traceable program."""
+    whole per-machine build as one traceable program. Where the fits run in
+    sequence (:func:`sequential_fits`): ``(X, y, w, key, state) →
+    (MachineResult, optimizer state)``, ``state`` the machine's training
+    state ``(params, optimizer state)`` as buffers of the caller's."""
 
     apply_fn = spec.module.apply
     fit_unroll = spec.fit_unroll
-    fit_fn = make_fit_fn(
-        apply_fn,
-        spec.optimizer,
+    fit_kwargs = dict(
         loss=spec.loss,
         batch_size=spec.batch_size,
         epochs=spec.epochs,
         use_dropout=spec.use_dropout,
         unroll=fit_unroll,
     )
+    fit_fn = make_fit_fn(apply_fn, spec.optimizer, **fit_kwargs)
     predict_fn = make_predict_fn(apply_fn)
 
     L = spec.lookback_window
     la = spec.lookahead
+    R = spec.rows_out  # rows a sample is judged against (windowed models)
     if la is None:
         n_samples = n_rows
     else:
-        n_samples = n_rows - L + 1 - la
+        n_samples = windowing.n_windows(n_rows, L, la, R)
         if n_samples < spec.batch_size:
             raise ValueError(
                 f"Bucket rows {n_rows} give {n_samples} windows "
                 f"(< batch_size {spec.batch_size})"
             )
     padded = pad_to_multiple(n_samples, spec.batch_size)
+
+    def per_row(sample_values):
+        """A per-sample vector or mask (last axis) as one per predicted row."""
+        return sample_values if R == 1 else jnp.repeat(sample_values, R, axis=-1)
 
     def prepare(Xs, ys, w):
         """Scaled rows → (inputs, targets, sample weights) padded to a whole
@@ -295,34 +332,39 @@ def make_machine_program(
         below), so HBM holds ``(n_rows, F)`` instead of the L×-blown-up
         ``(n_windows, L, F)`` tensor — the enabler for plant-scale buckets
         (10k tags × L=32 windows would be ~1 GB per machine materialized).
+        Samples lie ``rows_out`` rows apart, laid from the end, each judged
+        against its ``rows_out`` rows (``targets (samples, rows_out, T)``
+        where that is over 1).
 
         Row padding may sit ANYWHERE in the row axis (fold boundaries are
         computed on real-sample ranks, so placement is free): a window's
-        weight is the MIN of its rows' weights times its target row's
-        weight, so any window touching padding is masked out exactly.
+        weight is the MIN of its rows' weights times its target rows'
+        weights, so any window touching padding is masked out exactly.
         """
         if la is None:
             inputs, targets, wt = Xs, ys, w
         else:
-            inputs = jnp.arange(n_samples)
-            targets = (
-                windowing.reconstruction_targets(ys, L)
-                if la == 0
-                else windowing.forecast_targets(ys, L, la)
-            )
-            target_idx = windowing.window_output_index(n_rows, L, la)
-            window_w = windowing.sliding_windows(w[:, None], L, la)[:, :, 0]
-            wt = jnp.min(window_w, axis=1) * w[target_idx]
+            starts = windowing.window_starts(n_rows, L, la, R)
+            inputs = int(starts[0]) + R * jnp.arange(n_samples)
+            targets = windowing.sample_targets(ys, L, la, R)
+            target_idx = windowing.window_output_index(n_rows, L, la, R)
+            window_w = windowing.sliding_windows(w[:, None], L, la, R)[:, :, 0]
+            target_w = w[target_idx]
+            if R > 1:
+                target_w = jnp.min(target_w.reshape(n_samples, R), axis=1)
+            wt = jnp.min(window_w, axis=1) * target_w
         pad = padded - inputs.shape[0]
         if pad:
             inputs = jnp.pad(inputs, ((0, pad),) + ((0, 0),) * (inputs.ndim - 1))
-            targets = jnp.pad(targets, ((0, pad), (0, 0)))
+            targets = jnp.pad(
+                targets, ((0, pad),) + ((0, 0),) * (targets.ndim - 1)
+            )
             wt = jnp.pad(wt, (0, pad))
         return inputs, targets, wt
 
-    sample_shape = (1, n_features) if la is None else (1, L, n_features)
+    draw = _initial_params(spec, n_features)
 
-    def program(X, y, w, key) -> MachineResult:
+    def program(X, y, w, key, state=None):
         sx = _fit_scaler(spec.scaler, spec.scaler_options, spec.feature_range, X, w)
         if spec.scale_targets:
             # the TransformedTargetRegressor's transformer — its own kind,
@@ -343,7 +385,9 @@ def make_machine_program(
         Xs = X * sx.scale + sx.offset
         ys = y * sy.scale + sy.offset
         inputs, targets, wt = prepare(Xs, ys, w)
-        raw_targets = (targets - sy.offset) / sy.scale
+        # residuals, error scaler and thresholds are over predicted ROWS
+        raw_targets = ((targets - sy.offset) / sy.scale).reshape(-1, n_targets)
+        wt_rows = per_row(wt)
 
         if la is None:
             fit_local = fit_fn
@@ -358,15 +402,7 @@ def make_machine_program(
                     variables, windowing.gather_windows(Xs, starts, L), **kwargs
                 )
 
-            fit_local = make_fit_fn(
-                windowed_apply,
-                spec.optimizer,
-                loss=spec.loss,
-                batch_size=spec.batch_size,
-                epochs=spec.epochs,
-                use_dropout=spec.use_dropout,
-                unroll=fit_unroll,
-            )
+            fit_local = make_fit_fn(windowed_apply, spec.optimizer, **fit_kwargs)
             windowed_predict = make_predict_fn(windowed_apply)
 
             # prediction has no optimizer state or backward pass, so its
@@ -402,13 +438,10 @@ def make_machine_program(
                 preds = jax.lax.map(
                     lambda sb: windowed_predict(params, sb), chunks
                 )
-                return preds.reshape(padded, n_targets)
+                return preds.reshape(padded * R, n_targets)
 
         keys = jax.random.split(key, spec.n_splits + 2)
         init_key, fit_key, fold_keys = keys[0], keys[1], keys[2:]
-        params0 = spec.module.init(
-            init_key, jnp.zeros(sample_shape, jnp.float32), deterministic=True
-        )["params"]
 
         emin = jnp.full((n_targets,), jnp.inf)
         emax = jnp.full((n_targets,), -jnp.inf)
@@ -417,100 +450,121 @@ def make_machine_program(
         if spec.n_splits > 0:
             train_masks = jnp.stack([m[0] for m in fold_masks])
             test_masks = jnp.stack([m[1] for m in fold_masks])
-        if spec.n_splits > 0 and spec.cv_parallel:
+        else:
+            train_masks = test_masks = jnp.zeros((0, padded))
+        # every fit of the machine: the K folds', then the final one
+        all_w = jnp.concatenate([train_masks * wt[None, :], wt[None, :]])
+        all_keys = jnp.concatenate([fold_keys, fit_key[None]])
+        # rank-space folds guarantee a nonempty train region whenever a
+        # test region is nonempty; machines too short for any fold
+        # (n_real < n_splits+1) get empty test masks here and fall back
+        # to final-model residuals below
+        fold_test_masks = per_row(test_masks * wt[None, :])  # (K, rows)
+        if not sequential_fits(spec):
             # parallel CV: the K fold fits and the final fit are independent
             # programs with identical shapes, so ONE vmapped fit of K+1
             # weight vectors replaces K+1 sequential fits — sequential depth
             # drops to a single fit's epochs×batches at (K+1)× step memory
             # (see FleetSpec.cv_parallel). Per-fit keys match the scan path
             # exactly, so both modes train identical models.
-            all_w = jnp.concatenate([train_masks * wt[None, :], wt[None, :]])
-            all_keys = jnp.concatenate([fold_keys, fit_key[None]])
+            params0 = draw(init_key)
             fits = jax.vmap(
                 lambda wv, kv: fit_local(params0, inputs, targets, wv, kv)
             )(all_w, all_keys)
             with jax.named_scope("cv_predict"):
-                preds = jax.vmap(predict_all)(fits.params)  # (K+1, P, T)
+                preds = jax.vmap(predict_all)(fits.params)  # (K+1, rows, T)
             preds_raw = (preds - sy.offset) / sy.scale
-            errs_all = jnp.abs(raw_targets[None] - preds_raw)
-            fold_errors, err_final = errs_all[:-1], errs_all[-1]
-            # rank-space folds guarantee a nonempty train region whenever a
-            # test region is nonempty; machines too short for any fold
-            # (n_real < n_splits+1) get empty test masks here and fall back
-            # to final-model residuals below
-            fold_test_masks = test_masks * wt[None, :]
+            errs = jnp.abs(raw_targets[None] - preds_raw)
             fmask = (fold_test_masks > 0)[:, :, None]
-            emin = jnp.min(
-                jnp.where(fmask, fold_errors, jnp.inf), axis=(0, 1)
-            )
-            emax = jnp.max(
-                jnp.where(fmask, fold_errors, -jnp.inf), axis=(0, 1)
-            )
+            emin = jnp.min(jnp.where(fmask, errs[:-1], jnp.inf), axis=(0, 1))
+            emax = jnp.max(jnp.where(fmask, errs[:-1], -jnp.inf), axis=(0, 1))
             cv_scores = jax.vmap(_masked_metrics, in_axes=(None, 0, 0))(
                 raw_targets, preds_raw[:-1], fold_test_masks
             )
-            final = FitResult(
-                params=jax.tree_util.tree_map(lambda a: a[-1], fits.params),
-                loss_history=fits.loss_history[-1],
-            )
+            final = jax.tree_util.tree_map(lambda a: a[-1], fits)
+            state_out = None
         else:
-            if spec.n_splits > 0:
-                # sequential CV: ONE fold fit in the compiled graph, scanned
-                # over the stacked masks (folds share every shape) — an
-                # unrolled Python loop would inline n_splits copies of the
-                # whole training program and multiply XLA compile time
-                # accordingly; vs cv_parallel this holds step memory at 1×,
-                # the right trade for plant-scale remat configs
+            # sequential fits: ONE fit in the compiled graph, scanned over
+            # the K+1 stacked weight vectors (the fits share every shape) —
+            # an unrolled Python loop would inline K+1 copies of the whole
+            # training program and multiply XLA compile time accordingly;
+            # vs cv_parallel this holds step memory at 1×, the right trade
+            # for plant-scale remat configs and the only one for a model
+            # whose training state fills the chip. The state is the scan's
+            # carry, in the caller's buffers, and every fit trains it in
+            # place: the first as it came (:func:`machine_state` drew it
+            # from this key), each later one after a reset that selects the
+            # start over what the last fit left, element by element, into
+            # the same buffers (a select on the fit's index, which the
+            # compiler cannot fold: a start drawn into buffers of its own
+            # would be a second copy of the state, alive through the fit).
+            # The final fit runs last and leaves the machine's parameters
+            # there.
 
-                def fold_step(carry, xs):
-                    emin, emax = carry
-                    train_mask, test_mask, fold_key = xs
-                    res = fit_local(
-                        params0, inputs, targets, wt * train_mask, fold_key
-                    )
-                    with jax.named_scope("cv_predict"):
-                        pred = predict_all(res.params)
-                    pred_raw = (pred - sy.offset) / sy.scale
-                    err = jnp.abs(raw_targets - pred_raw)
-                    # rank-space folds guarantee a nonempty train region
-                    # whenever a test region is nonempty; machines too short
-                    # for any fold (n_real < n_splits+1) get empty test masks
-                    # here and fall back to final-model residuals below
-                    wtest = wt * test_mask
-                    mask = (wtest > 0)[:, None]
-                    emin = jnp.minimum(
-                        emin, jnp.min(jnp.where(mask, err, jnp.inf), axis=0)
-                    )
-                    emax = jnp.maximum(
-                        emax, jnp.max(jnp.where(mask, err, -jnp.inf), axis=0)
-                    )
-                    scores = _masked_metrics(raw_targets, pred_raw, wtest)
-                    return (emin, emax), (scores, err, wtest)
-
-                (emin, emax), (cv_scores, fold_errors, fold_test_masks) = (
-                    jax.lax.scan(
-                        fold_step,
-                        (emin, emax),
-                        (train_masks, test_masks, fold_keys),
-                    )
+            def one_fit(carry, xs):
+                (params, opt_state), emin, emax = carry
+                weights, wtest, fit_key_, first = xs
+                fresh = draw(init_key)
+                reset = lambda new, old: jnp.where(first, old, new)  # noqa: E731
+                params = jax.tree_util.tree_map(reset, fresh, params)
+                opt_state = jax.tree_util.tree_map(
+                    reset, spec.optimizer.init(params), opt_state
                 )
-            else:
-                cv_scores = jnp.zeros((0, len(FLEET_CV_METRICS)))
-                fold_errors = jnp.zeros((0, n_points, n_targets))
-                fold_test_masks = jnp.zeros((0, n_points))
+                res = fit_local(
+                    params, inputs, targets, weights, fit_key_,
+                    opt_state=opt_state,
+                )
+                with jax.named_scope("cv_predict"):
+                    pred = predict_all(res.params)
+                pred_raw = (pred - sy.offset) / sy.scale
+                err = jnp.abs(raw_targets - pred_raw)
+                mask = (wtest > 0)[:, None]
+                emin = jnp.minimum(
+                    emin, jnp.min(jnp.where(mask, err, jnp.inf), axis=0)
+                )
+                emax = jnp.maximum(
+                    emax, jnp.max(jnp.where(mask, err, -jnp.inf), axis=0)
+                )
+                scores = _masked_metrics(raw_targets, pred_raw, wtest)
+                return ((res.params, res.opt_state), emin, emax), (
+                    scores, err, res.loss_history, res.counters
+                )
 
-            final = fit_local(params0, inputs, targets, wt, fit_key)
-
+            if state is None:  # a caller without buffers of its own
+                params0 = draw(init_key)
+                state = (params0, spec.optimizer.init(params0))
+            n_fits = spec.n_splits + 1
+            (state, emin, emax), (scores, errs, histories, counters) = (
+                jax.lax.scan(
+                    one_fit,
+                    (state, emin, emax),
+                    (
+                        all_w,
+                        # the final fit tests nothing: its residuals are the
+                        # fallback below, never the error scaler's while a
+                        # fold covered the machine
+                        jnp.concatenate(
+                            [fold_test_masks, jnp.zeros((1, n_points))]
+                        ),
+                        all_keys,
+                        jnp.arange(n_fits) == 0,
+                    ),
+                )
+            )
+            cv_scores = scores[:-1]
+            final = FitResult(
+                params=state[0],
+                loss_history=histories[-1],
+                counters=jax.tree_util.tree_map(lambda c: c[-1], counters),
+            )
+            state_out = state[1]
+        err_final = errs[-1]
+        with jax.named_scope("error_scaler_thresholds"):
             # final-model residuals over all real rows: the error-scaler
             # source when CV is off, and the per-machine fallback when no CV
             # fold covered this machine's data (short machine in a tall
             # bucket)
-            with jax.named_scope("final_predict"):
-                pred_final = predict_all(final.params)
-            pred_final_raw = (pred_final - sy.offset) / sy.scale
-            err_final = jnp.abs(raw_targets - pred_final_raw)
-        with jax.named_scope("error_scaler_thresholds"):
-            mask_final = (wt > 0)[:, None]
+            mask_final = (wt_rows > 0)[:, None]
             fmin = jnp.min(jnp.where(mask_final, err_final, jnp.inf), axis=0)
             fmax = jnp.max(jnp.where(mask_final, err_final, -jnp.inf), axis=0)
 
@@ -525,11 +579,10 @@ def make_machine_program(
 
             # thresholds: 99th percentile of scaled residuals — out-of-fold
             # when CV covered this machine, final-model residuals otherwise
-            errs = jnp.concatenate([fold_errors, err_final[None]])  # (K+1, P, T)
-            fallback_mask = wt * jnp.where(use_cv, 0.0, 1.0)
+            fallback_mask = wt_rows * jnp.where(use_cv, 0.0, 1.0)
             masks = jnp.concatenate(
                 [fold_test_masks, fallback_mask[None]]
-            )  # (K+1, P)
+            )  # (K+1, rows)
             scaled = errs * error_scaler.scale + error_scaler.offset
             scaled = jnp.where((masks > 0)[:, :, None], scaled, jnp.nan)
             tag_thresholds = jnp.nan_to_num(
@@ -540,7 +593,7 @@ def make_machine_program(
             ) + jnp.where(masks > 0, 0.0, jnp.nan)
             total_threshold = jnp.nan_to_num(jnp.nanpercentile(norms, 99))
 
-        return MachineResult(
+        result = MachineResult(
             params=final.params,
             input_scaler=sx,
             target_scaler=sy,
@@ -549,13 +602,46 @@ def make_machine_program(
             cv_scores=cv_scores,
             tag_thresholds=tag_thresholds,
             total_threshold=total_threshold,
+            counters=final.counters,
         )
+        return result if state_out is None else (result, state_out)
 
     return program
 
 
+def machine_state(spec: FleetSpec, n_features: int) -> Callable:
+    """``key → (params, optimizer state)``: one machine's training state as
+    :func:`make_machine_program` draws it at the start of a fit, from the
+    same key. :func:`fleet_state` stacks it into buffers the allocator
+    counts, which the program trains in place."""
+    initial = _initial_params(spec, n_features)
+
+    def draw(key):
+        params = initial(jax.random.split(key, spec.n_splits + 2)[0])
+        return params, spec.optimizer.init(params)
+
+    return draw
+
+
 _PROGRAM_CACHE: dict = {}
 _PROGRAM_CACHE_MAX = 128  # distinct (spec, shape, mesh) programs kept live
+
+
+def _over_machines(machine_program: Callable) -> Callable:
+    """``machine_program`` over a leading machine axis: ``vmap``, except that
+    a slice of ONE machine runs the machine's program as it is (a batch of
+    one would turn its conditionals into both branches and its grouped
+    products into batched ones). The result is called ``program``: the
+    benchmark finds the train program's runs in a device trace by the XLA
+    module's name, ``jit_program``."""
+
+    def program(*args):
+        if args[0].shape[0] > 1:
+            return jax.vmap(machine_program)(*args)
+        out = machine_program(*jax.tree_util.tree_map(lambda a: a[0], args))
+        return jax.tree_util.tree_map(lambda a: a[None], out)
+
+    return program
 
 
 def fleet_program(
@@ -573,24 +659,63 @@ def fleet_program(
     The batch buffers are NOT donated: no output has a batch buffer's
     shape, so XLA has nothing to alias them to — on a v5e (PR 21) the
     donation was reported "not usable" for all four inputs and changed
-    nothing but the warning count."""
+    nothing but the warning count. The training state of a program whose
+    fits run in sequence (:func:`sequential_fits`) IS: its fifth argument,
+    aliased to the parameters and the optimizer state it hands back."""
 
     def build():
         _M_FLEET_PROGRAMS.labels("jit").inc()
-        program = jax.vmap(
+        program = _over_machines(
             make_machine_program(spec, n_rows, n_features, n_targets)
         )
+        n_args = 5 if sequential_fits(spec) else 4
+        donate = (4,) if sequential_fits(spec) else ()
         if mesh is None:
-            return jax.jit(program)
+            return jax.jit(program, donate_argnums=donate)
         shard = fleet_sharding(mesh)
         return jax.jit(
             program,
-            in_shardings=(shard, shard, shard, shard),
+            in_shardings=(shard,) * n_args,
             out_shardings=shard,
+            donate_argnums=donate,
         )
 
     key = (spec, n_rows, n_features, n_targets, mesh)
     return _cached(_PROGRAM_CACHE, _PROGRAM_CACHE_MAX, key, build)
+
+
+_STATE_CACHE: dict = {}
+
+
+def fleet_state(spec: FleetSpec, n_machines: int, n_features: int, mesh=None):
+    """The compiled program ``keys (M, key_width) → the M machines' training
+    states``, cached: what a :func:`sequential_fits` program is handed as its
+    fifth argument. Drawn in a program of its own so that the parameters and
+    the optimizer's moments are buffers the device's allocator counts and the
+    train program trains in place (donated), not temporaries inside it."""
+
+    def build():
+        draw = _over_machines(machine_state(spec, n_features))
+        draw.__name__ = "machine_states"  # the XLA module: not the train program's
+        keys = jax.ShapeDtypeStruct((n_machines, prng_key_width()), jnp.uint32)
+        if mesh is None:
+            return jax.jit(draw).lower(keys).compile()
+        shard = fleet_sharding(mesh)
+        return (
+            jax.jit(draw, in_shardings=(shard,), out_shardings=shard)
+            .lower(keys).compile()
+        )
+
+    key = (spec, n_machines, n_features, mesh)
+    return _cached(_STATE_CACHE, _PROGRAM_CACHE_MAX, key, build)
+
+
+def abstract_state(spec: FleetSpec, n_machines: int, n_features: int):
+    """Shapes of :func:`fleet_state`'s result for ``n_machines``."""
+    return jax.eval_shape(
+        _over_machines(machine_state(spec, n_features)),
+        jax.ShapeDtypeStruct((n_machines, prng_key_width()), jnp.uint32),
+    )
 
 
 _EXEC_CACHE: dict = {}
@@ -625,7 +750,9 @@ def fleet_executable(
     vs 0.7 ms program execution, i.e. the relayout would dominate the
     fleet hot loop ~300×.
 
-    Returns ``(compiled, formats)``.
+    Returns ``(compiled, formats)``: the formats of the four batch arrays
+    (a :func:`sequential_fits` program's fifth argument, the training state,
+    is :func:`fleet_state`'s result as it comes).
     """
     def build():
         program = fleet_program(spec, n_rows, n_features, n_targets, mesh=mesh)
@@ -635,6 +762,8 @@ def fleet_executable(
             jax.ShapeDtypeStruct((n_machines, n_rows), jnp.float32),
             jax.ShapeDtypeStruct((n_machines, prng_key_width()), jnp.uint32),
         )
+        if sequential_fits(spec):
+            avatars += (abstract_state(spec, n_machines, n_features),)
         compile_started = time.perf_counter()
         compiled = program.lower(*avatars).compile()
         _M_FLEET_PROGRAMS.labels("aot").inc()
@@ -742,7 +871,7 @@ def fleet_flops_accounting(
         n_samples = n_rows
         x_elem = (n_features,)
     else:
-        n_samples = n_rows - L + 1 - la
+        n_samples = windowing.n_windows(n_rows, L, la, spec.rows_out)
         x_elem = (L, n_features)
     padded = pad_to_multiple(n_samples, spec.batch_size)
     steps_per_epoch = padded // spec.batch_size
@@ -772,8 +901,9 @@ def fleet_flops_accounting(
         x_sd = jax.ShapeDtypeStruct(
             (n_machines, spec.batch_size, *x_elem), jnp.float32
         )
+        y_elem = (n_targets,) if spec.rows_out == 1 else (spec.rows_out, n_targets)
         y_sd = jax.ShapeDtypeStruct(
-            (n_machines, spec.batch_size, n_targets), jnp.float32
+            (n_machines, spec.batch_size, *y_elem), jnp.float32
         )
         w_sd = jax.ShapeDtypeStruct((n_machines, spec.batch_size), jnp.float32)
         k_sd = jax.ShapeDtypeStruct((n_machines, prng_key_width()), jnp.uint32)
@@ -864,6 +994,9 @@ def train_fleet_arrays(
             peek_fleet_executable(spec, *shape, mesh=mesh) is not None
         )
         lookup_started = time.perf_counter()
+        if sequential_fits(spec):  # the small program first: what follows
+            # the train program's load is then the slice's own work
+            draw_state = fleet_state(spec, n_machines, n_features, mesh=mesh)
         compiled, formats = fleet_executable(spec, *shape, mesh=mesh)
         # lower + compile (or the persistent cache's load) on a miss
         found["compile_s"] = (
@@ -873,7 +1006,13 @@ def train_fleet_arrays(
     with spans.stage("fleet.ingest", step="device_put"):
         placed = put_fleet_batch(batch, formats)
     with spans.stage("fleet.execute"):
-        result = compiled(placed.X, placed.y, placed.w, placed.keys)
+        if sequential_fits(spec):
+            # the machines' training state, drawn into buffers of its own
+            # and trained in place; the optimizer's part is dropped here
+            state = draw_state(placed.keys)
+            result, _ = compiled(placed.X, placed.y, placed.w, placed.keys, state)
+        else:
+            result = compiled(placed.X, placed.y, placed.w, placed.keys)
         # the span ends where the device does. Every output is the one
         # program's and they become ready together, and every caller
         # reads them next, so the wait serialises nothing

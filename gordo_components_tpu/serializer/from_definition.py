@@ -76,6 +76,7 @@ _SHORT_NAMES: Dict[str, str] = {
         "MultiStepForecast",
         "PatchTSTAutoEncoder",
         "PatchTSTForecast",
+        "MoEMLAForecast",
         "KerasAutoEncoder",
         "KerasLSTMAutoEncoder",
         "KerasLSTMForecast",

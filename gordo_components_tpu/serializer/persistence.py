@@ -106,18 +106,22 @@ def _write_state_npz(path: str, arrays: Dict[str, np.ndarray]) -> None:
     member with the wall clock, so two saves of identical arrays differ —
     which would break manifest-hash comparison between a server's artifact
     and its ``/download-model`` blob. Same format (``np.load`` reads it),
-    fixed ZIP-epoch timestamps, sorted member order."""
+    fixed ZIP-epoch timestamps, sorted member order. Streamed: a leaf goes
+    into its member in numpy's own chunks, so a model of gigabytes is
+    written without a second copy of any leaf on the host."""
     from numpy.lib import format as npformat
 
     with zipfile.ZipFile(path, "w", zipfile.ZIP_STORED, allowZip64=True) as zf:
         for name in sorted(arrays):
-            buffer = io.BytesIO()
-            npformat.write_array(
-                buffer, np.asarray(arrays[name]), allow_pickle=False
-            )
+            array = np.asarray(arrays[name])
             info = zipfile.ZipInfo(name + ".npy", date_time=_ZIP_EPOCH)
             info.external_attr = 0o644 << 16
-            zf.writestr(info, buffer.getvalue())
+            # a member's size is not known to the archive until it is
+            # written: only a leaf that may pass 2 GiB gets the wider header,
+            # so every smaller model's file is byte for byte what one
+            # ``writestr`` of the whole member gave
+            with zf.open(info, "w", force_zip64=array.nbytes >= 2**31 - 2**16) as member:
+                npformat.write_array(member, array, allow_pickle=False)
 
 
 def write_artifact_files(

@@ -98,6 +98,48 @@ def test_flash_matches_dense_gradients():
         )
 
 
+@pytest.mark.parametrize(
+    "seq,blocks",
+    [
+        (40, dict(block_q=16, block_k=16)),  # several tiles, the last one padded
+        (37, dict(block_q=8, block_k=16)),  # odd length, unequal blocks
+        (160, {}),  # the default blocks
+    ],
+)
+def test_causal_flash_with_a_value_width_of_its_own_matches_dense(seq, blocks):
+    """Latent attention's shape: keys and queries 24 wide (16 + 8 rotary),
+    values 16 wide, a query sees the keys up to its own position. Forward
+    and the gradient of all three against ``dense_attention``."""
+    rng = np.random.default_rng(21)
+    q, k = (jnp.asarray(rng.normal(scale=0.5, size=(2, seq, 2, 24)), jnp.float32) for _ in range(2))
+    v = jnp.asarray(rng.normal(scale=0.5, size=(2, seq, 2, 16)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=v.shape), jnp.float32)
+    scale = 24 ** -0.5
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, scale=scale, causal=True, **blocks)
+
+    def dense(q, k, v):
+        return dense_attention(q, k, v, scale=scale, causal=True)
+
+    ours, ref = flash(q, k, v), dense(q, k, v)
+    assert ours.shape == v.shape
+    np.testing.assert_allclose(np.asarray(ours), np.asarray(ref), atol=2e-5)
+    # the first position sees itself alone: its value comes back unmixed
+    np.testing.assert_allclose(np.asarray(ours[:, 0]), np.asarray(v[:, 0]), atol=1e-6)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * g)
+
+    for a, b, name in zip(
+        jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v),
+        jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v), "qkv",
+    ):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=5e-5, err_msg=f"d{name}"
+        )
+
+
 def test_flash_bfloat16_forward():
     q, k, v = _qkv((2, 32, 2, 8), seed=5, dtype=jnp.bfloat16)
     ours = flash_attention(q, k, v, block_q=16, block_k=16)

@@ -485,3 +485,80 @@ def test_load_external_plugin_opt_in(tmp_path):
 
     trusted = load(model_dir, allow_external=True)
     np.testing.assert_allclose(trusted.transform(X), np.abs(X), rtol=1e-6)
+
+
+def test_state_npz_is_byte_for_byte_what_one_write_a_member_gave(tmp_path):
+    """The streamed ``state.npz`` (a leaf goes into its member in chunks, so
+    a model of gigabytes has no second copy on the host) is the same file,
+    byte for byte, as a whole member written at once: the manifest hash of a
+    small model's artifact does not depend on which of the two wrote it."""
+    import io
+    import zipfile
+
+    from numpy.lib import format as npformat
+
+    from gordo_components_tpu.serializer import persistence
+
+    rng = np.random.default_rng(1)
+    arrays = {
+        "b/kernel": rng.normal(size=(37, 5)).astype(np.float32),
+        "a/scale": rng.normal(size=(4,)),
+        "scalar": np.float32(2.5),
+        "ids": np.arange(6, dtype=np.int32).reshape(2, 3),
+    }
+    path = str(tmp_path / "state.npz")
+    persistence._write_state_npz(path, arrays)
+
+    whole = io.BytesIO()
+    with zipfile.ZipFile(whole, "w", zipfile.ZIP_STORED, allowZip64=True) as zf:
+        for name in sorted(arrays):
+            buffer = io.BytesIO()
+            npformat.write_array(buffer, np.asarray(arrays[name]), allow_pickle=False)
+            info = zipfile.ZipInfo(name + ".npy", date_time=persistence._ZIP_EPOCH)
+            info.external_attr = 0o644 << 16
+            zf.writestr(info, buffer.getvalue())
+    with open(path, "rb") as fh:
+        assert fh.read() == whole.getvalue()
+    with np.load(path) as loaded:
+        assert sorted(loaded.files) == sorted(arrays)
+        for name, value in arrays.items():
+            np.testing.assert_array_equal(loaded[name], value)
+
+
+def test_a_loaded_models_first_predict_from_many_threads_at_once(X, tmp_path):
+    """A loaded estimator keeps its parameters on the host until it first
+    predicts. Threads that all meet that first call together (a server's
+    first requests) each get the fitted model's prediction, and the
+    parameters end up placed once for the calls that follow."""
+    import threading
+
+    import jax
+
+    pipe = pipeline_from_definition(REFERENCE_STYLE_DEFINITION)
+    pipe.fit(X)
+    expected = pipe.predict(X)
+    out = str(tmp_path / "model")
+    dump(pipe, out)
+    loaded = load(out)
+    estimator = loaded.steps[-1][1]
+    assert isinstance(jax.tree_util.tree_leaves(estimator.params_)[0], np.ndarray)
+
+    gate, results, errors = threading.Barrier(8), [None] * 8, []
+
+    def first_call(i):
+        try:
+            gate.wait()
+            results[i] = loaded.predict(X)
+        except Exception as exc:  # noqa: BLE001 (handed to the assertion below)
+            errors.append(exc)
+
+    threads = [threading.Thread(target=first_call, args=(i,)) for i in range(8)]
+    for thread in threads:
+        thread.start()
+    for thread in threads:
+        thread.join()
+    assert not errors, errors
+    for got in results:
+        np.testing.assert_allclose(got, expected, rtol=1e-5)
+    assert isinstance(jax.tree_util.tree_leaves(estimator.params_)[0], jax.Array)
+    np.testing.assert_allclose(loaded.predict(X), expected, rtol=1e-5)
