@@ -1,7 +1,7 @@
 # Developer entry points (reference parity: the reference ships a Makefile
 # driving tests and its four docker images).
 
-.PHONY: lint test testfast bench bench-serving chip-smoke metrics-smoke chaos-smoke store-fsck perf-smoke trace-smoke coldstart-smoke megabatch-smoke router-smoke slo-smoke quant-smoke autopilot-smoke capacity-smoke mesh-smoke telemetry-smoke qos-smoke reconcile-smoke layout-smoke incident-smoke smoke images builder-image server-image watchman-image
+.PHONY: lint test testfast chip-smoke metrics-smoke chaos-smoke store-fsck perf-smoke trace-smoke coldstart-smoke megabatch-smoke router-smoke slo-smoke quant-smoke autopilot-smoke capacity-smoke mesh-smoke telemetry-smoke qos-smoke reconcile-smoke layout-smoke incident-smoke smoke images builder-image server-image watchman-image
 
 # invariant linter (docs/ARCHITECTURE.md §17/§21): lock discipline
 # against the declared hierarchy, blocking-calls-under-hot-locks,
@@ -22,14 +22,6 @@ test:
 
 testfast:
 	python -m pytest tests/ -q -x -m "not slow"
-
-# both run on the accelerator JAX finds and exit non-zero when there is
-# none; prefix JAX_PLATFORMS=cpu to ask for the CPU on purpose
-bench:
-	python bench.py
-
-bench-serving:
-	python bench_serving.py
 
 # needs a TPU (exit 2 without one): fleet-build -> store -> run-server ->
 # second boot -> Pallas flash kernel, one process; stdout ends with a JSON
@@ -131,7 +123,7 @@ autopilot-smoke:
 # through 2 lazy workers at zero failures / zero SLO breaches, and the
 # Prometheus exposition size-bounded (top-K + `other` machine labels)
 # at any fleet size. GORDO_CAPACITY_MACHINES/SECONDS resize; the 10k+
-# sweep lives in the bench `capacity` block and the `slow` test
+# sweep is `tools/capacity_harness.py full` and the `slow` test
 capacity-smoke:
 	JAX_PLATFORMS=cpu python tools/capacity_smoke.py
 

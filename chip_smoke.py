@@ -5,9 +5,9 @@
 belongs to one process), through the entry points a user calls:
 
 1. device      what JAX found; anything but a TPU is exit 2, nothing built
-2. fleet_build ``gordo fleet-build``: the flagship dense-AE fleet as
-               bench.py sizes it (128 machines x 864 rows x 10 tags,
-               feedforward_hourglass, 10 epochs, batch 64, 3-fold CV)
+2. fleet_build ``gordo fleet-build``: the flagship dense-AE fleet (128
+               machines x 864 rows x 10 tags, feedforward_hourglass, 10
+               epochs, batch 64, 3-fold CV)
 3. serve       ``gordo run-server`` on the built tree, real HTTP: a few
                sequential 144x10 anomaly requests, then concurrent bursts
                over distinct machines (the fused megabatch program and its
@@ -567,7 +567,7 @@ def stage_kernel(ctx: dict) -> dict:
             if ctx["device"]["platform"] == "tpu":
                 check("tpu_custom_call" in step.lower(*args).as_text(),
                       "no Mosaic custom call in the lowered train step")
-        (new_params, _), (loss, _) = step(*args)
+        (new_params, _), (loss, *_) = step(*args)
         predict = jax.jit(make_predict_fn(spec.module.apply))
         out[impl] = jax.device_get(
             {"loss": loss, "predict": predict(params, x),

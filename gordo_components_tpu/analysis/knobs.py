@@ -365,7 +365,7 @@ KNOBS: Dict[str, Knob] = dict(
         _knob("GORDO_PARITY_RTOL_BF16", "0.02", "float",
               "bf16 parity budget: max |bf16−f32| of total anomaly "
               "scores, normalized to the mean f32 score (gated by "
-              "quant_smoke and the bench precision block)", "test"),
+              "quant_smoke)", "test"),
         _knob("GORDO_PARITY_RTOL_INT8", "0.08", "float",
               "int8 parity budget: same ruler as the bf16 budget, "
               "looser — int8 trades more accuracy for 4x weight "
@@ -389,16 +389,10 @@ KNOBS: Dict[str, Knob] = dict(
               "fleet build: base seconds between data-fetch retries "
               "(exponential)", "build"),
         # -- bench -------------------------------------------------------
-        _knob("GORDO_BENCH_HISTORY", "BENCH_HISTORY.jsonl", "path",
-              "where bench.py / bench_serving.py append their history "
-              "rows (tests point it at /dev/null)", "bench"),
-        _knob("GORDO_RESET_BENCH_ANCHOR", "0", "bool",
-              "reseed the bench-regression anchor ring (after a rig "
-              "change that legitimately moved the baseline)", "bench"),
-        _knob("GORDO_CAPACITY_MACHINES", "2000 (smoke) / 10000 (bench)",
+        _knob("GORDO_CAPACITY_MACHINES", "2000 (smoke) / 10000 (harness)",
               "int",
               "capacity harness (§22): synthetic-fleet size for "
-              "`tools/capacity_smoke.py` and the bench `capacity` block",
+              "`tools/capacity_smoke.py` and `tools/capacity_harness.py`",
               "bench"),
         _knob("GORDO_CAPACITY_SECONDS", "8", "float",
               "capacity harness: seconds of production-shaped load per "
@@ -413,13 +407,6 @@ KNOBS: Dict[str, Knob] = dict(
         _knob("GORDO_TELEMETRY_SMOKE_SECONDS", "5", "float",
               "telemetry smoke: seconds of Zipf load through the "
               "2-worker router tier", "bench"),
-        _knob("GORDO_TELEMETRY_BENCH_MACHINES", "300", "int",
-              "bench `telemetry` block (§24): synthetic-fleet size",
-              "bench"),
-        _knob("GORDO_TELEMETRY_BENCH_SECONDS", "6", "float",
-              "bench `telemetry` block: seconds of Zipf load before "
-              "the scrape-cost and warehouse-economy measurements",
-              "bench"),
         _knob("GORDO_QOS_SMOKE_MACHINES", "24", "int",
               "qos smoke (§25): synthetic-fleet size for "
               "`tools/qos_smoke.py`", "bench"),
@@ -439,12 +426,6 @@ KNOBS: Dict[str, Knob] = dict(
         _knob("GORDO_LAYOUT_SMOKE_MACHINES", "48", "int",
               "layout smoke (§27): synthetic-fleet size for "
               "`tools/layout_smoke.py`", "bench"),
-        _knob("GORDO_LAYOUT_BENCH_MACHINES", "48", "int",
-              "bench `layout` block (§27): synthetic-fleet size for "
-              "the name-hash vs computed-plan A/B", "bench"),
-        _knob("GORDO_LAYOUT_BENCH_SECONDS", "5", "float",
-              "bench `layout` block: seconds of Zipf load per A/B "
-              "phase", "bench"),
         _knob("GORDO_LAYOUT_SMOKE_SECONDS", "5", "float",
               "layout smoke: seconds of skewed Zipf load per phase "
               "through the 2-worker router tier", "bench"),

@@ -48,13 +48,8 @@ SCOPES: Dict[str, Tuple[str, ...]] = {
         "gordo_components_tpu/router/",
         "gordo_components_tpu/watchman/",
     ),
-    "metrics-conventions": (
-        "gordo_components_tpu/", "tools/", "bench.py", "bench_serving.py",
-    ),
-    "knob-registry": (
-        "gordo_components_tpu/", "tools/", "tests/", "bench.py",
-        "bench_serving.py",
-    ),
+    "metrics-conventions": ("gordo_components_tpu/", "tools/"),
+    "knob-registry": ("gordo_components_tpu/", "tools/", "tests/"),
     # tests legitimately swallow in teardown helpers; the hygiene rule
     # covers the shipped tree
     "exception-hygiene": ("gordo_components_tpu/", "tools/"),
@@ -94,10 +89,6 @@ def _iter_files(root: str) -> List[str]:
             for filename in sorted(filenames):
                 if filename.endswith(".py"):
                     out.append(os.path.join(dirpath, filename))
-    for single in ("bench.py", "bench_serving.py"):
-        path = os.path.join(root, single)
-        if os.path.exists(path):
-            out.append(path)
     return out
 
 

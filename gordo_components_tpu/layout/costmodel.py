@@ -2,7 +2,7 @@
 
 The model is intentionally a PROXY, not a simulator: it ranks candidate
 layouts on three terms the telemetry warehouse actually measures, and
-the smoke/bench harnesses gate the REAL p99 and bytes numbers on a live
+the smoke harness gates the REAL p99 and bytes numbers on a live
 fleet (tools/layout_smoke.py) — the model only has to order candidates
 correctly, not predict latencies absolutely.
 
@@ -61,7 +61,7 @@ def mean_machine_bytes(doc: Dict[str, Any]) -> float:
     """Fleet-mean device bytes per machine from the per-rung cost
     ledger. The export aggregates bytes per RUNG, not per machine, so
     the model works in fleet means — good enough to rank layouts (the
-    bench measures the real number)."""
+    smoke measures the real number)."""
     total_bytes = 0.0
     total_machines = 0.0
     for entry in (doc.get("rungs") or {}).values():

@@ -4,7 +4,7 @@ These replace the Keras graphs the reference's factories build
 (``gordo_components/model/factories/feedforward_autoencoder.py`` and
 ``lstm_autoencoder.py`` [UNVERIFIED]). TPU notes:
 
-- ``compute_dtype`` defaults to float32 but the bench configs flip it to
+- ``compute_dtype`` defaults to float32; where a config flips it to
   bfloat16: params stay float32 (``param_dtype``), activations/matmuls run
   on the MXU in bf16, and the final output is cast back to float32 so losses
   and anomaly scores keep full precision.

@@ -4,7 +4,7 @@ The reference has no metrics layer at all (SURVEY.md §6: debugging was
 kubectl logs); before this module the rebuild's only telemetry was the
 server's ad-hoc ``_Latency`` ring buffer and ``PhaseTimer`` durations that
 died with the build process. This registry is the ONE place every layer
-(client, server, engine, builder, fleet, watchman, bench) records to, so a
+(client, server, engine, builder, fleet, watchman) records to, so a
 single ``GET /metrics`` — JSON or Prometheus text — sees the whole process.
 
 Design (deliberately mirrors the retired ``_Latency``): lock-LIGHT, not

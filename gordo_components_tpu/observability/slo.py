@@ -591,9 +591,8 @@ def attribute_stages(
 
 def latency_knobs() -> Tuple[float, float]:
     """``(threshold_seconds, target_fraction)`` as the knobs resolve —
-    THE one place the latency-objective defaults live (bench history
-    rows and custom objective builders read these instead of
-    re-hardcoding the literals)."""
+    THE one place the latency-objective defaults live (custom objective
+    builders read these instead of re-hardcoding the literals)."""
     threshold_s = _env_float("GORDO_SLO_LATENCY_MS", 250.0) / 1000.0
     target = _env_float("GORDO_SLO_LATENCY_TARGET", 0.99)
     return threshold_s, target

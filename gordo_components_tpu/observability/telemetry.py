@@ -814,9 +814,9 @@ def merge_views(views: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
 
 def sample_costs(engine: Any, compile_store: Any = None) -> Dict[str, Any]:
     """One ledger sample from a live engine (+ optional compile-cache
-    store): what bench_serving only measures offline, read from the
-    serving process itself. Duck-typed on purpose — observability must
-    not import the server package (the dependency points the other way).
+    store), read from the serving process itself. Duck-typed on purpose —
+    observability must not import the server package (the dependency
+    points the other way).
     """
     costs: Dict[str, Any] = {}
     if engine is not None:

@@ -658,15 +658,12 @@ class FleetMachineConfig:
 
 def _effective_splits(
     machine: "FleetMachineConfig", default: int
-) -> Tuple[int, Optional[bool], List[str]]:
-    """Resolve the machine's CV depth and fold-execution mode:
-    ``evaluation.n_splits`` beats the builder default (``None``/absent means
-    "use the default"); ``evaluation.cv_parallel`` (bool, optional) pins the
-    fold-execution strategy (:class:`..fleet.FleetSpec.cv_parallel` —
-    vmapped vs scanned fold fits; ``None`` lets :func:`_spec_for` derive it
-    from the model's memory profile). Returns the keys the fleet builder
-    does NOT honor (e.g. ``cv_mode`` — always ``"fleet"`` here) so the
-    caller can surface them instead of silently dropping config."""
+) -> Tuple[int, List[str]]:
+    """Resolve the machine's CV depth: ``evaluation.n_splits`` beats the
+    builder default (``None``/absent means "use the default"). Returns with
+    it the keys the fleet builder does NOT honor (e.g. ``cv_mode`` — always
+    ``"fleet"`` here) so the caller can surface them instead of silently
+    dropping config."""
     evaluation = machine.evaluation or {}
     value = evaluation.get("n_splits")
     if value is None:
@@ -683,36 +680,8 @@ def _effective_splits(
                 f"got {value}"
             )
         eff = value
-    cv_parallel = evaluation.get("cv_parallel")
-    if cv_parallel is not None and not isinstance(cv_parallel, bool):
-        raise ValueError(
-            f"Machine {machine.name!r}: evaluation.cv_parallel must be a "
-            f"boolean, got {cv_parallel!r}"
-        )
-    honored = {"n_splits", "cv_parallel"}
-    ignored = sorted(k for k in evaluation if k not in honored)
-    return eff, cv_parallel, ignored
-
-
-def _derived_cv_parallel(model_config: Dict[str, Any]) -> bool:
-    """The fold-execution mode a config derives when ``evaluation.
-    cv_parallel`` is absent: sequential scan iff the model asked for remat
-    (memory-constrained — see :func:`_spec_for`). Reads the literal
-    ``remat`` kwarg off the config dict so bucketing can resolve the mode
-    without instantiating the pipeline; no factory defaults ``remat`` on,
-    so textual absence means remat is off (pinned against the spec-level
-    derivation by tests/test_fleet.py)."""
-
-    def scan(node: Any) -> bool:
-        if isinstance(node, dict):
-            if node.get("remat"):
-                return True
-            return any(scan(v) for v in node.values())
-        if isinstance(node, (list, tuple)):
-            return any(scan(v) for v in node)
-        return False
-
-    return not scan(model_config)
+    ignored = sorted(k for k in evaluation if k != "n_splits")
+    return eff, ignored
 
 
 def _scaler_kind(
@@ -739,7 +708,6 @@ def _spec_for(
     n_features: int,
     n_targets: int,
     n_splits: int,
-    cv_parallel: Optional[bool] = None,
 ) -> FleetSpec:
     est = analyzed.estimator
     if getattr(est, "joint_horizon", False):
@@ -762,17 +730,6 @@ def _spec_for(
             "single-machine builder for this config"
         )
     dropout = float(model_spec.config.get("dropout", 0.0) or 0.0)
-    # a model that recomputes its activations is trading FLOPs for memory:
-    # its folds run in sequence on one training state (fleet.sequential_fits)
-    # and its slices are sized from the parameter count (_slice_cap)
-    memory_constrained = bool(model_spec.config.get("remat", False))
-    if cv_parallel is None:
-        # derive the fold-execution mode from the model's memory profile: a
-        # config that asked for remat is trading FLOPs for memory already —
-        # multiplying step activations by (K+1) would undo that, so such
-        # buckets keep the sequential scan; everything else takes the
-        # (K+1)× sequential-depth win (FleetSpec.cv_parallel)
-        cv_parallel = not memory_constrained
     return FleetSpec(
         module=model_spec.module,
         optimizer=model_spec.optimizer,
@@ -790,29 +747,9 @@ def _spec_for(
         target_scaler=t_kind,
         target_feature_range=t_range,
         target_scaler_options=t_options,
-        cv_parallel=cv_parallel,
-        # scan unrolling follows the model's step-body size, NOT
-        # cv_parallel: an explicit cv_parallel override must not silently
-        # change compile-time/footprint behavior too. Only "flat" models
-        # (small MLP step bodies) unroll: a windowed model's batch step
-        # already contains an inner time scan / attention stack, so
-        # inlining 4 copies multiplies exactly the structures XLA:TPU's
-        # optimization passes are superlinear in — a builder measured the
-        # 32-machine LSTM fleet compile going from 28.7 s to ~25 min on a
-        # v5e with unroll=4 in round 4 (XLA:CPU shows no such blowup,
-        # 16-27 s across all knob combinations), while its
-        # dispatch-overhead win only ever applied to the tiny dense
-        # bodies anyway
-        fit_unroll=(
-            1
-            if (memory_constrained or model_spec.input_kind == "window")
-            else 4
-        ),
-        # predict-chunk widening keys off the memory profile alone: it is
-        # a forward-only memory argument (fleet.py) with no XLA:TPU
-        # compile-time cost, so windowed non-remat models keep it even
-        # though they don't unroll
-        widen_predict=not memory_constrained,
+        # a model that recomputes its activations is trading FLOPs for
+        # memory: the one fact every execution choice follows (fleet.py)
+        memory_constrained=bool(model_spec.config.get("remat", False)),
         rows_out=est.rows_out,
     )
 
@@ -823,7 +760,7 @@ def _slice_cap(spec: FleetSpec, n_features: int) -> Optional[int]:
     training state and a third of it again) within three quarters of the
     device's memory; at least one. ``None``: no cap (a spec that is not
     memory-constrained, or a device that does not say what it holds)."""
-    if not sequential_fits(spec) or spec.widen_predict:
+    if not spec.memory_constrained:
         return None
     limit = (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
     if not limit:
@@ -1165,7 +1102,7 @@ def _build_fleet(
 
     with spans.stage("fleet.preamble") as preamble:
         results: Dict[str, str] = {}
-        pending: List[Tuple[FleetMachineConfig, str, int, Optional[bool]]] = []
+        pending: List[Tuple[FleetMachineConfig, str, int]] = []
         ignored_eval: Dict[str, List[str]] = {}
         # resumable-build WAL: one fsync'd record per machine lifecycle event
         # (started / committed / failed); a re-run replays it (unioned with any
@@ -1177,17 +1114,9 @@ def _build_fleet(
         journal_states = store_journal.replay(output_dir)
         journal_counts = {"resumed": 0, "torn": 0, "rebuilt": 0}
         for machine in machines:
-            eff_splits, eff_cv_parallel, ignored = _effective_splits(
-                machine, n_splits
-            )
+            eff_splits, ignored = _effective_splits(machine, n_splits)
             if ignored:
                 ignored_eval[machine.name] = ignored
-            # cv_parallel is deliberately NOT part of the cache key: it is an
-            # execution strategy (vmapped vs scanned fold fits), numerically
-            # equivalent by tests/test_fleet.py::test_cv_parallel_matches_scan —
-            # flipping it must resume from existing artifacts, not retrain. The
-            # mode that actually trained an artifact is recorded in its fleet
-            # metadata block for provenance.
             evaluation_config = {"n_splits": eff_splits, "cv_mode": "fleet"}
             cache_key = calculate_model_key(
                 machine.name,
@@ -1251,7 +1180,7 @@ def _build_fleet(
                         journal_counts["resumed"] += 1
                         _M_FLEET_MACHINES.labels("cached").inc()
                         continue
-            pending.append((machine, cache_key, eff_splits, eff_cv_parallel))
+            pending.append((machine, cache_key, eff_splits))
         if ignored_eval:
             sample = dict(list(ignored_eval.items())[:5])
             logger.warning(
@@ -1275,7 +1204,7 @@ def _build_fleet(
         # widths come from the dataset's declared columns, so peak host memory
         # is one bucket's data, not the whole fleet's ---------------------------
         buckets: Dict[str, List[dict]] = {}
-        for machine, cache_key, eff_splits, eff_cv_parallel in pending:
+        for machine, cache_key, eff_splits in pending:
             dataset = _dataset_from_config(machine.data_config)
             item: dict = {
                 "machine": machine,
@@ -1311,23 +1240,12 @@ def _build_fleet(
                 n_features, n_targets = item["X"].shape[1], item["y"].shape[1]
             item["F"], item["T"] = n_features, n_targets
             item["n_splits"] = eff_splits
-            # resolve the fold-execution mode NOW (None → the remat-derived
-            # default, readable straight off the config dict) so a machine whose
-            # explicit override merely restates the default still buckets — and
-            # batches — with its unannotated twins; different resolved modes are
-            # different compiled programs and bucket separately
-            item["cv_parallel"] = (
-                eff_cv_parallel
-                if eff_cv_parallel is not None
-                else _derived_cv_parallel(machine.model_config)
-            )
             sig = json.dumps(
                 {
                     "model_config": machine.model_config,
                     "F": n_features,
                     "T": n_targets,
                     "n_splits": item["n_splits"],
-                    "cv_parallel": item["cv_parallel"],
                 },
                 sort_keys=True,
                 default=str,
@@ -1371,13 +1289,7 @@ def _build_fleet(
                 n_features = items[0]["F"]
                 n_targets = items[0]["T"]
                 bucket_splits = items[0]["n_splits"]
-                spec = _spec_for(
-                    analyzed,
-                    n_features,
-                    n_targets,
-                    bucket_splits,
-                    cv_parallel=items[0]["cv_parallel"],
-                )
+                spec = _spec_for(analyzed, n_features, n_targets, bucket_splits)
 
                 # ---- slice the bucket: each slice is an independent failure
                 # domain with its own data fetch, train call, and artifact
@@ -1417,7 +1329,7 @@ def _build_fleet(
                 # peak-HBM budget has no room for a second slice's buffers
                 place = (
                     (spec, mesh)
-                    if (not multihost and spec.widen_predict)
+                    if not (multihost or spec.memory_constrained)
                     else None
                 )
                 # the prefetch worker inherits no context: it binds this
@@ -1540,9 +1452,9 @@ def _build_fleet(
                             "slice_size": len(slice_items),
                             "slice_duration_s": slice_duration,
                             # fold-execution mode that trained this artifact
-                            # (provenance; not in the cache key — see
-                            # evaluation_config above)
-                            "cv_parallel": bool(spec.cv_parallel),
+                            # (provenance; not in the cache key: both modes
+                            # train the same models)
+                            "cv_parallel": not spec.memory_constrained,
                             # where the trained arrays lived, read off their
                             # sharding — JAX hands back the CPU without
                             # failing when it cannot get the chip, and the

@@ -89,36 +89,18 @@ class FleetSpec(NamedTuple):
     target_scaler: str = "minmax"
     target_feature_range: Tuple[float, float] = (0.0, 1.0)
     target_scaler_options: Tuple[bool, bool] = (True, True)
-    # True: the K CV-fold fits and the final fit — independent programs with
-    # identical shapes — run as ONE vmapped batched fit instead of a
-    # sequential lax.scan, cutting the program's sequential depth by (K+1)×
-    # at the price of (K+1)× the training-step activation memory. The right
-    # default on a TPU whose per-machine models are tiny (the fleet design
-    # point); builders flip it off for memory-constrained configs (remat
-    # models at plant scale). Numerically equivalent to the scan path up to
-    # XLA reduction-order float noise — parity pinned by
-    # tests/test_fleet.py::test_cv_parallel_matches_scan.
-    cv_parallel: bool = True
-    # mini-batch steps inlined per iteration of the training scan
-    # (lax.scan's unroll): tiny fleet models are dispatch-overhead-bound,
-    # and unrolling lets XLA schedule several steps per dispatch. Pure
-    # scheduling, numerics unchanged; compile time grows with the body, so
-    # the default here is the safe 1 and _spec_for opts non-remat flat
-    # buckets into 4 — independent of cv_parallel so an explicit override
-    # of one never silently drags the other along. Windowed models never
-    # unroll: their batch step already carries an inner time scan /
-    # attention stack, and inlining copies of it is exactly what XLA:TPU's
-    # optimization passes are superlinear in (measured r4: 28.7 s -> ~25
-    # min for the 32-machine LSTM fleet compile).
-    fit_unroll: int = 1
-    # "memory profile is unconstrained" bit, set by _spec_for from the
-    # model's remat request: predict-chunk widening keys off it (NOT off
-    # the user-overridable cv_parallel, and NOT off fit_unroll, which
-    # windowed models keep at 1 for compile-time reasons unrelated to
-    # memory). Defaults to the safe narrow mode like fit_unroll — a spec
-    # built without _spec_for must opt in, never inherit 4x-wide predict
-    # chunks it didn't budget for.
-    widen_predict: bool = False
+    # the ONE fact the execution strategy follows, set by _spec_for from the
+    # module's remat request (a model that recomputes its activations trades
+    # FLOPs for memory already). Not memory-constrained: the K fold fits and
+    # the final fit run as one vmapped fit at (K+1)× the step's activations,
+    # prediction runs in chunks up to four training batches wide, and a
+    # flat-input fit inlines four batch steps a scan iteration.
+    # Memory-constrained: the fits run in sequence on one donated training
+    # state, prediction stays one batch wide, nothing is unrolled, and
+    # slices are sized from the parameter count. :func:`sequential_fits`
+    # and :func:`fit_unroll` derive the rest; the two fold modes train the
+    # same models (the parity tests of tests/test_fleet.py).
+    memory_constrained: bool = False
     # rows a windowed sample is judged against; its samples lie that many
     # rows apart (ops.windowing: "several rows a sample"). 1: every window,
     # a row apart, judged against one row.
@@ -275,10 +257,22 @@ def _initial_params(spec: FleetSpec, n_features: int) -> Callable:
 
 def sequential_fits(spec: FleetSpec) -> bool:
     """Whether the machine's fits (the CV folds', then the final one) run one
-    after another on ONE training state: every spec but the vmapped-folds
-    one. Such a program takes that state as a fifth, donated argument
-    (:func:`fleet_state` draws it) and hands the optimizer's part back."""
-    return not (spec.n_splits > 0 and spec.cv_parallel)
+    after another on ONE training state: a memory-constrained spec, or one
+    with no folds to vmap. Such a program takes that state as a fifth,
+    donated argument (:func:`fleet_state` draws it) and hands the
+    optimizer's part back."""
+    return spec.memory_constrained or spec.n_splits == 0
+
+
+def fit_unroll(spec: FleetSpec) -> int:
+    """Batch steps inlined per iteration of the training scan (``lax.scan``'s
+    unroll; scheduling only, the numbers do not change): 4 for a flat-input
+    model that is not memory-constrained, whose tiny step is bound by
+    dispatch, else 1. A windowed step already holds a time scan or an
+    attention stack; that inlining four of them costs the TPU compiler
+    minutes is a figure from a deleted rig that no record holds, and the
+    chip has not been asked since (ROADMAP D7)."""
+    return 4 if spec.lookahead is None and not spec.memory_constrained else 1
 
 
 def make_machine_program(
@@ -291,13 +285,12 @@ def make_machine_program(
     state ``(params, optimizer state)`` as buffers of the caller's."""
 
     apply_fn = spec.module.apply
-    fit_unroll = spec.fit_unroll
     fit_kwargs = dict(
         loss=spec.loss,
         batch_size=spec.batch_size,
         epochs=spec.epochs,
         use_dropout=spec.use_dropout,
-        unroll=fit_unroll,
+        unroll=fit_unroll(spec),
     )
     fit_fn = make_fit_fn(apply_fn, spec.optimizer, **fit_kwargs)
     predict_fn = make_predict_fn(apply_fn)
@@ -406,25 +399,17 @@ def make_machine_program(
             windowed_predict = make_predict_fn(windowed_apply)
 
             # prediction has no optimizer state or backward pass, so its
-            # chunks can be wider than the training batch: fold up to 4
-            # training batches into one forward call (largest factor of the
-            # step count), cutting the predict pass's sequential ticks by
-            # that factor. The bound is RELATIVE to the training step, not
-            # absolute, because predict_all runs under the same vmaps
-            # (machines, and K+1 fits in cv_parallel mode) as the training
-            # step: a NON-remat training step holds ~3x its forward
-            # activations (fwd + bwd + grads), so a 4x-wide forward-only
-            # chunk peaks at ~4/3 of the training step's memory under ANY
-            # vmap multiplication. That argument does NOT hold for remat
-            # buckets (their step peak is deliberately small), so the
-            # widening keys off spec.widen_predict — the bit _spec_for
-            # sets from the model's memory profile — NOT off the
-            # user-overridable cv_parallel, and not off fit_unroll (which
-            # windowed models keep at 1 for XLA:TPU compile-time reasons
-            # unrelated to memory). Values are unchanged — prediction is
-            # per-window.
+            # chunks can be wider than the training batch: up to 4 training
+            # batches in one forward call (largest factor of the step
+            # count). The bound is RELATIVE to the training step because
+            # predict_all runs under the same vmaps as it: a step that
+            # keeps its activations holds ~3x its forward pass, so a
+            # 4x-wide forward-only chunk peaks at ~4/3 of it. A
+            # memory-constrained step recomputes them and is deliberately
+            # small, so its chunks stay one batch wide. Values are
+            # unchanged — prediction is per-window.
             steps = padded // spec.batch_size
-            if spec.widen_predict:
+            if not spec.memory_constrained:
                 predict_width = spec.batch_size * next(
                     k for k in range(min(4, steps), 0, -1) if steps % k == 0
                 )
@@ -465,7 +450,7 @@ def make_machine_program(
             # programs with identical shapes, so ONE vmapped fit of K+1
             # weight vectors replaces K+1 sequential fits — sequential depth
             # drops to a single fit's epochs×batches at (K+1)× step memory
-            # (see FleetSpec.cv_parallel). Per-fit keys match the scan path
+            # (see FleetSpec.memory_constrained). Per-fit keys match the scan path
             # exactly, so both modes train identical models.
             params0 = draw(init_key)
             fits = jax.vmap(
@@ -488,7 +473,7 @@ def make_machine_program(
             # the K+1 stacked weight vectors (the fits share every shape) —
             # an unrolled Python loop would inline K+1 copies of the whole
             # training program and multiply XLA compile time accordingly;
-            # vs cv_parallel this holds step memory at 1×, the right trade
+            # vs the vmapped fits this holds step memory at 1×, the right trade
             # for plant-scale remat configs and the only one for a model
             # whose training state fills the chip. The state is the scan's
             # carry, in the caller's buffers, and every fit trains it in
@@ -822,7 +807,7 @@ def put_fleet_batch(batch: MachineBatch, formats=None) -> MachineBatch:
 
 def compiled_flops(compiled) -> Optional[float]:
     """XLA-reported flops of a compiled executable, or ``None`` on backends
-    without cost analysis — bench.py and the accounting below share it."""
+    without cost analysis."""
     try:
         return float(compiled.cost_analysis()["flops"])
     except Exception:  # lint: allow-swallow(XLA cost introspection is optional; None is the documented unknown result)
@@ -840,8 +825,7 @@ def fleet_flops_accounting(
 
     XLA's ``cost_analysis()`` counts a ``lax.scan`` body ONCE regardless of
     trip count, so the whole fleet program's reported flops undercount the
-    training loop by roughly ``n_fits × epochs × steps_per_epoch`` — on the
-    round-4 TPU bench that made MFU look ~25× smaller than reality. This
+    training loop by roughly ``n_fits × epochs × steps_per_epoch``. This
     helper compiles the loop bodies standalone — the EXACT mini-batch train
     step (:func:`gordo_components_tpu.models.train.make_batch_step`, the
     same function ``make_fit_fn`` scans) and a batch-size-wide predict
@@ -934,10 +918,9 @@ def fleet_flops_accounting(
         )
         predict_chunk_flops = compiled_flops(predict_compiled)
     except Exception:
-        # accounting is a measurement aid and must never fail a bench run —
+        # accounting is a measurement aid and must never fail its caller —
         # but a silent None here would be indistinguishable from "backend
-        # has no cost analysis", hiding real probe bugs until a one-shot
-        # TPU run comes back without its MFU number. Log loudly instead.
+        # has no cost analysis", hiding real probe bugs. Log loudly instead.
         logger.warning(
             "fleet_flops_accounting probe failed; MFU will be unreported",
             exc_info=True,

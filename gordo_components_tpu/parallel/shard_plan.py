@@ -169,7 +169,7 @@ class FleetShardPlan:
 
     def counts(self, machines: Sequence[str]) -> List[int]:
         """Machines per shard under the sharded policy — the balance an
-        operator (and the bench) reads."""
+        operator reads."""
         counts = [0] * self.n_shards
         for name in machines:
             counts[self.shard_of(name)] += 1

@@ -24,9 +24,9 @@ Downgraded precisions trade accuracy for speed and residency; the trade
 is GATED, not assumed: the parity budgets below bound how far bf16/int8
 total anomaly scores may drift from the f32 reference (normalized to the
 f32 score scale — raw relative error explodes where residuals cancel to
-~0), and ``tools/quant_smoke.py`` + the bench's ``precision`` block
-measure them on every run. Anomaly-threshold flip rates across
-precisions are measured and reported there too, never silently absorbed.
+~0), and ``tools/quant_smoke.py`` measures them on every run.
+Anomaly-threshold flip rates across precisions are measured and reported
+there too, never silently absorbed.
 """
 
 from __future__ import annotations
@@ -53,7 +53,7 @@ QUANT_INT8_FILE = "quant_int8.npz"
 # parity_error). Raw rtol is the wrong ruler here — residuals that
 # cancel toward zero make per-element relative error unbounded while the
 # actual anomaly signal is unaffected. Defaults hold with margin on the
-# bench shapes (measured in tools/quant_smoke.py); GORDO_PARITY_RTOL_*
+# smoke's shapes (measured in tools/quant_smoke.py); GORDO_PARITY_RTOL_*
 # override for fleets whose models are more (or less) sensitive.
 _DEFAULT_BUDGETS = {"f32": 0.0, "bf16": 0.02, "int8": 0.08}
 _BUDGET_ENV = {
@@ -113,7 +113,7 @@ def error_budget(precision: str) -> float:
 def parity_error(reference: np.ndarray, candidate: np.ndarray) -> float:
     """Normalized parity error between two total-anomaly-score arrays:
     ``max|candidate - reference| / mean|reference|``. The one ruler the
-    smoke harness, the bench block, and the tests all measure with."""
+    smoke harness and the tests both measure with."""
     reference = np.asarray(reference, np.float64)
     candidate = np.asarray(candidate, np.float64)
     scale = float(np.mean(np.abs(reference)))

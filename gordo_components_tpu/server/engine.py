@@ -1171,11 +1171,10 @@ class _Bucket:
         return program
 
     def _warm_mega_stack(self):
-        """A dispatchable resident stack for the warm paths (warmup,
-        bench program warming): the live stack when one exists, else a
-        zeros stack of the right height (partial mode before any
-        promotion — the warmed program's binary is slot-content-agnostic,
-        only the SHAPE matters)."""
+        """A dispatchable resident stack for the warm paths: the live
+        stack when one exists, else a zeros stack of the right height
+        (partial mode before any promotion — the warmed program's binary
+        is slot-content-agnostic, only the SHAPE matters)."""
         with self._mega_lock:
             stack = self.stacked if self._mega_full else self._mega_stack_dev
         if stack is not None:
@@ -3237,11 +3236,10 @@ class ServingEngine:
         }
 
     def cost_ledger(self) -> Dict[str, Any]:
-        """The §24 measured-cost sample: what bench_serving only measures
-        offline, read from the live engine — per-rung stacked-tree device
-        bytes, served requests, and accumulated compile-free device
-        seconds (stacked buckets + the spill tier), plus the host-cache
-        tier's byte/latency economy. Consumed by the telemetry
+        """The §24 measured-cost sample, read from the live engine —
+        per-rung stacked-tree device bytes, served requests, and
+        accumulated compile-free device seconds (stacked buckets + the
+        spill tier), plus the host-cache tier's byte/latency economy. Consumed by the telemetry
         warehouse's cost sampler each tick; everything here is O(buckets
         + rungs), never O(machines)."""
         rungs: Dict[str, Dict[str, float]] = {}

@@ -2,8 +2,8 @@
 
 The serving data plane's transport half (docs/ARCHITECTURE.md §12). The
 original response path serialized every score via ``.tolist()`` +
-``json.dumps`` — one Python float object per array element, which BENCH_r05
-showed dominating host time once device dispatch fell to ~0.3 ms. Two
+``json.dumps`` — one Python float object per array element, which came
+to dominate host time once device dispatch was fast. Two
 fixes, negotiated per request:
 
 - ``application/x-gordo-npz`` (``Accept`` request header / response

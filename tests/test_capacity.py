@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from werkzeug.test import Client
 
-import bench_serving
+from gordo_components_tpu.models.synthetic_fleet import build_models
 from gordo_components_tpu.server.engine import ServingEngine
 from gordo_components_tpu.server.host_cache import HostTierCache
 
@@ -23,7 +23,7 @@ pytestmark = pytest.mark.usefixtures("thread_hygiene")
 def models():
     """Three same-architecture machines, distinct weights — spill parity
     is about the dispatch path, not training quality."""
-    return bench_serving.build_models(3, 64, 4)
+    return build_models(3, 64, 4)
 
 
 @pytest.fixture(scope="module")

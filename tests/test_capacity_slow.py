@@ -30,8 +30,8 @@ def test_100k_machine_sweep():
             seconds=8.0,
             workers=2,
             threads=8,
-            # the full-scan boot comparison is the 10k bench block's
-            # job; at 100k the scan alone takes ~25 minutes
+            # no full-scan boot comparison: at 100k the scan alone takes
+            # ~25 minutes
             measure_scan_boot=False,
         )
         boot = report["boot"]

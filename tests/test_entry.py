@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+_REPO_ROOT = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(_REPO_ROOT))
 
 import __graft_entry__ as graft_entry  # noqa: E402
 
@@ -55,8 +56,29 @@ def test_dryrun_multichip_subprocess_self_provisions():
     graft_entry.dryrun_multichip(n)
 
 
+def test_graft_entry_refuses_a_cpu_nobody_asked_for(tmp_path):
+    """No chip and no JAX_PLATFORMS=cpu: the script exits non-zero, names
+    the platform it got and prints no artifact — it neither re-execs itself
+    on the CPU nor measures there under a device metric's name. (With
+    JAX_PLATFORMS unset, on a chipless machine JAX logs the libtpu failure
+    and hands back the CPU.)"""
+    import subprocess
+
+    proc = subprocess.run(
+        [sys.executable, "__graft_entry__.py"],
+        env={"PATH": "/usr/bin:/bin", "HOME": str(tmp_path)},
+        capture_output=True, text=True, timeout=300,
+        cwd=str(_REPO_ROOT),
+    )
+    if proc.returncode == 0:
+        pytest.skip("this machine has an accelerator")
+    assert proc.returncode == 3, proc.stderr[-2000:]
+    assert "__graft_entry__.py: JAX found no accelerator" in proc.stderr
+    assert "cpu" in proc.stderr and "JAX_PLATFORMS=cpu" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
 # -- chip_smoke.py ----------------------------------------------------------
-_REPO_ROOT = Path(__file__).resolve().parent.parent
 
 
 def _chip_smoke(argv, cwd, timeout):

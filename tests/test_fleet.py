@@ -21,24 +21,25 @@ from gordo_components_tpu.parallel.fleet import MachineResult
 from gordo_components_tpu.parallel.build_fleet import _analyze_model, _spec_for
 from gordo_components_tpu.serializer import load, load_metadata, pipeline_from_definition
 
-MODEL_CONFIG = {
-    "DiffBasedAnomalyDetector": {
-        "base_estimator": {
-            "TransformedTargetRegressor": {
-                "regressor": {
-                    "Pipeline": {
-                        "steps": [
-                            "MinMaxScaler",
-                            {"DenseAutoEncoder": {"kind": "feedforward_hourglass",
-                                                  "epochs": 4, "batch_size": 32}},
-                        ]
-                    }
-                },
-                "transformer": "MinMaxScaler",
+
+def _definition(estimator, **kwargs):
+    return {
+        "DiffBasedAnomalyDetector": {
+            "base_estimator": {
+                "TransformedTargetRegressor": {
+                    "regressor": {
+                        "Pipeline": {"steps": ["MinMaxScaler", {estimator: kwargs}]}
+                    },
+                    "transformer": "MinMaxScaler",
+                }
             }
         }
     }
-}
+
+
+MODEL_CONFIG = _definition(
+    "DenseAutoEncoder", kind="feedforward_hourglass", epochs=4, batch_size=32
+)
 
 
 def _data_config(tags):
@@ -92,60 +93,76 @@ def test_fleet_trains_stacked_machines():
 
 
 def test_cv_parallel_evaluation_override():
-    """evaluation.cv_parallel pins the fold-execution mode per machine
-    (beating the remat-derived default), bad types are rejected, and the
-    key counts as honored (not surfaced in the ignored list)."""
+    """Nobody sets the fold-execution mode: an ``evaluation.cv_parallel``
+    key is reported among the ignored keys beside ``cv_mode``, whatever its
+    value, and the spec follows the model's own remat request alone
+    (``test_spec_derives_its_execution_from_the_remat_request``)."""
     from gordo_components_tpu.parallel.build_fleet import _effective_splits
+    from gordo_components_tpu.parallel.fleet import sequential_fits
 
     m = FleetMachineConfig(
         name="m", model_config={}, data_config={},
         evaluation={"n_splits": 1, "cv_parallel": False, "cv_mode": "full"},
     )
-    splits, cv_parallel, ignored = _effective_splits(m, 3)
-    assert (splits, cv_parallel) == (1, False)
-    assert ignored == ["cv_mode"]  # cv_parallel is honored, cv_mode is not
+    assert _effective_splits(m, 3) == (1, ["cv_mode", "cv_parallel"])
     m_default = FleetMachineConfig(
         name="m2", model_config={}, data_config={}, evaluation={}
     )
-    assert _effective_splits(m_default, 3)[:2] == (3, None)
-    bad = FleetMachineConfig(
+    assert _effective_splits(m_default, 3) == (3, [])
+    odd = FleetMachineConfig(
         name="m3", model_config={}, data_config={},
         evaluation={"cv_parallel": "yes"},
     )
-    with pytest.raises(ValueError, match="cv_parallel must be a boolean"):
-        _effective_splits(bad, 3)
-    # the derived default: remat models keep the sequential scan
-    probe = pipeline_from_definition(MODEL_CONFIG)
-    spec = _spec_for(_analyze_model(probe), 3, 3, 2)
-    assert spec.cv_parallel is True
-    assert _spec_for(
-        _analyze_model(probe), 3, 3, 2, cv_parallel=False
-    ).cv_parallel is False
-    # the bucketing-time textual derivation must agree with the spec-level
-    # one (it reads the literal remat kwarg instead of instantiating)
-    from gordo_components_tpu.parallel.build_fleet import _derived_cv_parallel
+    assert _effective_splits(odd, 3) == (3, ["cv_parallel"])
+    spec = _spec_for(_analyze_model(pipeline_from_definition(MODEL_CONFIG)), 3, 3, 2)
+    assert not spec.memory_constrained and not sequential_fits(spec)
+    with pytest.raises(TypeError):
+        _spec_for(_analyze_model(pipeline_from_definition(MODEL_CONFIG)),
+                  3, 3, 2, cv_parallel=False)
 
-    assert _derived_cv_parallel(MODEL_CONFIG) is True
-    import copy
 
-    remat_config = copy.deepcopy(MODEL_CONFIG)
-    steps = remat_config["DiffBasedAnomalyDetector"]["base_estimator"][
-        "TransformedTargetRegressor"
-    ]["regressor"]["Pipeline"]["steps"]
-    steps[1]["DenseAutoEncoder"]["remat"] = True
-    assert _derived_cv_parallel(remat_config) is False
+def test_machines_that_differ_in_an_ignored_evaluation_key_share_a_bucket(
+    tmp_path, caplog
+):
+    """The bucket signature is the model config, the widths and the CV
+    depth: a machine that still carries ``evaluation.cv_parallel`` trains in
+    the same program, in the same slice, as its twin without the key."""
+    import logging
+
+    from gordo_components_tpu.observability.flightrec import RECORDER
+
+    machines = [
+        FleetMachineConfig(
+            name=name,
+            model_config=MODEL_CONFIG,
+            data_config=_data_config(["a", "b", "c"]),
+            evaluation=evaluation,
+        )
+        for name, evaluation in (("plain", {}), ("keyed", {"cv_parallel": False}))
+    ]
+    with caplog.at_level(logging.WARNING):
+        results = build_fleet(machines, str(tmp_path / "out"), n_splits=1)
+    assert "ignores unsupported evaluation keys" in caplog.text
+    assert "'keyed': ['cv_parallel']" in caplog.text
+    buckets = [
+        span for span in RECORDER.latest(kind="fleet-build").spans
+        if span.name == "fleet.bucket"
+    ]
+    assert [span.attrs["machines"] for span in buckets] == [2]
+    for name in ("plain", "keyed"):
+        assert load_metadata(results[name])["model"]["fleet"]["cv_parallel"] is True
 
 
 def test_cv_parallel_matches_scan():
-    """The vmapped fold path (FleetSpec.cv_parallel) must train the SAME
-    models as the sequential scan path: per-fit keys are identical by
+    """The vmapped fold path must train the SAME models as the sequential
+    scan path (a memory-constrained spec's): per-fit keys are identical by
     construction, so every MachineResult field agrees up to XLA
     reduction-order float noise. This pins the (K+1)x sequential-depth
     optimization as a pure execution-strategy change, not a semantic one."""
     spec, batch = _make_spec_and_batch(3, n_rows=128, n_splits=2)
-    assert spec.cv_parallel  # the derived default for non-remat models
+    assert not spec.memory_constrained  # no remat asked: vmapped folds
     fast = train_fleet_arrays(spec, batch)
-    slow = train_fleet_arrays(spec._replace(cv_parallel=False), batch)
+    slow = train_fleet_arrays(spec._replace(memory_constrained=True), batch)
     for name in MachineResult._fields:
         a, b = getattr(fast, name), getattr(slow, name)
         for la, lb in zip(
@@ -186,9 +203,9 @@ def test_cv_parallel_windowed_matches_scan():
     spec, batch = _make_spec_and_batch(
         2, n_rows=96, model_config=lstm_config, n_splits=2
     )
-    assert spec.cv_parallel
+    assert not spec.memory_constrained
     fast = train_fleet_arrays(spec, batch)
-    slow = train_fleet_arrays(spec._replace(cv_parallel=False), batch)
+    slow = train_fleet_arrays(spec._replace(memory_constrained=True), batch)
     for name in MachineResult._fields:
         for la, lb in zip(
             jax.tree_util.tree_leaves(getattr(fast, name)),
@@ -198,6 +215,136 @@ def test_cv_parallel_windowed_matches_scan():
                 np.asarray(la), np.asarray(lb), rtol=2e-4, atol=1e-5,
                 err_msg=f"cv_parallel vs scan mismatch in {name}",
             )
+
+
+def _decoder_definition():
+    return _definition(
+        "MoEMLAForecast", kind="moe_mla_decoder", lookback_window=16,
+        vocab_size=64, hidden_size=64, n_layers=2, n_dense_layers=1,
+        intermediate_size=96, moe_intermediate_size=32, n_routed_experts=8,
+        experts_held=[1, 5], experts_per_token=2, n_shared_experts=1,
+        routed_scaling_factor=2.5, q_lora_rank=48, kv_lora_rank=32, n_heads=4,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, remat=True,
+        batch_size=2, epochs=1,
+    )
+
+
+def _plant_definition():
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "tools"))
+    from plant_memory_sweep import plant_model
+
+    return plant_model(batch_size=16)
+
+
+@pytest.mark.parametrize(
+    "definition, constrained, sequential, unroll",
+    [
+        (lambda: MODEL_CONFIG, False, False, 4),
+        (
+            lambda: _definition(
+                "LSTMAutoEncoder", kind="lstm_symmetric", lookback_window=24,
+                epochs=1, batch_size=64,
+            ),
+            False, False, 1,
+        ),
+        (_plant_definition, True, True, 1),
+        (_decoder_definition, True, True, 1),
+    ],
+    ids=["flat", "windowed", "remat", "moe_mla_decoder"],
+)
+def test_spec_derives_its_execution_from_the_remat_request(
+    definition, constrained, sequential, unroll
+):
+    """What ``_spec_for`` derives for a flat, a windowed, a remat and the
+    decoder definition: one stored fact, the module's remat request, and
+    from it (with the input kind) the fold mode and the scan unroll. Each
+    definition must also parse into a pipeline at all — a config typo shows
+    here, not in a chip run."""
+    from gordo_components_tpu.parallel.fleet import fit_unroll, sequential_fits
+
+    spec = _spec_for(_analyze_model(pipeline_from_definition(definition())), 4, 4, 2)
+    assert spec.lookback_window >= 1
+    assert spec.memory_constrained is constrained
+    assert sequential_fits(spec) is sequential
+    assert fit_unroll(spec) == unroll
+    # no folds: nothing to vmap, so the fits run in sequence either way
+    assert sequential_fits(spec._replace(n_splits=0))
+
+
+@pytest.mark.parametrize(
+    "definition, bytes_limit, states_that_fit",
+    [
+        (lambda: MODEL_CONFIG, 16 * 2**30, None),  # not memory-constrained
+        (_decoder_definition, None, None),  # the device does not say
+        (_decoder_definition, 16 * 2**30, "from the state"),
+        (_decoder_definition, 1, 1),  # at least one machine a slice
+    ],
+    ids=["unconstrained", "no-limit", "capped", "at-least-one"],
+)
+def test_slice_cap_follows_the_memory_constrained_fact(
+    monkeypatch, definition, bytes_limit, states_that_fit
+):
+    """A slice of a memory-constrained spec holds as many machines as their
+    training state (and a third of it again) fits into three quarters of
+    what the device says it has; any other spec, and a device that says
+    nothing, is not capped."""
+    import importlib
+
+    from gordo_components_tpu.parallel.fleet import abstract_state
+
+    bf = importlib.import_module("gordo_components_tpu.parallel.build_fleet")
+
+    class _Device:
+        def memory_stats(self):
+            return None if bytes_limit is None else {"bytes_limit": bytes_limit}
+
+    spec = _spec_for(_analyze_model(pipeline_from_definition(definition())), 4, 4, 2)
+    if states_that_fit == "from the state":
+        state = sum(
+            leaf.size * leaf.dtype.itemsize
+            for leaf in jax.tree_util.tree_leaves(abstract_state(spec, 1, 4))
+        )
+        states_that_fit = int(0.75 * bytes_limit // (state * 4 / 3))
+        assert states_that_fit > 1
+    monkeypatch.setattr(bf.jax, "local_devices", lambda: [_Device()])
+    assert bf._slice_cap(spec, 4) == states_that_fit
+
+
+def test_fleet_flops_accounting_trip_adjustment():
+    """MFU accounting: the trip-count-adjusted total must dominate the raw
+    whole-program cost_analysis figure (which counts each scan body once)
+    and scale linearly with epochs."""
+    from gordo_components_tpu.parallel.fleet import (
+        compiled_flops,
+        fleet_executable,
+        fleet_flops_accounting,
+    )
+
+    probe = pipeline_from_definition(
+        _definition(
+            "DenseAutoEncoder", kind="feedforward_hourglass", epochs=4,
+            batch_size=64,
+        )
+    )
+    spec = _spec_for(_analyze_model(probe), 10, 10, n_splits=2)
+    acct = fleet_flops_accounting(spec, 2, 128, 10, 10)
+    assert acct is not None
+    # structure: 3 fits x 4 epochs x (128/64=2) steps
+    assert acct["train_steps"] == 3 * spec.epochs * (128 // spec.batch_size)
+    assert acct["predict_chunks"] == 3 * (128 // spec.batch_size)
+    assert acct["total_flops"] > 0
+    # doubling epochs doubles train steps, total grows accordingly
+    acct2 = fleet_flops_accounting(
+        spec._replace(epochs=2 * spec.epochs), 2, 128, 10, 10
+    )
+    assert acct2["train_steps"] == 2 * acct["train_steps"]
+    assert acct2["total_flops"] > acct["total_flops"]
+    # the adjusted total dominates the whole-program body-once figure
+    compiled, _ = fleet_executable(spec, 2, 128, 10, 10)
+    assert acct["total_flops"] >= compiled_flops(compiled)
 
 
 @pytest.mark.slow
@@ -243,8 +390,8 @@ def test_fleet_program_has_nothing_to_donate():
 
 
 def test_fleet_executable_matches_train_fleet_arrays():
-    """The AOT executable fed layout-matched arguments by hand (the path
-    bench.py times) is the program train_fleet_arrays runs."""
+    """The AOT executable fed layout-matched arguments by hand (what the
+    slice loop does) is the program train_fleet_arrays runs."""
     from gordo_components_tpu.parallel.fleet import (
         fleet_executable,
         put_fleet_batch,

@@ -9,7 +9,7 @@ import time
 import numpy as np
 import pytest
 
-import bench_serving
+from gordo_components_tpu.models.synthetic_fleet import build_models
 from gordo_components_tpu.server.engine import (
     ServingEngine,
     _fill_window_us,
@@ -28,7 +28,7 @@ def models():
     """Six same-architecture machines with distinct weights (one fit +
     perturbed replicas — megabatching is about dispatch shape, not
     training quality)."""
-    return bench_serving.build_models(6, 64, 4)
+    return build_models(6, 64, 4)
 
 
 @pytest.fixture(scope="module")

@@ -318,7 +318,7 @@ def test_the_spec_of_a_memory_constrained_model_runs_its_fits_in_sequence():
     from gordo_components_tpu.serializer import pipeline_from_definition
 
     spec = _spec_for(analyze_model(pipeline_from_definition(MODEL)), TAGS, TAGS, 2)
-    assert fleet.sequential_fits(spec) and not spec.widen_predict
+    assert spec.memory_constrained and fleet.sequential_fits(spec)
     assert (spec.rows_out, spec.loss, spec.lookahead) == (16, "module", 1)
     # its training state is an argument of its own, handed back in place
     state = fleet.abstract_state(spec, 1, TAGS)

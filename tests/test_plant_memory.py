@@ -59,15 +59,10 @@ def test_plant_memory_linear_and_fits_v5e():
 
 @pytest.mark.slow
 def test_bench_plant_config_uses_safe_batch_size():
-    """bench.py's plant config must keep the batch size the sweep proved
-    fits; silently bumping it back to 64 re-introduces a guaranteed OOM."""
-    sys.path.insert(0, str(_REPO_ROOT))
-    import bench
+    """The sweep tool's plant config must keep the batch size the sweep
+    proved fits; silently bumping it back to 64 re-introduces a guaranteed
+    OOM."""
+    from plant_memory_sweep import PLANT_ESTIMATOR
 
-    configs = bench._configs(full=False, epochs=2, machines=2)
-    plant = configs["plant_10ktag_bf16"]
-    est = plant["model"]["DiffBasedAnomalyDetector"]["base_estimator"][
-        "TransformedTargetRegressor"
-    ]["regressor"]["Pipeline"]["steps"][1]["PatchTSTAutoEncoder"]
-    assert est["batch_size"] <= 16
-    assert est["remat"] is True
+    assert PLANT_ESTIMATOR["batch_size"] <= 16
+    assert PLANT_ESTIMATOR["remat"] is True

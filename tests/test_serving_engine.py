@@ -33,6 +33,29 @@ def _anomaly_config(epochs=2, extra=None):
     }
 
 
+def test_synthetic_fleet_is_n_machines_that_score_differently():
+    """The fixture the serving tests, the drills and the entry-point dry
+    run share: ``n`` replicas of one fit, each with its own perturbed
+    weights, so a dispatch that confuses two machines shows as a value."""
+    from gordo_components_tpu.models.synthetic_fleet import build_models
+
+    models = build_models(3, 64, 4)
+    assert sorted(models) == ["machine-0000", "machine-0001", "machine-0002"]
+    X = np.random.default_rng(1).normal(size=(64, 4)).astype(np.float32) * 2 + 4
+    totals = [
+        float(model.anomaly(X)["total-anomaly-score"].to_numpy().mean())
+        for model in models.values()
+    ]
+    assert np.isfinite(totals).all() and len(set(totals)) == 3
+    # and one stacked engine serves all of them, each with its own score
+    engine = ServingEngine(models)
+    served = [
+        float(np.asarray(engine.anomaly(name, X).total_anomaly_score).mean())
+        for name in sorted(models)
+    ]
+    np.testing.assert_allclose(served, totals, rtol=1e-4)
+
+
 def _lstm_config():
     return {
         "DiffBasedAnomalyDetector": {
@@ -635,19 +658,14 @@ def test_mesh_sharded_hot_cache_stable_under_uniform_spread():
     round-robin over M machines touches each hot entry only every ~M
     dispatches, so the old FIXED 64-dispatch window evicted live entries
     on every fleet cycle once M > 64 — promote/evict gather churn inside
-    what bench_serving reports as steady state. With the scaled window
+    steady state. With the scaled window
     the working set must not rotate at all under uniform spread."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-    import bench_serving
-
+    from gordo_components_tpu.models.synthetic_fleet import build_models
     from gordo_components_tpu.parallel.mesh import fleet_mesh
     from gordo_components_tpu.server.engine import ServingEngine
 
     machines = 72  # > the 64-dispatch base window: the churn regime
-    models = bench_serving.build_models(machines, 64, 4)
+    models = build_models(machines, 64, 4)
     engine = ServingEngine(models, mesh=fleet_mesh(8), hot_cap=2)
     names = engine.machines()
     rng = np.random.default_rng(6)
@@ -678,18 +696,14 @@ def test_mesh_sharded_steady_state_tail_latency_bounded():
     a proper warmup (every machine served three times, every power-of-two
     batch program executed once), nothing in the steady-state path may
     cost compile-scale time."""
-    import sys
     import time
     from concurrent.futures import ThreadPoolExecutor
-    from pathlib import Path
 
-    sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-    import bench_serving
-
+    from gordo_components_tpu.models.synthetic_fleet import build_models
     from gordo_components_tpu.parallel.mesh import fleet_mesh
     from gordo_components_tpu.server.engine import ServingEngine
 
-    models = bench_serving.build_models(24, 64, 4)
+    models = build_models(24, 64, 4)
     engine = ServingEngine(models, mesh=fleet_mesh(8), hot_cap=4)
     names = engine.machines()
     rng = np.random.default_rng(5)

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Fleet-scale capacity harness (docs/ARCHITECTURE.md §22, ROADMAP item 5).
 
-The north star says "heavy traffic from millions of users"; every bench
+The north star says "heavy traffic from millions of users"; every drill
 before this stopped at 8 machines. This harness makes the claim
 measurable on any rig: it generates a synthetic fleet of 10k-100k TINY
 machines (a realistic shape spread over a few template architectures),
@@ -21,8 +21,7 @@ way it measures exactly the economies ISSUE 14 names:
   cardinality (bounded at ANY fleet size);
 - SLO attainment + the host-cache hit/miss/eviction ledger under load.
 
-Usage (see also `tools/capacity_smoke.py` and the bench `capacity`
-block, which import this module):
+Usage (see also `tools/capacity_smoke.py`, which imports this module):
 
     python tools/capacity_harness.py full --machines 10000
     python tools/capacity_harness.py build --root /tmp/fleet --machines 2000
@@ -862,8 +861,8 @@ def full_run(
     spill_probes: int = 12,
     log: Callable[[str], None] = lambda s: print(s, flush=True),
 ) -> Dict[str, Any]:
-    """The whole §22 story end to end; returns the report dict the bench
-    `capacity` block and the smoke gates read."""
+    """The whole §22 story end to end; returns the report dict the smoke
+    gates read."""
     report: Dict[str, Any] = {"machines": n_machines}
 
     log(f"[1/6] generating {n_machines}-machine synthetic fleet at {root}")

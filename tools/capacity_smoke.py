@@ -6,8 +6,7 @@ Checks (ISSUE 14 acceptance, scaled to CI):
 
 - **lazy boot economics**: a FLEET_INDEX-sidecar boot of the whole
   fleet completes in bounded wall-clock AND ≥5x faster than the
-  full-scan boot of the same tree (the §22 index gate, at 10k machines
-  the bench `capacity` block measures hundreds-x).
+  full-scan boot of the same tree (the §22 index gate).
 - **spill-tier economy**: serving a demoted (host-cache-dropped) lazy
   machine end to end is ≥3x slower than serving it from the host-RAM
   spill tier — i.e. the hit is ≥3x faster, the §22 memcpy-vs-store gate.
@@ -21,7 +20,7 @@ Checks (ISSUE 14 acceptance, scaled to CI):
 
 Fast mode: GORDO_CAPACITY_MACHINES (default 2000) and
 GORDO_CAPACITY_SECONDS (default 4 here) shrink/grow the run; the full
-10k+ sweep lives in the bench `capacity` block and the `slow`-marked
+10k+ sweep is `tools/capacity_harness.py full` and the `slow`-marked
 test in tests/test_capacity_slow.py.
 
 Exit codes: 0 = all checks passed, 1 = at least one failed.

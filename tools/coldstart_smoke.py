@@ -386,12 +386,12 @@ def main() -> int:
 
     import numpy as np
 
-    import bench_serving
+    from gordo_components_tpu.models.synthetic_fleet import build_models
     from gordo_components_tpu.server.engine import ServingEngine
 
     print("cold-start smoke: warm boot O(load), reload/rollback zero "
           "recompiles, corrupt/stale/torn cache fallback")
-    models = bench_serving.build_models(4, 64, 4)
+    models = build_models(4, 64, 4)
     X = np.random.default_rng(11).normal(size=(64, 4)).astype(np.float32)
     # the parity reference: a cache-less engine (today's compile path)
     plain = ServingEngine(models)

@@ -316,8 +316,7 @@ def main() -> int:
         )
         # density gate: the parity-budgeted variant of the SAME evidence
         # must pack more machines per device GiB than the all-measured
-        # baseline (projected at the §19 ladder's byte ratios — the
-        # bench layout block records the same comparison)
+        # baseline (projected at the §19 ladder's byte ratios)
         budgeted = layout_compiler.compile_plan(
             doc, residency_cap=_RESIDENCY_CAP, parity_budget=0.02,
         )

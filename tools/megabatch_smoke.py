@@ -185,11 +185,11 @@ def shard_mode_falls_back(models, X) -> None:
 def main() -> int:
     import numpy as np
 
-    import bench_serving
+    from gordo_components_tpu.models.synthetic_fleet import build_models
 
     print("megabatch smoke: fused-path bit-identity + cross-machine "
           "fusion ratio + fallback honesty")
-    models = bench_serving.build_models(8, 64, 4)
+    models = build_models(8, 64, 4)
     X = np.random.default_rng(23).normal(size=(64, 4)).astype(np.float32)
     X = X * 2 + 4
     fused_path_bit_identity(models, X)

@@ -15,7 +15,7 @@ thresholds on absolute RPS, CI boxes vary):
   pipeline engaged, in BOTH replicated and shard mode. Per-rung RPS is
   printed for the log but deliberately not gated — 2-core CI boxes show
   ±2.5x run-to-run variance, and a flaky gate teaches people to ignore
-  the battery (bench_serving.py is where throughput is tracked).
+  the battery (speed is measured by benchmarks/run.py on the chip).
 
 Exit codes: 0 = all checks passed, 1 = at least one failed.
 """
@@ -207,9 +207,9 @@ def flightrec_overhead(client) -> None:
 
 
 def _build_engines():
-    import bench_serving
+    from gordo_components_tpu.models.synthetic_fleet import build_models
 
-    models = bench_serving.build_models(8, 64, 4)
+    models = build_models(8, 64, 4)
     return models
 
 

@@ -4,7 +4,7 @@ Config 5 (``plant_10ktag_bf16``) has never executed anywhere: CPU is
 measured-impractical. To keep the first real TPU run from burning scarce
 chip time discovering an OOM, this
 sweep compiles the EXACT fleet training program (``fleet_executable`` —
-the program bench.py times) across tag scales on the CPU backend and reads
+the program fleet-build runs) across tag scales on the CPU backend and reads
 XLA's own ``memory_analysis()`` of each compiled executable: argument +
 output + temp bytes. Nothing executes — compile + static analysis only —
 so plant-shape compiles finish in seconds-to-minutes even though running
@@ -25,7 +25,7 @@ What the first run of this sweep found (2026-07-30, r4):
   scales with tags (~1.6 GiB/1k tags).
 - the lever that measurably works is BATCH SIZE: temp is linear in
   B x F, so batch_size 64 → 16 cuts the step peak 4x (measured, not
-  inferred). bench.py's plant config now ships batch_size=16.
+  inferred). The plant config below ships batch_size=16.
 
 Caveats, recorded with the numbers:
 - the XLA:CPU partitioner's buffer assignment is not the TPU's; treat
@@ -37,7 +37,7 @@ Caveats, recorded with the numbers:
   compiled in CPU interpret mode reports interpreter buffers, not the
   TPU kernel's VMEM tiles). With 7 patches per window the attention
   internals are noise; dense is a strict upper bound on flash;
-- everything else matches bench.py's plant config: bf16 compute, remat,
+- everything else is the plant config below: bf16 compute, remat,
   n_splits=1, rows=384, epochs 3.
 
 Outputs a JSON line (and a human table on stderr) with per-scale bytes
@@ -65,28 +65,50 @@ import numpy as np  # noqa: E402
 V5E_HBM_BYTES = 16 * 2**30
 
 
+# the plant-scale machine: a PatchTST autoencoder over 10k tags that asks
+# for remat. batch_size=16, NOT 64: the step peak is linear in batch x tags
+# — B=64 needs ~41 GiB at 10k tags (2.6x v5e HBM, guaranteed OOM); B=16
+# fits with headroom (module docstring).
+PLANT_ESTIMATOR = {
+    "kind": "patchtst",
+    "lookback_window": 32,
+    "d_model": 64,
+    "n_layers": 2,
+    "epochs": 3,
+    "batch_size": 16,
+    "compute_dtype": "bfloat16",
+    "attention_impl": "flash",
+    "remat": True,
+}
+
+
 def plant_model(batch_size: int, remat: bool = True):
-    """bench.py's ACTUAL plant config (derived, not duplicated — a bench
-    edit to d_model/n_layers/etc. flows through here so the sweep can
-    never silently certify a stale model), with two sweep overrides:
+    """The plant config as a model definition, with the sweep's overrides:
     ``batch_size`` is the swept lever, and ``attention_impl`` becomes
     "dense" (see module docstring caveat on interpret-mode Pallas)."""
-    import copy
-
-    import bench
-
-    model = copy.deepcopy(
-        bench._configs(full=False, epochs=9, machines=1)["plant_10ktag_bf16"][
-            "model"
-        ]
+    estimator = dict(
+        PLANT_ESTIMATOR,
+        batch_size=batch_size,
+        attention_impl="dense",
+        remat=remat,
     )
-    est = model["DiffBasedAnomalyDetector"]["base_estimator"][
-        "TransformedTargetRegressor"
-    ]["regressor"]["Pipeline"]["steps"][1]["PatchTSTAutoEncoder"]
-    est["batch_size"] = batch_size
-    est["attention_impl"] = "dense"
-    est["remat"] = remat
-    return model
+    return {
+        "DiffBasedAnomalyDetector": {
+            "base_estimator": {
+                "TransformedTargetRegressor": {
+                    "regressor": {
+                        "Pipeline": {
+                            "steps": [
+                                "MinMaxScaler",
+                                {"PatchTSTAutoEncoder": estimator},
+                            ]
+                        }
+                    },
+                    "transformer": "MinMaxScaler",
+                }
+            }
+        }
+    }
 
 
 def compiled_bytes(

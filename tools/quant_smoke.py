@@ -60,9 +60,9 @@ def _bits(result) -> tuple:
 
 def _mixed_fleet():
     """6 same-architecture machines split 2/2/2 across the ladder."""
-    import bench_serving
+    from gordo_components_tpu.models.synthetic_fleet import build_models
 
-    models = bench_serving.build_models(6, 64, 4)
+    models = build_models(6, 64, 4)
     names = sorted(models)
     precisions = {}
     for i, name in enumerate(names):
