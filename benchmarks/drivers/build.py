@@ -35,6 +35,8 @@ from benchmarks import harness
 from benchmarks.harness import log
 
 POLL_S = 0.01
+# a trace stops this long after its stretch's end, so that the end is in it
+TRACE_EDGE_S = 0.02
 COUNTER = "gordo_fleet_machines_total"
 
 
@@ -137,7 +139,6 @@ class Watch:
         self.base = resolved_counts()
         self.commits: List[float] = []  # commits[k]: when slice k had committed
         self.seen: List[float] = []  # when the poll saw it
-        self.commit_started: List[float] = []  # first machine of slice k seen
         self.worst_poll = 0.0
         self._last_poll = time.perf_counter()
         # file times are on the wall clock; everything else on perf_counter
@@ -174,8 +175,6 @@ class Watch:
         done = sum(
             counts.get(k, 0) - self.base.get(k, 0) for k in ("completed", "failed")
         )
-        if done > len(self.commit_started) * self.slice_size:
-            self.commit_started.append(now)
         while done >= (len(self.commits) + 1) * self.slice_size:
             self.seen.append(now)
             self.commits.append(self._committed_at(len(self.commits), now))
@@ -558,17 +557,28 @@ def run_cell(run: Dict[str, Any]) -> Dict[str, Any]:
 
     # -- the window ---------------------------------------------------------
     trace_dir = os.path.join(work, "trace")
-    trace_times: Optional[Tuple[float, float]] = None
+    trace_times: Optional[Dict[str, float]] = None
     if run["trace"]:
-        # the device's own events and the harness's marks; no Python call
-        # tracing, which slows the job's host side and swells the trace
+        # the device's own events, the harness's marks and the program's
+        # spans; no Python call tracing, which slows the job's host side and
+        # swells the trace
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=options)
-        trace_from = time.perf_counter()
         with jax.profiler.TraceAnnotation("bench:sync"):
             sync_at = time.perf_counter()
-        traced = watch.wait_commit(opened + 1, also_after=trace_from + float(traffic["trace_min_s"]))
+        # The stretch is whole commit periods by the store's file times,
+        # trace_min_s or more of them, laid as far behind its two commits
+        # as the trace's start lay behind the first: the poll's lag and the
+        # profiler's start. The trace is held open that long past the
+        # closing commit, wherever the device's runs fall against either.
+        first = len(watch.commits) - 1  # the newest commit the poll has seen
+        lag = sync_at - watch.commits[first]
+        traced = watch.wait_commit(
+            first + 1, also_after=watch.commits[first] + float(traffic["trace_min_s"])
+        )
+        if traced is not None:
+            time.sleep(max(0.0, watch.commits[traced] + lag + TRACE_EDGE_S - time.perf_counter()))
         trace_to = time.perf_counter()
         if traced is not None and watch.commits[traced] >= t_open + run["seconds"]:
             # the window closes at this commit: let the job reach its next
@@ -578,7 +588,15 @@ def run_cell(run: Dict[str, Any]) -> Dict[str, Any]:
         log(f"trace stopped and read in {time.perf_counter() - trace_to:.1f}s")
         if traced is None:
             return early_end(run, job, watch, "inside the traced stretch")
-        trace_times = (trace_from, trace_to, sync_at)
+        trace_times = {
+            "periods": traced - first, "ran_s": trace_to - sync_at,
+            "stretch_s": watch.commits[traced] - watch.commits[first],
+        }
+        log(
+            f"traced stretch: {traced - first} commit period(s), slice {first} to "
+            f"{traced}, {trace_times['stretch_s']:.3f}s by the store's file times, laid "
+            f"{lag:.3f}s behind its commits; the trace ran {trace_times['ran_s']:.3f}s"
+        )
     with jax.profiler.TraceAnnotation("bench:window"):
         closed = watch.wait_commit(opened + 1, also_after=t_open + run["seconds"])
     if closed is None:
@@ -638,7 +656,7 @@ def run_cell(run: Dict[str, Any]) -> Dict[str, Any]:
     }
     breakdown = None
     if run["trace"]:
-        view.update(trace_view(run, trace_data, trace_times, watch, fetches, slice_size))
+        view.update(trace_view(run, trace_data, trace_times, fetches, slice_size))
         breakdown = view.get("breakdown")
         if view["trace"] is not None:
             device["busy_s"] = view["trace"]["busy_s"]
@@ -720,12 +738,14 @@ def stop_trace_in_memory(trace_dir: str):
     return data
 
 
-def trace_view(run, trace_data, trace_times, watch, fetches, slice_size) -> Dict[str, Any]:
+def trace_view(run, trace_data, trace_times, fetches, slice_size) -> Dict[str, Any]:
     """The traced stretch, reduced: device numbers on the trace's clock, and
-    each long idle gap named by what the job's host side was doing then."""
+    each long idle gap named by the program's own span it falls in. Where
+    the trace does not hold the stretch, stderr says what is missing and
+    no device number is read off it."""
     from benchmarks import flops_bytes, trace_reduce
+    from benchmarks.layer_metrics import execute_wait_s_per_slice
 
-    trace_from, trace_to, sync_at = trace_times
     if trace_data is None or run["device"]["platform"] == "cpu":
         return {"trace": None}
     started = time.perf_counter()
@@ -734,37 +754,34 @@ def trace_view(run, trace_data, trace_times, watch, fetches, slice_size) -> Dict
     if not reduced["devices"] or not sync:
         log("trace: no device plane or no sync mark; planes unread")
         return {"trace": None}
-    # trace clock -> this process's perf_counter
-    shift = sync_at - sync[0][0]
-    lo, hi = trace_from - shift, trace_to - shift
-    summary = trace_reduce.window_summary(reduced, lo, hi)
+    # the stretch begins at the sync mark, on the trace's own clock
+    lo = sync[0][0]
+    hi = lo + trace_times["stretch_s"]
+    summary = trace_reduce.window_summary(reduced, lo, hi, trace_times["periods"])
     log(
         f"trace reduced in "
-        f"{time.perf_counter() - started:.1f}s: {summary['chips']} chip(s), window "
-        f"{summary['window_s']:.3f}s, busy {summary['busy_s']:.3f}s, modules "
-        f"{ {m: (len(r), round(sum(r), 3)) for m, r in summary['modules'].items()} }"
+        f"{time.perf_counter() - started:.1f}s: {summary['chips']} chip(s), stretch "
+        f"{summary['window_s']:.3f}s, busy {summary['busy_s']:.3f}s, programs' seconds "
+        f"inside { {m: round(s, 3) for m, s in summary['module_s'].items() if s >= 1e-3} }, "
+        f"whole runs { {m: len(r) for m, r in summary['modules'].items()} }"
     )
+    short_by = trace_times["stretch_s"] - trace_times["ran_s"]
+    lacks = (
+        f"the trace stopped {short_by:.3f}s before the stretch's end" if short_by > 0
+        else trace_reduce.missing(
+            summary, run["config"].get("train_module"),
+            execute_wait_s_per_slice.read({}),
+        )
+    )
+    if lacks:
+        log(f"trace: {lacks}; no device number is read off it")
+        return {"trace": None}
     peak = harness.peak_for(run["device"]["kind"])
     counts = flops_bytes.slice_counts(
         run["config"]["reference_model"], slice_size, padded_rows(fetches),
         run["config"]["tags"],
     )
-    # name the idle gaps by the job's host-side phase at that time
-    phases = []
-    for k, done in enumerate(watch.commits):
-        begun = watch.commit_started[k] if k < len(watch.commit_started) else done
-        phases.append((begun, done, f"slice-commit-{k}"))
-    named: Dict[str, float] = {}
-    for a, b in summary["gaps"]:
-        a_host, b_host = a + shift, b + shift
-        label, best = "between-commit-and-train", 0.0
-        for begun, done, name in phases:
-            overlap = min(b_host, done) - max(a_host, begun)
-            if overlap > best:
-                label, best = name, overlap
-        if best < 0.5 * (b - a):
-            label = "fetch-wait-ingest-or-result-fetch"
-        named[label] = named.get(label, 0.0) + (b - a)
+    named = trace_reduce.name_gaps(summary["gaps"], reduced["marks"])
     breakdown = {
         "device_ops": [[name, seconds] for name, seconds in summary["top_ops"]],
         "idle_gaps": [

@@ -1,4 +1,5 @@
-"""``device_idle_pct``: 1 - (union of device-op intervals) / traced stretch.
+"""``device_idle_pct``: 1 - (union of device-op intervals) / traced stretch,
+a whole number of commit periods by the store's own file times.
 
 Layer: device. Source: device trace. Moves ``machines_per_hour``.
 """

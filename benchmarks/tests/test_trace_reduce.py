@@ -35,7 +35,13 @@ def test_window_summary_on_hand_made_planes():
     summary = trace_reduce.window_summary(reduced, 0.0, 10.0)
     assert summary["chips"] == 1 and summary["window_s"] == 10.0
     assert summary["busy_s"] == pytest.approx(5.0)  # the third run is clipped
-    assert summary["modules"] == {"jit_program": [2.0, 2.0]}  # whole runs only
+    # a program's seconds inside the stretch, the run at the edge cut there
+    assert summary["module_s"] == {"jit_program": pytest.approx(5.0)}
+    assert summary["periods"] == 1
+    assert summary["modules"] == {"jit_program": [2.0, 2.0]}  # for the log line
+    assert trace_reduce.window_summary(reduced, 2.0, 10.0, 2)["module_s"] == {
+        "jit_program": pytest.approx(4.0)
+    }
     assert summary["top_ops"][0] == ("fusion.1", 4.0)
     assert summary["gaps"][0] == (3.0, 5.0)
 
@@ -58,4 +64,10 @@ def test_recorded_trace_from_the_chip():
     assert 0.5 * module_s < summary["busy_s"] <= module_s * 1.001
     assert summary["busy_s"] < 0.2 * summary["window_s"]  # mostly the pauses
     assert len(summary["modules"]["jit_fixture_program"]) == 3
+    assert summary["module_s"]["jit_fixture_program"] == pytest.approx(module_s)
+    # cut in the middle of the second run: half of it is inside
+    middle = starts[1] + 0.5 * runs[1][1]
+    half = trace_reduce.window_summary(reduced, lo, middle)
+    assert half["module_s"]["jit_fixture_program"] == pytest.approx(runs[0][1] + 0.5 * runs[1][1])
+    assert trace_reduce.missing(summary, "jit_fixture_program", None) is None
     assert len([g for g in summary["gaps"] if g[1] - g[0] > 0.04]) == 2
