@@ -195,8 +195,9 @@ def build_cmd(name, model_config, data_config, output_dir, model_register_dir,
 @click.option("--seed", default=0, show_default=True)
 @click.option("--slice-size", default=256, show_default=True, type=int,
               help="machines per checkpointed slice within a bucket: each "
-                   "slice's artifacts + registry keys land as it finishes, "
-                   "so a killed build loses at most one slice; 0 disables "
+                   "slice's artifacts + registry keys land while the next "
+                   "slice trains, so a killed build loses at most the "
+                   "slice training and the slice committing; 0 disables "
                    "slicing (whole bucket per program call)")
 @click.option("--coordinator-address", envvar="GORDO_COORDINATOR", default=None,
               help="multi-host: jax.distributed coordinator host:port — run "

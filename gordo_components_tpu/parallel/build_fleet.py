@@ -24,13 +24,15 @@ The estimator must be a zoo model (``BaseFlaxEstimator``); the scaler
 
 from __future__ import annotations
 
+import contextlib
 import json
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import Future, ThreadPoolExecutor
+from concurrent.futures import wait as futures_wait
 import logging
 import os
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import numpy as np
@@ -294,12 +296,28 @@ def _prepare_slice(
     return X, y, w, n_rows
 
 
-def _prepare_bound(seam: spans.SpanContext, bucket: int, sl: int, *args):
+def _prepare_bound(
+    seam: spans.SpanContext, bucket: int, sl: int,
+    after: Optional[Future], *args,
+):
     """The prefetch worker's side of the seam: :func:`_prepare_slice` as
     the stage ``fleet.prepare`` of the job's timeline, under the context
-    the main thread captured when it submitted the slice."""
+    the main thread captured when it submitted the slice.
+
+    ``after``: the commit that was in flight then. The fetch begins once it
+    has ended (``held_s`` on the stage: how long that was): a fetch pool
+    busy in Python starves the commit worker of the interpreter lock — a
+    commit of 2 s took 22 s beside eight such threads, and every artifact
+    of the slice landed that much later (PERF.md §6, PR 35) — while a
+    commit handed over with the slice's result ends before a fetch that
+    needs a whole slice's time has to begin. What the commit raised is the
+    loop's join's to raise, not this worker's."""
+    held = time.perf_counter()
+    if after is not None:
+        futures_wait([after])
+    held = time.perf_counter() - held
     with spans.bind(seam), spans.stage(
-        "fleet.prepare", bucket=bucket, slice=sl
+        "fleet.prepare", bucket=bucket, slice=sl, held_s=held
     ):
         return _prepare_slice(*args)
 
@@ -959,22 +977,27 @@ def build_fleet(
     Remaining machines are bucketed by (model config, data shape)
     and each bucket trains as one compiled program, sharded over ``mesh``.
     ``profile_dir``: one ``jax.profiler`` session around one whole steady
-    slice (the second of the first bucket, else the first), host phases
-    included, and the job's span timeline as ``fleet_build_timeline.json``
-    beside it.
+    slice (the second of the first bucket, else the first) and its commit,
+    host phases included, and the job's span timeline as
+    ``fleet_build_timeline.json`` beside it.
 
     **Spans**: the job is one ``observability.spans.Timeline`` (``fleet.job``
     → ``fleet.preamble``, ``fleet.bucket`` → ``fleet.slice`` and its phases
     on this thread, ``fleet.prepare`` and its fetches on the prefetch
+    worker's, ``fleet.commit_loop`` and ``fleet.manifest`` on the commit
     worker's; docs/ARCHITECTURE.md §13), handed to the flight recorder
     (``meta`` ``kind="fleet-build"``) however the job ends.
 
     Buckets larger than ``slice_size`` train in slices: every slice is padded
     to the same machine count (so the compiled executable is reused across
-    slices) and its artifacts + registry keys are written the moment it
-    finishes — a killed build loses at most one in-flight slice, and the
-    resume pass skips everything already registered. ``slice_size=None``
-    trains each bucket in a single program call (round-1 behavior).
+    slices) and its artifacts + registry keys are written the moment its
+    result is on the host, by a worker thread while the next slice trains
+    (:class:`_SliceCommitter`: one commit in flight; this call returns, or
+    lets an exception out, only after it has ended) — a killed build loses
+    at most the slice in flight and the slice committing (whose checkpoint,
+    where it has more than one machine, a resume restores), and the resume
+    pass skips everything already registered. ``slice_size=None`` trains
+    each bucket in a single program call (round-1 behavior).
 
     **Multi-host** (``jax.process_count() > 1`` with a
     :func:`~gordo_components_tpu.parallel.distributed.global_fleet_mesh`):
@@ -1270,6 +1293,12 @@ def _build_fleet(
         prefetcher = ThreadPoolExecutor(
             max_workers=1, thread_name_prefix="fleet-prefetch"
         )
+        books = _JobBooks(
+            output_dir, model_register_dir, precision_of, journal,
+            journal_counts, manifest, results, pending_names,
+        )
+        committer = _SliceCommitter(checkpointer)
+        session = contextlib.ExitStack()  # --trace-dir's profiler session
         preamble["cached"] = len(results)
         preamble["buckets"] = len(buckets)
     logger.info(
@@ -1339,17 +1368,18 @@ def _build_fleet(
 
                 def prefetch(s: int):
                     return prefetcher.submit(
-                        _prepare_bound, seam, b, s,
+                        _prepare_bound, seam, b, s, committer.in_flight(),
                         slices[s], n_padded, n_features, n_targets,
                         len(slices) > 1, span, place, fetch_retries,
                         fetch_backoff,
                     )
 
                 prepared = prefetch(0)
-                # --trace-dir: ONE profiler session per job, around one whole
-                # steady slice (the first of a job holds the compile), from
-                # its wait for the prefetch to its checkpoint's end — host
-                # phases and the idle gaps between them included
+                # --trace-dir: ONE profiler session per job, from one steady
+                # slice's wait for the prefetch (the first slice of a job
+                # holds the compile) until that slice's commit has been
+                # joined — host phases and the idle gaps between them
+                # included, and the commit beside the next slice's device ops
                 traced_slice = min(1, len(slices) - 1) if b == 0 else None
                 for s, slice_items in enumerate(slices):
                     # armed only multi-host + GORDO_SLICE_TIMEOUT_S: if THIS
@@ -1358,9 +1388,9 @@ def _build_fleet(
                     # job layer to restart; disarmed at iteration end below
                     # and in the outer finally
                     watchdog.start(b, s)
-                    with device_trace(
-                        profile_dir if s == traced_slice else None
-                    ), spans.stage(
+                    if s == traced_slice:
+                        session.enter_context(device_trace(profile_dir))
+                    with spans.stage(
                         "fleet.slice", bucket=b, slice=s,
                         machines=len(slice_items),
                     ) as sliced:
@@ -1397,42 +1427,51 @@ def _build_fleet(
                                     spec, n_padded, n_rows, n_features, n_targets
                                 ),
                             )
-                            restore["hit"] = result is not None
-                        if result is None:
+                            restore["hit"] = restored = result is not None
+                        if not restored:
                             # stages fleet.program, fleet.ingest (the
                             # device_put) and fleet.execute, which ends when
                             # the result is ready on the device
                             result = train_fleet_arrays(spec, batch, mesh=mesh)
                             trained_on = _device_summary(result.loss_history)
-                            if not multihost:
+                        # what the commit reads is on the host: the whole
+                        # result, or this process's machine block of a
+                        # globally sharded one (restored or trained), which
+                        # the checkpoint keeps sharded
+                        if multihost:
+                            with spans.stage("fleet.result_fetch"):
+                                on_host = _gather_local_block(result)
+                        else:
+                            if not restored:
                                 with spans.stage("fleet.result_fetch") as fetched:
                                     result = jax.device_get(result)
                                     fetched["bytes"] = sum(
                                         leaf.nbytes for leaf in
                                         jax.tree_util.tree_leaves(result)
                                     )
+                            on_host = result
+                            # what the model's own loss counted over each
+                            # machine's final fit (models.train: counters)
+                            for name, counted in (result.counters or {}).items():
+                                sliced[name] = np.asarray(counted).tolist()
+                        slice_duration = time.perf_counter() - slice_started
+
+                        # at most one commit in flight, and one thread for
+                        # the checkpointer and its collectives: the slice
+                        # before is durable and its checkpoint dropped
+                        # before this one's is written
+                        committer.join()
+                        if not restored:
                             # async: orbax writes in the background while the
-                            # artifact loop below runs (multi-host: a
-                            # COLLECTIVE save of the sharded result);
-                            # finalize() joins + deletes. A slice of one
+                            # worker commits (multi-host: a COLLECTIVE save
+                            # of the sharded result); the join of this
+                            # slice's commit finalizes it. A slice of one
                             # machine has nothing to save that its artifact,
                             # written next, does not hold
                             with spans.stage("fleet.checkpoint_save") as saved:
                                 saved["skipped"] = n_padded == 1
                                 if n_padded > 1:
                                     checkpointer.save_async(ckpt_key, result)
-                        # what the model's own loss counted over each
-                        # machine's final fit (models.train: counters)
-                        for name, counted in (result.counters or {}).items():
-                            if not multihost:
-                                sliced[name] = np.asarray(counted).tolist()
-                        if multihost:
-                            # restored or trained, the result is globally
-                            # sharded: pull only this process's machine block
-                            # to host
-                            with spans.stage("fleet.result_fetch"):
-                                result = _gather_local_block(result)
-                        slice_duration = time.perf_counter() - slice_started
 
                         if multihost:
                             lo, hi = span
@@ -1463,69 +1502,35 @@ def _build_fleet(
                         }
 
                         # ---- per-machine artifacts (same format as the
-                        # single path), written before the next slice trains
-                        # so a kill loses at most the in-flight slice --------
-                        with spans.stage("fleet.commit_loop"):
-                            for i, item in indexed_items:
-                                name = item["machine"].name
-                                with spans.stage(
-                                    "fleet.commit", machine=name
-                                ) as commit:
-                                    if "build_error" in item:
-                                        # isolated at fetch: trained as
-                                        # zero-weight padding; no artifact, no
-                                        # registry key — the next run retries
-                                        # it from scratch
-                                        manifest[name] = {
-                                            "status": "failed",
-                                            "error": item["build_error"],
-                                            "bucket": b,
-                                            "slice": s,
-                                        }
-                                        journal.record(
-                                            name,
-                                            store_journal.EVENT_FAILED,
-                                            error=item["build_error"],
-                                        )
-                                        _M_FLEET_MACHINES.labels("failed").inc()
-                                        commit["outcome"] = "failed"
-                                        continue
-                                    model_dir = _commit_machine(
-                                        item, result, i,
-                                        (n_features, n_targets, bucket_splits),
-                                        provenance, output_dir,
-                                        model_register_dir,
-                                        precision_of(name), journal,
-                                    )
-                                    commit["bytes"] = sum(
-                                        leaf[i].nbytes for leaf in
-                                        jax.tree_util.tree_leaves(result)
-                                    )
-                                    journal_counts["rebuilt"] += 1
-                                    results[name] = model_dir
-                                    _M_FLEET_MACHINES.labels("completed").inc()
-                                    manifest[name] = {
-                                        "status": "completed",
-                                        "model_dir": model_dir,
-                                        "bucket": b,
-                                        "slice": s,
-                                    }
-                                    commit["outcome"] = "completed"
-                        with spans.stage("fleet.manifest"):
-                            _write_manifest(
-                                output_dir,
-                                manifest,
-                                [n for n in pending_names if n not in manifest],
-                                journal_counts=journal_counts,
-                            )
-                        with spans.stage("fleet.checkpoint_wait"):
-                            # artifacts durable → join the async save, drop
-                            # the ckpt (multi-host: barrier, then process 0
-                            # deletes)
-                            checkpointer.finalize(ckpt_key)
-                        for item in slice_items:  # free before the next fetch
+                        # single path), written by the commit worker while
+                        # the next slice trains: a kill loses at most the
+                        # slice in flight and the slice committing. A slice
+                        # of several machines keeps its checkpoint until its
+                        # commit is durable, so a resume restores it; a
+                        # slice of one saved none and is retrained ----------
+                        for item in slice_items:
+                            # free before the next fetch: the batch holds
+                            # the rows, and the commit reads none of them
                             item.pop("X", None)
                             item.pop("y", None)
+                        committer.hand_over(
+                            s, ckpt_key, books, indexed_items, on_host,
+                            (n_features, n_targets, bucket_splits),
+                            provenance,
+                        )
+                        # the worker's is the last hold on the fetched
+                        # result: a large one (2 GB take a tenth of a
+                        # second to unmap) is freed there, beside the next
+                        # slice's run, not here between two runs
+                        del result, on_host
+                        if s + 1 == len(slices):
+                            # no slice of this bucket is left to train
+                            # beside the commit
+                            committer.join()
+                    if traced_slice is not None and (
+                        s > traced_slice or s + 1 == len(slices)
+                    ):
+                        session.close()  # the traced slice has committed
                     watchdog.stop()  # this slice made liveness; next start()
                     # re-arms with a fresh budget
             logger.info(
@@ -1536,7 +1541,14 @@ def _build_fleet(
     finally:
         watchdog.stop()
         prefetcher.shutdown(wait=True, cancel_futures=True)
-        checkpointer.join()
+        try:
+            # where an exception is on its way out with a commit in flight:
+            # the job ends only after that commit has, and with the commit's
+            # own exception if it failed
+            committer.drain()
+        finally:
+            session.close()
+            checkpointer.join()
     checkpointer.close()
     return results
 
@@ -1571,6 +1583,149 @@ def _global_batch(X, y, w, n_rows: int, keys, mesh, span: Tuple[int, int]):
         ),
     )
     return batch, n_rows
+
+
+class _JobBooks(NamedTuple):
+    """What a job keeps of its machines' outcomes, and where it writes them.
+    The preamble fills them on the loop's thread; from the first hand-over
+    on, the commit worker alone writes them (one commit in flight, joined
+    before the next is handed over and before ``build_fleet`` ends)."""
+
+    output_dir: str
+    model_register_dir: Optional[str]
+    precision_of: Callable[[str], str]
+    journal: Any
+    journal_counts: Dict[str, int]
+    manifest: Dict[str, Dict[str, Any]]
+    results: Dict[str, str]
+    pending_names: List[str]
+
+
+class _SliceCommitter:
+    """The artifact commit off the build loop's thread: slice ``s`` commits
+    on the one ``fleet-commit`` worker while the loop's thread waits for
+    slice ``s+1``'s prefetch, ingests it, dispatches its program and waits
+    on the device. At most one commit is in flight: :meth:`join` comes
+    before every :meth:`hand_over`.
+
+    The checkpointer stays the loop's thread's (one thread touches it, and
+    multi-host every process reaches its barrier at the same point of its
+    loop): :meth:`join` drops a slice's checkpoint once that slice's commit
+    is durable, never sooner, so a kill inside a commit finds the trained
+    result on disk."""
+
+    def __init__(self, checkpointer: "_SliceCheckpointer"):
+        self._pool = ThreadPoolExecutor(
+            max_workers=1, thread_name_prefix="fleet-commit"
+        )
+        self._checkpointer = checkpointer
+        self._in_flight: Optional[Tuple[Future, int, str]] = None
+
+    def hand_over(self, sl: int, ckpt_key: str, *commit_args) -> None:
+        """Slice ``sl``'s commit (:func:`_commit_slice`) to the worker, its
+        spans under the stage open here: the slice's own."""
+        if self._in_flight is not None:
+            raise RuntimeError("a commit is in flight: join() comes first")
+        future = self._pool.submit(
+            _commit_slice, spans.capture(), *commit_args
+        )
+        self._in_flight = (future, sl, ckpt_key)
+
+    def in_flight(self) -> Optional[Future]:
+        """The commit on the worker now, for what has to begin after it."""
+        return self._in_flight[0] if self._in_flight is not None else None
+
+    def join(self) -> None:
+        """On the loop's thread, inside a ``fleet.slice`` stage: wait for
+        the commit in flight (``fleet.commit_wait``: 0 where the commit hid
+        whole behind the slice that trained beside it), raise what it
+        raised, then join its slice's checkpoint save and drop the
+        checkpoint (``fleet.checkpoint_wait``; multi-host: barrier, then
+        process 0 deletes). Nothing in flight: nothing to do."""
+        if self._in_flight is None:
+            return
+        (future, sl, ckpt_key), self._in_flight = self._in_flight, None
+        with spans.stage("fleet.commit_wait", slice=sl):
+            future.result()
+        with spans.stage("fleet.checkpoint_wait"):
+            self._checkpointer.finalize(ckpt_key)
+
+    def drain(self) -> None:
+        """The job's ending, whatever ends it: the commit in flight runs to
+        its end and raises here what it raised; its checkpoint stays for the
+        resume. No worker thread is left behind."""
+        self._pool.shutdown(wait=True)
+        if self._in_flight is not None:
+            (future, _, _), self._in_flight = self._in_flight, None
+            future.result()
+
+
+def _commit_slice(
+    seam: spans.SpanContext,
+    books: _JobBooks,
+    indexed_items: List[Tuple[int, dict]],
+    result,
+    shape: Tuple[int, int, int],
+    provenance: Dict[str, Any],
+) -> None:
+    """The commit worker's side of the seam: every machine of a trained
+    slice durable (:func:`_commit_machine`: artifact, then registry key and
+    journal record), then the manifest's rewrite — ``fleet.commit_loop`` and
+    ``fleet.manifest`` of the job's timeline, under the slice's stage (the
+    context captured at hand-over).
+    ``result`` is on the host; nothing here touches a device. Every input is
+    an argument (the call runs on another thread: see
+    :func:`_prepare_slice`)."""
+    b, s = provenance["bucket"], provenance["slice"]
+    with spans.bind(seam):
+        with spans.stage("fleet.commit_loop"):
+            for i, item in indexed_items:
+                name = item["machine"].name
+                with spans.stage("fleet.commit", machine=name) as commit:
+                    if "build_error" in item:
+                        # isolated at fetch: trained as zero-weight padding;
+                        # no artifact, no registry key — the next run
+                        # retries it from scratch
+                        books.manifest[name] = {
+                            "status": "failed",
+                            "error": item["build_error"],
+                            "bucket": b,
+                            "slice": s,
+                        }
+                        books.journal.record(
+                            name,
+                            store_journal.EVENT_FAILED,
+                            error=item["build_error"],
+                        )
+                        _M_FLEET_MACHINES.labels("failed").inc()
+                        commit["outcome"] = "failed"
+                        continue
+                    model_dir = _commit_machine(
+                        item, result, i, shape, provenance,
+                        books.output_dir, books.model_register_dir,
+                        books.precision_of(name), books.journal,
+                    )
+                    commit["bytes"] = sum(
+                        leaf[i].nbytes for leaf in
+                        jax.tree_util.tree_leaves(result)
+                    )
+                    books.journal_counts["rebuilt"] += 1
+                    books.results[name] = model_dir
+                    _M_FLEET_MACHINES.labels("completed").inc()
+                    books.manifest[name] = {
+                        "status": "completed",
+                        "model_dir": model_dir,
+                        "bucket": b,
+                        "slice": s,
+                    }
+                    commit["outcome"] = "completed"
+        with spans.stage("fleet.manifest"):
+            _write_manifest(
+                books.output_dir,
+                books.manifest,
+                [n for n in books.pending_names if n not in books.manifest],
+                journal_counts=books.journal_counts,
+            )
 
 
 def _commit_machine(

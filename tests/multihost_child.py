@@ -195,7 +195,8 @@ def build_crash_mode(output_dir: str) -> None:
 
 def _install_die_at_slice1(victim_ranks) -> None:
     """Monkeypatch shared by the asymmetric drills: the given ranks die at
-    the start of slice 1 (after slice 0's artifacts landed); every other
+    the start of slice 1 (slice 0's commit is in flight on the commit
+    worker then, and dies with the process); every other
     rank survives, stalls in the slice's collective assembly, and must be
     freed by the slice watchdog with the RETRYABLE exit code."""
     import importlib
@@ -215,8 +216,8 @@ def _install_die_at_slice1(victim_ranks) -> None:
 
 def build_asym_crash_mode(output_dir: str) -> None:
     """ASYMMETRIC failure drill (ROADMAP #5 / VERDICT r3 weak #5): only
-    process 1 dies — at the start of its second slice, after slice 0's
-    artifacts landed. The survivors stall in the slice's collective
+    process 1 dies — at the start of its second slice, with slice 0's
+    commit in flight. The survivors stall in the slice's collective
     assembly (their peer is gone) and must be killed by the slice watchdog
     (``GORDO_SLICE_TIMEOUT_S``, set by the parent test) with the RETRYABLE
     exit code — never hang. The parent then re-runs a normal build, which

@@ -256,8 +256,8 @@ def test_two_process_kill_mid_build_restores_from_checkpoint(tmp_path):
 @pytest.mark.slow
 def test_two_process_asymmetric_peer_death_fails_fast_and_resumes(tmp_path):
     """ROADMAP #5 / VERDICT r3 weak #5: ASYMMETRIC multi-host failure. Only
-    process 1 dies (at the start of slice 1, after slice 0's artifacts
-    landed). The survivor must FAIL FAST with a retryable outcome — on
+    process 1 dies (at the start of slice 1, with slice 0's commit in flight
+    on its commit worker). The survivor must FAIL FAST with a retryable outcome — on
     Gloo the transport detects the dead peer (connection reset ->
     JaxRuntimeError -> generic nonzero exit, which the CLI maps to the
     retryable code; only 64/66 mean permanent) — never complete a partial
@@ -286,12 +286,15 @@ def test_two_process_asymmetric_peer_death_fails_fast_and_resumes(tmp_path):
     assert survivor_code > 0 and survivor_code not in (64, 66), (
         codes, "\n".join(outputs)
     )
-    # slice 0's artifacts survived the crash (both processes' halves)
+    # slice 0's artifacts survived the crash: the survivor's half whole (it
+    # drains its commit worker before it lets the transport error out), the
+    # victim's as far as its worker had come when it died — a kill loses the
+    # slice in flight AND the slice committing beside it
     built_before = {
         name for name in os.listdir(os.path.join(out_dir, "models"))
         if name.startswith("mh-")
     }
-    assert len(built_before) == 8, built_before
+    assert 4 <= len(built_before) <= 8, built_before
 
     # restart-all: a NORMAL re-run (same dirs) resumes and completes —
     # with a realistic watchdog budget (the tight 45s is for freeing
@@ -579,13 +582,15 @@ def test_four_process_two_nonadjacent_peer_deaths_fail_fast_and_resume(
             codes,
             outputs[survivor][-2000:],
         )
-    # slice 0's artifacts (8 of 16 machines) survived the deaths
+    # slice 0's artifacts survived the deaths: the two survivors' quarters
+    # whole (each drains its commit worker before it exits), the victims' as
+    # far as their workers had come when they died
     built_before = {
         name
         for name in os.listdir(os.path.join(out_dir, "models"))
         if name.startswith("mh-")
     }
-    assert len(built_before) == 8, built_before
+    assert 4 <= len(built_before) <= 8, built_before
 
     # resume with a REALISTIC watchdog budget: the drill's tight 45s
     # exists to free the survivors quickly in the death phase; the

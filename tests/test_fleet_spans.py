@@ -51,6 +51,7 @@ N_MACHINES, SLICE = 6, 2
 # span -> (its parent's name, the thread that records it); the names are the
 # contract the benchmark's readers and PERF.md use
 MAIN, PREFETCH, POOL = "MainThread", "fleet-prefetch", "fleet-fetch"
+COMMIT = "fleet-commit"  # slice s commits there while slice s+1 trains
 SPAN_TREE = {
     "fleet.job": (None, MAIN),
     "fleet.preamble": ("fleet.job", MAIN),
@@ -63,9 +64,10 @@ SPAN_TREE = {
     "fleet.execute": ("fleet.slice", MAIN),
     "fleet.result_fetch": ("fleet.slice", MAIN),
     "fleet.checkpoint_save": ("fleet.slice", MAIN),
-    "fleet.commit_loop": ("fleet.slice", MAIN),
-    "fleet.commit": ("fleet.commit_loop", MAIN),
-    "fleet.manifest": ("fleet.slice", MAIN),
+    "fleet.commit_wait": ("fleet.slice", MAIN),
+    "fleet.commit_loop": ("fleet.slice", COMMIT),
+    "fleet.commit": ("fleet.commit_loop", COMMIT),
+    "fleet.manifest": ("fleet.slice", COMMIT),
     "fleet.checkpoint_wait": ("fleet.slice", MAIN),
     "fleet.prepare": ("fleet.bucket", PREFETCH),
     "fleet.fetch": ("fleet.prepare", POOL),
@@ -76,8 +78,10 @@ READERS = (
     "prefetch_wait_s_per_slice", "ingest_s_per_slice",
     "result_fetch_s_per_slice", "checkpoint_s_per_slice",
     "commit_s_per_machine", "execute_wait_s_per_slice",
-    "slice_unattributed_s",
+    "slice_unattributed_s", "commit_wait_s_per_slice",
 )
+# what the loop's thread does in a slice: the readers of these cover it
+LOOP_READERS = tuple(r for r in READERS if r != "commit_s_per_machine")
 
 
 def _machines(prefix, dataset=None):
@@ -138,9 +142,19 @@ def test_span_has_its_parent_and_thread(traced_build, name):
         "fleet.job": 1, "fleet.slice": per_slice, "fleet.prepare": per_slice,
         "fleet.commit": N_MACHINES, "fleet.fetch": N_MACHINES,
         "fleet.ingest": 2 * per_slice,  # batch assembly, then the device_put
+        "fleet.commit_loop": per_slice, "fleet.manifest": per_slice,
+        # every slice's commit is joined once: by the slice after it, the
+        # bucket's last by itself
+        "fleet.commit_wait": per_slice, "fleet.checkpoint_wait": per_slice,
     }
     if name in expected:
         assert len(found) == expected[name]
+    if name == "fleet.commit_wait":
+        slices = {s.id: s.attrs["slice"] for s in timeline.spans
+                  if s.name == "fleet.slice"}
+        assert sorted(
+            (slices[w.parent], w.attrs["slice"]) for w in found
+        ) == [(1, 0), (2, 1), (2, 2)]
 
 
 def test_slice_children_cover_it_to_within_its_self_time(traced_build):
@@ -149,17 +163,30 @@ def test_slice_children_cover_it_to_within_its_self_time(traced_build):
     slices = [s for s in timeline.spans if s.name == "fleet.slice"]
     for parent in slices:
         children = [s for s in timeline.spans if s.parent == parent.id]
-        # one thread, one after the other: no child overlaps the next
-        ordered = sorted(children, key=lambda s: s.start)
-        for a, b in zip(ordered, ordered[1:]):
-            assert a.start + a.duration <= b.start + 1e-6
-        covered = sum(s.duration for s in children)
-        assert covered + self_seconds[parent.id] == pytest.approx(
-            parent.duration, abs=1e-6
+        # the loop's thread, one phase after the other: no child overlaps
+        # the next
+        on_loop = sorted(
+            (s for s in children if s.thread == parent.thread),
+            key=lambda s: s.start,
         )
+        for a, b in zip(on_loop, on_loop[1:]):
+            assert a.start + a.duration <= b.start + 1e-6
+        # the slice's commit runs on the worker, after the slice's span or
+        # (the last slice) beside its wait for it: a child is clipped to its
+        # parent's interval, so the self time stays the loop's own
+        loop_self = parent.duration - sum(s.duration for s in on_loop)
+        assert -1e-6 <= self_seconds[parent.id] <= loop_self + 1e-6
         # every phase of the loop has a span: what is left is the loop's own
         # few statements between them
-        assert self_seconds[parent.id] < 0.25 + 0.05 * parent.duration
+        assert loop_self < 0.25 + 0.05 * parent.duration
+        worker = [s for s in children if s.thread != parent.thread]
+        assert sorted(s.name for s in worker) == [
+            "fleet.commit_loop", "fleet.manifest"
+        ]
+        assert all(s.thread.startswith(COMMIT) for s in worker)
+        # handed over once the slice's own checkpoint is on its way
+        saved, = [s for s in on_loop if s.name == "fleet.checkpoint_save"]
+        assert min(s.start for s in worker) >= saved.start + saved.duration
     # the first slice holds the compile, and says so
     programs = sorted(
         (s for s in timeline.spans if s.name == "fleet.program"),
@@ -210,18 +237,42 @@ def test_profiler_session_holds_the_spans_as_host_annotations(traced_build):
         if parent in ("fleet.slice", "fleet.commit_loop")
     } | {"fleet.slice"}
     assert per_slice_phases <= set(names)
-    # the one traced slice, not all three; and the next slice's prefetch,
-    # which runs beside it on the worker's threads
-    assert len(names["fleet.slice"]) == 1
-    assert len(names["fleet.commit"]) == SLICE
+    # the next slice's prefetch runs beside the traced one on the worker's
+    # threads
     assert {"fleet.prepare", "fleet.fetch", "fleet.assemble"} <= set(names)
-    traced = sorted(
+    ordered = sorted(
         (s for s in timeline.spans if s.name == "fleet.slice"),
         key=lambda s: s.start,
-    )[1]
-    assert names["fleet.slice"][0] * 1e-9 == pytest.approx(
-        traced.duration, rel=0.05, abs=0.005
     )
+    traced = ordered[1]
+    # one session, open from the traced slice's start until its commit has
+    # been joined (by the slice after it, which is in the session as far as
+    # that): the traced slice whole ...
+    assert len(names["fleet.slice"]) == 2
+
+    def held(span):
+        """An annotation of the span's name and length."""
+        return any(
+            ns * 1e-9 == pytest.approx(span.duration, rel=0.05, abs=0.005)
+            for ns in names[span.name]
+        )
+
+    assert held(traced)
+    # ... and its whole commit: the loop, one commit a machine inside, the
+    # manifest, and the loop's wait for them
+    commit_loop, = [
+        s for s in timeline.spans
+        if s.name == "fleet.commit_loop" and s.parent == traced.id
+    ]
+    commits = [s for s in timeline.spans if s.parent == commit_loop.id]
+    assert [s.name for s in commits] == ["fleet.commit"] * SLICE
+    assert held(commit_loop) and all(held(s) for s in commits)
+    assert SLICE <= len(names["fleet.commit"]) <= N_MACHINES
+    wait, = [
+        s for s in timeline.spans
+        if s.name == "fleet.commit_wait" and s.attrs["slice"] == 1
+    ]
+    assert wait.parent == ordered[2].id and held(wait)
 
 
 class StopJob(BaseException):
@@ -318,6 +369,9 @@ def test_reader_reads_the_steady_slices(traced_build, recorder, name):
 
 
 def test_readers_partition_the_steady_slice(traced_build, stopped_build, recorder):
+    """What the loop's thread does in a steady slice is read whole: its
+    phases, the wait for the worker's commit of the slice before among them,
+    and its own statements between. The commit itself is the worker's."""
     from benchmarks.layer_metrics import fleet_spans
 
     def values():
@@ -338,9 +392,29 @@ def test_readers_partition_the_steady_slice(traced_build, stopped_build, recorde
             key=lambda s: s.start,
         )
         (steady,) = fleet_spans.steady_slices()
-        assert steady["seconds"] == slices[steady_index].duration
+        parent = slices[steady_index]
+        assert steady["seconds"] == parent.duration
         read = values()
         per_machine = read.pop("commit_s_per_machine")
-        assert sum(read.values()) + SLICE * per_machine == pytest.approx(
-            steady["seconds"], abs=1e-9
+        assert set(read) == set(LOOP_READERS)
+        on_loop = [
+            s for s in timeline.spans
+            if s.parent == parent.id and s.thread == parent.thread
+        ]
+        assert "fleet.commit_wait" in {s.name for s in on_loop}
+        assert sum(read.values()) == pytest.approx(
+            sum(s.duration for s in on_loop) + steady["self_s"], abs=1e-9
+        )
+        # the worker's commit begins as the slice's span ends: the sliver of
+        # it inside the span is all that the sum can miss of the slice
+        assert steady["seconds"] - 0.05 <= sum(read.values()) <= (
+            steady["seconds"] + 1e-9
+        )
+        # the commit is read off the worker's spans, under the same slice
+        commit = [
+            s for s in timeline.spans
+            if s.parent == parent.id and s.thread.startswith(COMMIT)
+        ]
+        assert SLICE * per_machine == pytest.approx(
+            sum(s.duration for s in commit), abs=1e-9
         )
