@@ -23,6 +23,7 @@ from .models import (
     MultiStepForecast,
     PatchTSTAutoEncoder,
     PatchTSTForecast,
+    MoEGQAForecast,
     MoEMLAForecast,
     KerasAutoEncoder,
     KerasLSTMAutoEncoder,
@@ -30,7 +31,7 @@ from .models import (
 )
 
 # import for the registration side effects — every factory registers its kind
-from .factories import feedforward, lstm, moe_mla, transformer  # noqa: F401
+from .factories import feedforward, lstm, moe_gqa, moe_mla, transformer  # noqa: F401
 
 __all__ = [
     "GordoBase",
@@ -44,6 +45,7 @@ __all__ = [
     "MultiStepForecast",
     "PatchTSTAutoEncoder",
     "PatchTSTForecast",
+    "MoEGQAForecast",
     "MoEMLAForecast",
     "KerasAutoEncoder",
     "KerasLSTMAutoEncoder",

@@ -494,8 +494,8 @@ class PatchTSTForecast(LSTMForecast):
 
 class MoEMLAForecast(LSTMForecast):
     """Window → the ``lookback_window`` rows that FOLLOW each of its rows: a
-    decoder over sensor values read as tokens (the ``moe_mla_decoder``
-    kind), every tag a sequence of its own. A sample reads rows ``i ..
+    decoder over sensor values read as tokens (a kind on the decoder
+    scaffold; ``moe_mla_decoder`` by default), every tag a sequence of its own. A sample reads rows ``i ..
     i+L-1`` and predicts rows ``i+1 .. i+L``; samples lie ``L`` rows apart,
     laid from the end, so every row from the first sample's second on is
     predicted once and ``predict`` returns them in order (tail-aligned, as
@@ -516,6 +516,16 @@ class MoEMLAForecast(LSTMForecast):
         params = super().get_params(deep)
         params.pop("horizon")
         return params
+
+
+class MoEGQAForecast(MoEMLAForecast):
+    """:class:`MoEMLAForecast`'s windowing, loss and ``predict`` over the
+    ``moe_gqa_decoder`` kind (grouped-query attention, window and full
+    layers mixed, softmax scores): the estimator is the scaffold's, the
+    name says which block a machine config asks for."""
+
+    def __init__(self, kind: str = "moe_gqa_decoder", **kwargs: Any):
+        super().__init__(kind, **kwargs)
 
 
 # Aliases so ported reference configs resolve (the serializer rewrites

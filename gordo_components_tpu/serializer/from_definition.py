@@ -77,6 +77,7 @@ _SHORT_NAMES: Dict[str, str] = {
         "PatchTSTAutoEncoder",
         "PatchTSTForecast",
         "MoEMLAForecast",
+        "MoEGQAForecast",
         "KerasAutoEncoder",
         "KerasLSTMAutoEncoder",
         "KerasLSTMForecast",

@@ -184,3 +184,147 @@ def test_patchtst_flash_kind_matches_dense():
     np.testing.assert_allclose(
         np.asarray(out_flash), np.asarray(out_dense), atol=5e-5
     )
+
+
+# -- the banded form: grouped key/value heads and a window ---------------------
+def _masked_dense(q, k, v, scale, window):
+    """Grouped, windowed causal attention with the whole score matrix:
+    query head ``h`` reads key head ``h // group``; row ``i`` sees ``i -
+    window < j <= i``."""
+    seq, group = q.shape[-3], q.shape[-2] // k.shape[-2]
+    k, v = (jnp.repeat(a, group, axis=-2) for a in (k, v))
+    logits = jnp.einsum("...qhd,...khd->...hqk", q, k) * scale
+    i, j = jnp.arange(seq)[:, None], jnp.arange(seq)[None, :]
+    seen = j <= i
+    if window is not None:
+        seen &= j > i - window
+    weights = jax.nn.softmax(jnp.where(seen, logits, -jnp.inf), axis=-1)
+    return jnp.einsum("...hqk,...khd->...qhd", weights, v)
+
+
+BANDED = [
+    # (sequence, window, blocks (q, k), query heads a key head)
+    (64, 16, (8, 8), 4),  # a band of three blocks of eight
+    (64, 200, (16, 16), 2),  # a window longer than the sequence
+    (96, 20, (16, 16), 1),  # a window that is no multiple of the block
+    (37, 12, (8, 16), 2),  # an odd length, unequal blocks
+    (64, None, (16, 8), 4),  # grouped heads, no window (the full layers)
+    (48, 16, (16, 16), 1),  # equal heads, the window alone
+    (40, 1, (8, 8), 2),  # a window of one: every row sees itself
+]
+
+
+@pytest.mark.parametrize(
+    "seq,window,blocks,group,operand_dtype",
+    [(*case, None) for case in BANDED]
+    # what the 8k cell's model asks for: the kernels' bodies with every cast
+    # the TPU lowers, in interpret mode, at a bfloat16 tolerance
+    + [(*BANDED[i], "bfloat16") for i in (0, 3, 4)],
+)
+def test_the_banded_kernel_is_masked_dense_attention_forward_and_every_gradient(
+    seq, window, blocks, group, operand_dtype
+):
+    rng = np.random.default_rng(31)
+    q = jnp.asarray(rng.normal(scale=0.5, size=(2, seq, 2 * group, 8)), jnp.float32)
+    k = jnp.asarray(rng.normal(scale=0.5, size=(2, seq, 2, 8)), jnp.float32)
+    v = jnp.asarray(rng.normal(scale=0.5, size=(2, seq, 2, 8)), jnp.float32)
+    g = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
+    scale = 8 ** -0.5
+    rounded = operand_dtype is not None
+    out_atol, grad_atol = (6e-3, 3e-2) if rounded else (2e-5, 5e-5)
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, scale=scale, block_q=blocks[0], block_k=blocks[1],
+            causal=True, window=window, operand_dtype=operand_dtype,
+        )
+
+    def dense(q, k, v):
+        return _masked_dense(q, k, v, scale, window)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * g)
+
+    grads = jax.grad(loss(flash), argnums=(0, 1, 2))
+    assert "pallas_call" in str(jax.make_jaxpr(flash)(q, k, v))
+    # the casts are in the traced program, forward and backward, only when
+    # the caller asks: the kernel layer reads neither backend nor global
+    assert ("bf16" in str(jax.make_jaxpr(grads)(q, k, v))) == rounded
+    out, exact = np.asarray(flash(q, k, v)), np.asarray(dense(q, k, v))
+    assert out.dtype == np.float32
+    np.testing.assert_allclose(out, exact, atol=out_atol)
+    assert (np.abs(out - exact).max() > 1e-4) == rounded  # rounded as bfloat16 rounds
+    ref = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(grads(q, k, v), ref, "qkv"):
+        # a key head's, summed over its query heads; float32 whatever the tiles
+        assert a.shape == b.shape and a.dtype == jnp.float32
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=grad_atol, err_msg=f"d{name}"
+        )
+
+
+@pytest.mark.parametrize("seq,window,blocks,group", BANDED)
+def test_the_visit_counter_is_the_bands_block_count(seq, window, blocks, group):
+    """``visited_blocks`` against the tiles that hold a visible pair,
+    counted from the mask itself: forward once, backward twice (``dq`` by
+    query block, ``dk/dv`` by key block)."""
+    import math
+
+    from gordo_components_tpu.ops.flash_attention import visited_blocks
+
+    bq, bk = blocks
+    s_pad = -(-seq // math.lcm(bq, bk)) * math.lcm(bq, bk)
+    i, j = np.arange(s_pad)[:, None], np.arange(s_pad)[None, :]
+    seen = (j <= i) & ((j > i - window) if window is not None else True)
+    tiles = seen.reshape(s_pad // bq, bq, s_pad // bk, bk).any(axis=(1, 3))
+    forward, backward = visited_blocks(seq, bq, bk, window)
+    assert forward == tiles.sum() and backward == 2 * tiles.sum()
+    if window is not None and window < seq // 2:
+        assert forward < visited_blocks(seq, bq, bk, None)[0]
+
+
+def test_blocks_the_window_hides_are_neither_computed_nor_fetched():
+    """What lies outside a block's band is poisoned: a kernel that only
+    masked it would carry the poison into the result (0 · NaN), forward
+    (values in key blocks before the band) and backward (``dk``, ``dv`` of
+    a key block whose band ends before the poisoned rows of the cotangent;
+    ``dq`` of rows whose band begins after the poisoned keys)."""
+    seq, window, block = 64, 16, 8
+    rng = np.random.default_rng(5)
+    q, k, v = (
+        jnp.asarray(rng.normal(scale=0.5, size=(1, seq, heads, 8)), jnp.float32)
+        for heads in (4, 2, 2)
+    )
+
+    def flash(q, k, v):
+        return flash_attention(
+            q, k, v, block_q=block, block_k=block, causal=True, window=window
+        )
+
+    # rows 40.. see keys 25..: the first three key blocks are outside
+    poisoned_v = v.at[:, :24].set(jnp.nan)
+    out = flash(q, k, poisoned_v)
+    assert np.all(np.isfinite(np.asarray(out[:, 40:])))
+    assert not np.all(np.isfinite(np.asarray(out[:, :24])))
+    # a cotangent poisoned from row 40 on: keys 0..23 are seen by rows
+    # 0..38 alone
+    _, pull = jax.vjp(flash, q, k, v)
+    g = jnp.ones_like(out).at[:, 40:].set(jnp.nan)
+    dq, dk, dv = pull(g)
+    assert np.all(np.isfinite(np.asarray(dk[:, :24])))
+    assert np.all(np.isfinite(np.asarray(dv[:, :24])))
+    assert np.all(np.isfinite(np.asarray(dq[:, :40])))
+    # and dq of rows 40.. never reads the keys before their band
+    _, pull = jax.vjp(flash, q, k.at[:, :24].set(jnp.nan), poisoned_v)
+    dq, _, _ = pull(jnp.ones_like(out).at[:, :40].set(0.0))
+    assert np.all(np.isfinite(np.asarray(dq[:, 40:])))
+
+
+def test_a_window_without_causal_or_heads_that_do_not_divide_are_refused():
+    q, k, v = _qkv((1, 32, 4, 8), seed=3)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, window=8)
+    with pytest.raises(ValueError, match="divide"):
+        flash_attention(q, k[:, :, :3], v[:, :, :3], causal=True)
+    with pytest.raises(ValueError, match="operand_dtype"):
+        flash_attention(q, k, v, causal=True, operand_dtype="bfloat16")

@@ -125,7 +125,7 @@ def test_the_gradient_of_every_leaf_is_the_references(both):
 
 
 def _layer_inputs(n_tokens=48, seed=3):
-    from gordo_components_tpu.models.factories import moe_mla
+    from gordo_components_tpu.models.factories import decoder as moe_mla
 
     D, I, E = 64, 32, 8
     keys = jax.random.split(jax.random.PRNGKey(seed), 8)

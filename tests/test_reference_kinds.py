@@ -6,7 +6,7 @@
   blocks of their own);
 * the kinds' contract, run whole on a kind that brings every optional
   function: the stand-in ``tokens`` kind that lives with the benchmark's
-  tests, and ``moe_mla`` at small widths. Through ``make_build``, ``anomaly``,
+  tests, and ``moe_mla`` and ``moe_gqa`` at small widths. Through ``make_build``, ``anomaly``,
   ``slice_counts`` and ``compare.machine_numbers``, with a planted fault read
   as one.
 """
@@ -17,6 +17,7 @@ import sys
 import numpy as np
 import pytest
 
+from test_moe_gqa import SMALL as SMALL_GQA
 from test_moe_mla import SMALL
 
 TOKENS = {
@@ -24,7 +25,10 @@ TOKENS = {
     "aux_weight": 0.3, "epochs": 1, "batch_size": 4, "micro_batch": 2,
     "n_splits": 1, "learning_rate": 3e-3,
 }
-KINDS = {"tokens": TOKENS, "moe_mla": {**SMALL, "n_splits": 1}}
+KINDS = {
+    "tokens": TOKENS, "moe_mla": {**SMALL, "n_splits": 1},
+    "moe_gqa": {**SMALL_GQA, "n_splits": 1},
+}
 TAGS, N_ROWS, N_REAL = 3, 160, 150
 
 
@@ -132,3 +136,30 @@ def test_moe_mla_counts_no_more_than_its_forward_pass_multiplies():
     dense_passes = 2 * (2.0 * tokens * 3 * 64 * 32)
     assert kind.expert_ffn_flops(model, tokens) == dense_passes / 4
     assert models.train_flops(model, TAGS) > 3.0 * total
+
+
+def test_moe_gqa_counts_the_pairs_inside_the_band_and_no_more_than_it_multiplies():
+    """Its count takes a window layer's attention at the pairs the window
+    leaves (row ``i`` sees ``min(i + 1, W)`` keys), a full layer's at its
+    causal mean, and the experts at their expected slots, where its plain
+    forward pass multiplies whole blocks of scores over all keys and every
+    held expert over every token: under the jaxpr's products, never over."""
+    from benchmarks.reference import models
+    from benchmarks.tests.test_flops_bytes import product_flops_a_sample
+
+    model = KINDS["moe_gqa"]
+    kind = models.for_kind(model)
+    multiplied = product_flops_a_sample(kind, model, TAGS)
+    total = kind.forward_flops(model, TAGS)["total"]
+    assert 0.4 * multiplied <= total <= multiplied, (total, multiplied)
+    # a window of 6 over 16 rows: 1 + 2 + ... + 6, then ten rows of 6
+    assert kind.attention_pairs(model, "sliding_attention") == 21 + 60
+    assert kind.attention_pairs(model, "full_attention") == 16 * 17 / 2
+    a_pair = 4.0 * 8 * 16  # scores and mixing, 8 query heads of 16
+    assert kind.attention_flops(model, TAGS) == TAGS * a_pair * (4 * 81 + 2 * 136)
+    # q and the output a query head, k and v a key head, float32, six layers
+    assert kind.attention_bytes(model, TAGS) == TAGS * 6 * 4.0 * (2 * 8 + 2 * 2) * 16 * 16
+    # 2 of 8 experts held and 2 chosen a token: half a slot a token expected
+    tokens = 16 * TAGS
+    assert kind.expert_ffn_flops(model, tokens) == 0.5 * (2.0 * tokens * 3 * 64 * 32)
+    assert models.train_flops(model, TAGS) == 3.0 * total
