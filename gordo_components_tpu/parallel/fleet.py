@@ -130,7 +130,9 @@ class MachineResult(NamedTuple):
     tag_thresholds: jnp.ndarray  # (T,) 99th pct of scaled residuals
     total_threshold: jnp.ndarray  # () 99th pct of residual L2 norms
     # what the model's own loss counted over the final fit's steps (a dict of
-    # arrays; empty for an elementwise loss): span attributes of the slice
+    # arrays; empty for an elementwise loss), and where the fits run in
+    # sequence the samples they predicted (``predicted_samples`` of
+    # ``predictable_samples``): span attributes of the slice
     counters: Any = None
 
 
@@ -385,6 +387,8 @@ def make_machine_program(
         if la is None:
             fit_local = fit_fn
             predict_all = lambda params: predict_fn(params, inputs)  # noqa: E731
+            # a flat sample is one row: its chunk is the whole set
+            predict_chunk, predict_width = predict_fn, padded
         else:
 
             def windowed_apply(variables, starts, **kwargs):
@@ -396,7 +400,7 @@ def make_machine_program(
                 )
 
             fit_local = make_fit_fn(windowed_apply, spec.optimizer, **fit_kwargs)
-            windowed_predict = make_predict_fn(windowed_apply)
+            predict_chunk = make_predict_fn(windowed_apply)
 
             # prediction has no optimizer state or backward pass, so its
             # chunks can be wider than the training batch: up to 4 training
@@ -421,9 +425,37 @@ def make_machine_program(
                 # so peak HBM per machine stays one (width, L, F) gather
                 chunks = inputs.reshape(-1, predict_width)
                 preds = jax.lax.map(
-                    lambda sb: windowed_predict(params, sb), chunks
+                    lambda sb: predict_chunk(params, sb), chunks
                 )
                 return preds.reshape(padded * R, n_targets)
+
+        def predict_at(params, wanted):
+            """Predictions at the samples where ``wanted > 0``, as rows
+            ``(padded·R, T)``, zero at every other row: ONE loop over chunks
+            of ``predict_width`` of them (the wanted first, in index order)
+            whose trip count is the chunks they fill, so a fit that wants
+            none runs none. The forward is in the graph once: a conditional
+            around a second one would double an executable that has to fit
+            the machines' compile cache (``PERF.md`` §7 item 13)."""
+            want = wanted > 0
+            count = jnp.sum(want)
+            order = jnp.argsort(jnp.logical_not(want), stable=True)
+
+            def chunk(i, out):
+                at = i * predict_width + jnp.arange(predict_width)
+                samples = order[at]
+                pred = predict_chunk(params, inputs[samples])
+                # the last chunk's tail past the wanted samples is dropped
+                samples = jnp.where(at < count, samples, padded)
+                return out.at[samples].set(
+                    pred.reshape(predict_width, R, n_targets), mode="drop"
+                )
+
+            out = jax.lax.fori_loop(
+                0, (count + predict_width - 1) // predict_width, chunk,
+                jnp.zeros((padded, R, n_targets)),
+            )
+            return out.reshape(padded * R, n_targets)
 
         keys = jax.random.split(key, spec.n_splits + 2)
         init_key, fit_key, fold_keys = keys[0], keys[1], keys[2:]
@@ -444,7 +476,8 @@ def make_machine_program(
         # test region is nonempty; machines too short for any fold
         # (n_real < n_splits+1) get empty test masks here and fall back
         # to final-model residuals below
-        fold_test_masks = per_row(test_masks * wt[None, :])  # (K, rows)
+        sample_test_masks = test_masks * wt[None, :]  # (K, samples)
+        fold_test_masks = per_row(sample_test_masks)  # (K, rows)
         if not sequential_fits(spec):
             # parallel CV: the K fold fits and the final fit are independent
             # programs with identical shapes, so ONE vmapped fit of K+1
@@ -486,9 +519,16 @@ def make_machine_program(
             # The final fit runs last and leaves the machine's parameters
             # there.
 
+            # A fit predicts only the samples its result reads: a fold its
+            # own test samples, the final fit every real sample where no
+            # fold tested any (the fallback below) and none otherwise; the
+            # rows left at zero are rows that no mask below reads
+            fallback = wt * (jnp.sum(sample_test_masks) == 0)
+            predict_masks = jnp.concatenate([sample_test_masks, fallback[None]])
+
             def one_fit(carry, xs):
                 (params, opt_state), emin, emax = carry
-                weights, wtest, fit_key_, first = xs
+                weights, wtest, wpredict, fit_key_, first = xs
                 fresh = draw(init_key)
                 reset = lambda new, old: jnp.where(first, old, new)  # noqa: E731
                 params = jax.tree_util.tree_map(reset, fresh, params)
@@ -500,7 +540,7 @@ def make_machine_program(
                     opt_state=opt_state,
                 )
                 with jax.named_scope("cv_predict"):
-                    pred = predict_all(res.params)
+                    pred = predict_at(res.params, wpredict)
                 pred_raw = (pred - sy.offset) / sy.scale
                 err = jnp.abs(raw_targets - pred_raw)
                 mask = (wtest > 0)[:, None]
@@ -525,12 +565,12 @@ def make_machine_program(
                     (state, emin, emax),
                     (
                         all_w,
-                        # the final fit tests nothing: its residuals are the
-                        # fallback below, never the error scaler's while a
-                        # fold covered the machine
+                        # the final fit tests nothing, and predicts nothing
+                        # unless its residuals are the fallback below
                         jnp.concatenate(
                             [fold_test_masks, jnp.zeros((1, n_points))]
                         ),
+                        predict_masks,
                         all_keys,
                         jnp.arange(n_fits) == 0,
                     ),
@@ -540,7 +580,13 @@ def make_machine_program(
             final = FitResult(
                 params=state[0],
                 loss_history=histories[-1],
-                counters=jax.tree_util.tree_map(lambda c: c[-1], counters),
+                counters={
+                    **jax.tree_util.tree_map(lambda c: c[-1], counters),
+                    # the sample passes the K+1 fits predicted, of those
+                    # a prediction of every padded sample in each would be
+                    "predicted_samples": jnp.sum(predict_masks > 0),
+                    "predictable_samples": jnp.asarray(n_fits * padded),
+                },
             )
             state_out = state[1]
         err_final = errs[-1]

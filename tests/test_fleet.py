@@ -174,34 +174,17 @@ def test_cv_parallel_matches_scan():
             )
 
 
+LSTM_CONFIG = _definition(
+    "LSTMAutoEncoder", kind="lstm_symmetric", lookback_window=8, dims=[8],
+    epochs=2, batch_size=16,
+)
+
+
 def test_cv_parallel_windowed_matches_scan():
     """Same parity through the windowed (LSTM) path, whose predict side
     runs lax.map chunks under the fold vmap."""
-    lstm_config = {
-        "DiffBasedAnomalyDetector": {
-            "base_estimator": {
-                "TransformedTargetRegressor": {
-                    "regressor": {
-                        "Pipeline": {
-                            "steps": [
-                                "MinMaxScaler",
-                                {"LSTMAutoEncoder": {
-                                    "kind": "lstm_symmetric",
-                                    "lookback_window": 8,
-                                    "dims": [8],
-                                    "epochs": 2,
-                                    "batch_size": 16,
-                                }},
-                            ]
-                        }
-                    },
-                    "transformer": "MinMaxScaler",
-                }
-            }
-        }
-    }
     spec, batch = _make_spec_and_batch(
-        2, n_rows=96, model_config=lstm_config, n_splits=2
+        2, n_rows=96, model_config=LSTM_CONFIG, n_splits=2
     )
     assert not spec.memory_constrained
     fast = train_fleet_arrays(spec, batch)
@@ -214,6 +197,119 @@ def test_cv_parallel_windowed_matches_scan():
             np.testing.assert_allclose(
                 np.asarray(la), np.asarray(lb), rtol=2e-4, atol=1e-5,
                 err_msg=f"cv_parallel vs scan mismatch in {name}",
+            )
+
+
+RESULT_READS = ("cv_scores", "error_scaler", "tag_thresholds", "total_threshold")
+
+
+def _windowed_case(case, n_splits=2):
+    """(spec, batch) of LSTM machines of 96 rows (89 windows of 8, padded to
+    96 samples; 16 a batch). ``fallback``: a second machine with 9 real rows,
+    2 real windows, fewer than ``n_splits + 1``; ``holes``: rows 80-81 of the
+    first machine weigh nothing, so the 9 windows over them drop out of the
+    last fold's test region and cut it in two (80 real samples)."""
+    n_machines = 1 if case == "one_machine" else 2
+    spec, batch = _make_spec_and_batch(
+        n_machines, n_rows=96, model_config=LSTM_CONFIG, n_splits=n_splits
+    )
+    w = batch.w.copy()
+    if case == "fallback":
+        w[1, :87] = 0.0
+    if case in ("holes", "counted"):
+        w[0, 80:82] = 0.0
+    if case == "counted":
+        w[1, :87] = 0.0
+    return spec, batch._replace(w=w)
+
+
+@pytest.mark.parametrize("case", ["one_machine", "fallback", "holes"])
+def test_sequential_fits_read_what_the_vmapped_fits_read(case):
+    """The sequential fold mode predicts only what its result reads (a fold
+    its test samples; the final fit nothing while a fold covers the machine,
+    every real sample where none does) and its result is the vmapped mode's,
+    which predicts every sample in every fit: on a slice of ONE machine (the
+    unbatched predict loop), beside a machine that falls back to the final
+    fit's residuals (the loop under a vmap, to the longer machine's trip
+    count), and with a test region that is not contiguous in index space."""
+    spec, batch = _windowed_case(case)
+    assert not spec.memory_constrained
+    fast = train_fleet_arrays(spec, batch)
+    slow = train_fleet_arrays(spec._replace(memory_constrained=True), batch)
+    for name in RESULT_READS:
+        for la, lb in zip(
+            jax.tree_util.tree_leaves(getattr(fast, name)),
+            jax.tree_util.tree_leaves(getattr(slow, name)),
+        ):
+            np.testing.assert_allclose(
+                np.asarray(la), np.asarray(lb), rtol=2e-4, atol=1e-5,
+                err_msg=f"vmapped vs sequential mismatch in {name} ({case})",
+            )
+    if case == "fallback":
+        assert not np.isfinite(np.asarray(slow.cv_scores[1])).any()
+        assert np.isfinite(np.asarray(slow.tag_thresholds[1])).all()
+        assert float(slow.total_threshold[1]) > 0
+
+
+def test_sequential_fits_count_the_samples_they_predict():
+    """``predicted_samples``: a covered machine's folds each predict their
+    test samples, ``n_real // (K+1)``, and its final fit none; a machine no
+    fold covers predicts its real samples in the final fit alone.
+    ``predictable_samples``: (K+1) x padded samples. The vmapped mode counts
+    neither."""
+    spec, batch = _windowed_case("counted")
+    slow = train_fleet_arrays(spec._replace(memory_constrained=True), batch)
+    # machine 0: 80 real samples, 2 folds of 80 // 3; machine 1: 2 real
+    assert np.asarray(slow.counters["predicted_samples"]).tolist() == [
+        2 * (80 // 3), 2
+    ]
+    assert np.asarray(slow.counters["predictable_samples"]).tolist() == [
+        3 * 96, 3 * 96
+    ]
+    assert train_fleet_arrays(spec, batch).counters == {}
+
+
+@pytest.mark.parametrize("constrained", [False, True])
+def test_sequential_fits_without_folds_predict_every_real_sample(constrained):
+    """``n_splits`` 0: the final fit predicts every real sample, and its
+    residuals set the error scaler and the thresholds as a plain forward of
+    the machine's own parameters over all of its windows reads them."""
+    from gordo_components_tpu.ops import windowing
+
+    spec, batch = _windowed_case("holes", n_splits=0)
+    spec = spec._replace(memory_constrained=constrained)
+    result = train_fleet_arrays(spec, batch)
+    L, la, n_rows = spec.lookback_window, spec.lookahead, batch.X.shape[1]
+    sx, sy = (
+        jax.tree_util.tree_map(lambda a: np.asarray(a[0]), s)
+        for s in (result.input_scaler, result.target_scaler)
+    )
+    starts = windowing.window_starts(n_rows, L, la)
+    windows = (batch.X[0] * sx.scale + sx.offset)[starts[:, None] + np.arange(L)]
+    params = jax.tree_util.tree_map(lambda a: a[0], result.params)
+    pred = np.asarray(
+        spec.module.apply({"params": params}, windows, deterministic=True)
+    )
+    target = windowing.window_output_index(n_rows, L, la)
+    real = batch.w[0][starts[:, None] + np.arange(L)].min(axis=1) > 0
+    real &= batch.w[0][target] > 0
+    err = np.abs(batch.y[0][target] - (pred - sy.offset) / sy.scale)[real]
+    lo, hi = err.min(axis=0), err.max(axis=0)
+    scaled = (err - lo) / (hi - lo)
+    expected = {
+        "error_scaler": (1 / (hi - lo), -lo / (hi - lo)),
+        "tag_thresholds": np.percentile(scaled, 99, axis=0),
+        "total_threshold": np.percentile(np.linalg.norm(scaled, axis=1), 99),
+    }
+    # every real sample, once: the machine with holes, and its whole twin
+    assert np.asarray(result.counters["predicted_samples"]).tolist() == [80, 89]
+    for name, want in expected.items():
+        for got, one in zip(
+            jax.tree_util.tree_leaves(getattr(result, name)),
+            jax.tree_util.tree_leaves(want),
+        ):
+            np.testing.assert_allclose(
+                np.asarray(got)[0], one, rtol=1e-3, atol=1e-5, err_msg=name
             )
 
 

@@ -368,6 +368,18 @@ def test_reader_reads_the_steady_slices(traced_build, recorder, name):
     assert isinstance(value, float) and value >= 0.0
 
 
+def test_vmapped_folds_count_no_predicted_samples(traced_build, recorder):
+    """``predicted_samples_pct`` reads a program whose fits run in sequence:
+    the vmapped folds' slices carry no such count, so the reader finds
+    nothing, as it finds nothing on a program from before the count."""
+    from benchmarks.layer_metrics import predicted_samples_pct
+
+    recorder.record(traced_build[0])
+    slices = [s for s in traced_build[0].spans if s.name == "fleet.slice"]
+    assert slices and all("predicted_samples" not in s.attrs for s in slices)
+    assert predicted_samples_pct.read({}) is None
+
+
 def test_readers_partition_the_steady_slice(traced_build, stopped_build, recorder):
     """What the loop's thread does in a steady slice is read whole: its
     phases, the wait for the worker's commit of the slice before among them,

@@ -343,3 +343,13 @@ def test_one_machine_a_slice_through_fleet_build_store_and_serializer(tmp_path):
     from benchmarks.layer_metrics import attention_key_blocks_visited_pct
 
     assert attention_key_blocks_visited_pct.read({}) == 100.0
+    # the folds predict their test samples, the final fit none: the reader
+    # takes the share off the steady (second) slice's span
+    from benchmarks.layer_metrics import predicted_samples_pct
+
+    steady = slices[1].attrs
+    predicted = steady["predicted_samples"][0]
+    assert 0 < predicted < steady["predictable_samples"][0]
+    assert predicted_samples_pct.read({}) == pytest.approx(
+        100.0 * predicted / steady["predictable_samples"][0]
+    )
