@@ -23,6 +23,7 @@ from .models import (
     MultiStepForecast,
     PatchTSTAutoEncoder,
     PatchTSTForecast,
+    AfMoEForecast,
     MoEGQAForecast,
     MoEMLAForecast,
     KerasAutoEncoder,
@@ -31,7 +32,7 @@ from .models import (
 )
 
 # import for the registration side effects — every factory registers its kind
-from .factories import feedforward, lstm, moe_gqa, moe_mla, transformer  # noqa: F401
+from .factories import afmoe, feedforward, lstm, moe_gqa, moe_mla, transformer  # noqa: F401
 
 __all__ = [
     "GordoBase",
@@ -45,6 +46,7 @@ __all__ = [
     "MultiStepForecast",
     "PatchTSTAutoEncoder",
     "PatchTSTForecast",
+    "AfMoEForecast",
     "MoEGQAForecast",
     "MoEMLAForecast",
     "KerasAutoEncoder",

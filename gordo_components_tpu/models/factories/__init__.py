@@ -1,4 +1,4 @@
 from .spec import ModelSpec, make_optimizer
-from . import feedforward, lstm, moe_gqa, moe_mla, transformer  # noqa: F401 — registration side effects
+from . import afmoe, feedforward, lstm, moe_gqa, moe_mla, transformer  # noqa: F401 — registration side effects
 
-__all__ = ["ModelSpec", "make_optimizer", "feedforward", "lstm", "moe_gqa", "moe_mla", "transformer"]
+__all__ = ["ModelSpec", "make_optimizer", "afmoe", "feedforward", "lstm", "moe_gqa", "moe_mla", "transformer"]

@@ -11,13 +11,17 @@ error scaler and thresholds stay the stock ones.
 ``_sequences``, ``_over_vocabulary``, the expected bin centre), the blocks'
 bounds on memory (``_per_sequence``, ``_recomputed``), one layer (attention,
 then a dense feed-forward or the expert layer, a shared expert where the
-layer's parameters hold one) and the next row's loss. A kind brings its
+layer's parameters hold one, each sub-block's output normed where they hold
+a norm for it) and the next row's loss. A kind brings its
 attention block (``_attention``), its scoring function (``_route``) and
 its stacks of layers (``setup``, ``_trunk``): ``moe_mla.py`` (latent
 attention, sigmoid scores with a selection bias, leading dense layers, a
-shared expert, a prediction module) and ``moe_gqa.py`` (grouped-query
+shared expert, a prediction module), ``moe_gqa.py`` (grouped-query
 attention with sliding-window and full layers mixed by period, softmax
-scores, none of the three).
+scores, none of the three) and ``afmoe.py`` (``moe_gqa.py``'s attention with
+an output gate and per-head query/key norms, rotary in the window layers
+alone, sandwich norms, sigmoid scores with a selection bias, leading dense
+layers, a shared expert).
 
 **The expert layer's contract.** The layer is told which experts it holds
 (``experts_held``, ids among ``n_routed_experts``: one chip's share when a
@@ -284,7 +288,7 @@ class TokenDecoder(nn.Module):
 
     def _experts(self, p, x):
         """All the tokens ``(S, L, D)`` together (the slots are sorted over
-        them), their norm first: ``(given, token-slots a held expert)``."""
+        them), their norm first: ``(given, what the layer counts)``."""
         S, L, D = x.shape
         tokens = rms_norm(x, p["ffn_norm"], self.rms_norm_eps).reshape(S * L, D)
         if "shared_gate" in p:
@@ -298,23 +302,39 @@ class TokenDecoder(nn.Module):
         )
         if "shared_gate" in p:
             partial = out + partial
-        return partial.reshape(S, L, D), sizes
+        partial = self._normed_out(p, "post_ffn_norm", partial)
+        return partial.reshape(S, L, D), self._counted(chosen, sizes)
+
+    def _counted(self, chosen, sizes):
+        """What an expert layer counts, from the experts its tokens chose
+        ``(T, k)`` and the token-slots a held expert received ``(E,)``: those
+        slots, unless a kind counts more."""
+        return sizes
+
+    def _normed_out(self, p, name, y):
+        """A sub-block's output, normed by ``p[name]`` where the layer holds
+        such a norm (``post_attn_norm``, ``post_ffn_norm``), else as it is."""
+        return rms_norm(y, p[name], self.rms_norm_eps) if name in p else y
 
     def _layer(self, p, x, **how):
-        """``x (S, L, D) → (x, token-slots a held expert (E,))``; ``how`` goes
+        """``x (S, L, D) → (x, what the expert layer counted)``; ``how`` goes
         to the kind's attention. Where the model recomputes, the layer's
         input and its attention's output are what the backward pass keeps of
         it."""
-        x = x + self._per_sequence(lambda x_s: self._attention(p, x_s, **how), x)
+        x = x + self._per_sequence(
+            lambda x_s: self._normed_out(
+                p, "post_attn_norm", self._attention(p, x_s, **how)
+            ), x,
+        )
         if "w_gate" in p:
             return x + self._per_sequence(
-                lambda x_s: swiglu(
+                lambda x_s: self._normed_out(p, "post_ffn_norm", swiglu(
                     rms_norm(x_s, p["ffn_norm"], self.rms_norm_eps),
                     p["w_gate"], p["w_up"], p["w_down"],
-                ), x,
+                )), x,
             ), None
-        given, sizes = self._recomputed(self._experts)(p, x)
-        return x + given, sizes
+        given, counted = self._recomputed(self._experts)(p, x)
+        return x + given, counted
 
     # -- the token front and back end -----------------------------------------
     def _over_vocabulary(self, norm, read, h, *rest):

@@ -119,6 +119,12 @@ def period_runs(layer_types: Sequence[str]) -> Tuple[int, List[Tuple[str, int]]]
     return n // length, runs
 
 
+def by_layer(counts):
+    """What periods counted, ``(periods, layers of a period, ...)`` a leaf,
+    as ``(layers, ...)`` in the layers' order."""
+    return jax.tree_util.tree_map(lambda c: c.reshape(-1, c.shape[-1]), counts)
+
+
 def _draw_periods(key, shapes, n_periods: int, runs):
     return jax.lax.map(
         lambda k: {
@@ -136,21 +142,27 @@ class MoEGQADecoder(TokenDecoder):
     layer_types: Tuple[str, ...]
     sliding_window: int
     # ``rope_parameters`` as ((kind, ((key, value), ...)), ...): the module is
-    # part of the fleet program's memo key, so every field is hashable
+    # part of the fleet program's memo key, so every field is hashable; a
+    # layer kind it leaves out is not turned
     rope_parameters: Tuple[Tuple[str, Tuple[Tuple[str, Any], ...]], ...]
     n_heads: int
     n_kv_heads: int
     head_dim: int
     attention_operand_dtype: Optional[str] = None
 
-    def _layer_shapes(self) -> Dict[str, Tuple[int, ...]]:
+    # a layer's attention block runs under ``<_SCOPE>/sliding`` or ``/full``
+    _SCOPE = "gqa_attention"
+
+    def _attention_shapes(self) -> Dict[str, Tuple[int, ...]]:
         D, d = self.hidden_size, self.head_dim
         return {
             "attn_norm": (D,), "wq": (D, self.n_heads * d),
             "wk": (D, self.n_kv_heads * d), "wv": (D, self.n_kv_heads * d),
             "wo": (self.n_heads * d, D), "ffn_norm": (D,),
-            **self._expert_shapes(),
         }
+
+    def _layer_shapes(self) -> Dict[str, Tuple[int, ...]]:
+        return {**self._attention_shapes(), **self._expert_shapes()}
 
     def setup(self):
         self._token_ends()
@@ -162,33 +174,49 @@ class MoEGQADecoder(TokenDecoder):
     def _blocks(self, length: int) -> int:
         return min(_BLOCK, -(-length // 128) * 128) if length > 128 else -(-length // 8) * 8
 
+    def _heads(self, p, name: str, x, n_heads: int, kind: str):
+        """Queries (``name`` "q") or keys ("k") ``(L, n_heads, head_dim)`` of
+        the normed sequence ``x``: each head normed where the layer holds
+        ``<name>_norm``, turned where the layer kind has rotary parameters."""
+        d = self.head_dim
+        heads = (x @ p[f"w{name}"]).reshape(x.shape[0], n_heads, d)
+        if f"{name}_norm" in p:
+            with jax.named_scope("qk_norm"):
+                heads = rms_norm(heads, p[f"{name}_norm"], self.rms_norm_eps)
+        rope = dict(self.rope_parameters).get(kind)
+        if rope is None:
+            return heads
+        return rotary_halves(heads, *rotary_frequencies(d, dict(rope)))
+
     def _attention(self, p, x, kind: str):
-        """One sequence ``(L, D)``, its norm first."""
+        """One sequence ``(L, D)``, its norm first; the heads' output gated
+        by ``sigmoid(x W_g)`` where the layer holds ``attn_gate``."""
         L, d = x.shape[0], self.head_dim
-        inv_freq, factor = rotary_frequencies(d, dict(dict(self.rope_parameters)[kind]))
         block = self._blocks(L)
-        with jax.named_scope(f"gqa_attention/{kind.split('_')[0]}"):
+        with jax.named_scope(f"{self._SCOPE}/{kind.split('_')[0]}"):
             x = rms_norm(x, p["attn_norm"], self.rms_norm_eps)
-            q = rotary_halves((x @ p["wq"]).reshape(L, self.n_heads, d), inv_freq, factor)
-            k = rotary_halves((x @ p["wk"]).reshape(L, self.n_kv_heads, d), inv_freq, factor)
+            q = self._heads(p, "q", x, self.n_heads, kind)
+            k = self._heads(p, "k", x, self.n_kv_heads, kind)
             v = (x @ p["wv"]).reshape(L, self.n_kv_heads, d)
             mixed = flash_attention(
                 q, k, v, scale=d ** -0.5, block_q=block, block_k=block, causal=True,
                 window=self.sliding_window if kind == SLIDING else None,
                 operand_dtype=self.attention_operand_dtype,
-            )
-            return mixed.reshape(L, self.n_heads * d) @ p["wo"]
+            ).reshape(L, self.n_heads * d)
+            if "attn_gate" in p:
+                with jax.named_scope("attention_gate"):
+                    mixed = mixed * jax.nn.sigmoid(x @ p["attn_gate"])
+            return mixed @ p["wo"]
 
     def _route(self, p, tokens):
         return route(
             tokens, p["router"], None, self.experts_per_token, 1.0, "softmax"
         )
 
-    def _trunk(self, ids):
-        """``ids (S, L)`` → the last layer's output, before its norm, and the
-        layers' token-slot counts ``(layers, E)``, in the layers' order."""
-        _, runs = period_runs(self.layer_types)
-
+    def _through(self, x, periods, runs):
+        """``x`` through stacked periods (``_draw_periods``' tree, cut into
+        ``runs``): ``(x, what the layers counted, (periods, layers of a
+        period, ...); None where no layer counts)``."""
         def period(x, stacks):
             counted = []
             for j, (kind, _) in enumerate(runs):
@@ -196,10 +224,17 @@ class MoEGQADecoder(TokenDecoder):
                     lambda x, p: self._layer(p, x, kind=kind), x, stacks[f"{j}_{kind}"]
                 )
                 counted.append(sizes)
-            return x, jnp.concatenate(counted)
+            return x, jax.tree_util.tree_map(lambda *c: jnp.concatenate(c), *counted)
 
-        x, counts = jax.lax.scan(period, self.embed[ids], self.periods)
-        return x, counts.reshape(-1, counts.shape[-1])
+        return jax.lax.scan(period, x, periods)
+
+    def _trunk(self, ids):
+        """``ids (S, L)`` → the last layer's output, before its norm, and the
+        layers' token-slot counts ``(layers, E)``, in the layers' order."""
+        x, counts = self._through(
+            self.embed[ids], self.periods, period_runs(self.layer_types)[1]
+        )
+        return x, by_layer(counts)
 
     def attention_key_blocks(self, length: int, n_sequences: int):
         """``(2, 2, 2)``: a layer kind (sliding, full) x (forward, backward)
@@ -252,10 +287,38 @@ def moe_gqa_decoder(
     **unknown: Any,
 ) -> ModelSpec:
     _reject_unknown("moe_gqa_decoder", unknown)
+    default_rope = {"rope_type": "default", "rope_theta": 10000.0}
+    ropes = {
+        kind: dict((rope_parameters or {}).get(kind, default_rope))
+        for kind in (SLIDING, FULL)
+    }
+    fields, config = gqa_arguments(
+        "moe_gqa_decoder", n_features, n_features_out, lookback_window, vocab_size,
+        hidden_size, layer_types, sliding_window, ropes, n_heads, n_kv_heads,
+        head_dim, moe_intermediate_size, n_routed_experts, experts_held,
+        experts_per_token, rms_norm_eps, attention_operand_dtype,
+    )
+    return decoder_spec(
+        MoEGQADecoder(**fields, remat=remat), config, optimizer, optimizer_kwargs
+    )
+
+
+def gqa_arguments(
+    name: str, n_features: int, n_features_out: Optional[int], lookback_window: int,
+    vocab_size: int, hidden_size: int, layer_types: Sequence[str],
+    sliding_window: int, ropes: Dict[str, Dict[str, Any]], n_heads: int,
+    n_kv_heads: int, head_dim: int, moe_intermediate_size: int,
+    n_routed_experts: int, experts_held: Optional[Sequence[int]],
+    experts_per_token: int, rms_norm_eps: float,
+    attention_operand_dtype: Optional[str],
+) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+    """``(the module's fields, the spec's config)`` of the arguments every
+    grouped-query kind takes, checked: the factory ``name`` refuses what no
+    module of it can be."""
     rms_norm_eps = float(rms_norm_eps)  # a YAML machine config reads "1e-06" as text
     if n_features_out not in (None, n_features):
         raise ValueError(
-            "moe_gqa_decoder predicts each tag's own next rows: "
+            f"{name} predicts each tag's own next rows: "
             f"{n_features_out} targets for {n_features} tags"
         )
     layer_types = tuple(str(kind) for kind in layer_types)
@@ -279,11 +342,6 @@ def moe_gqa_decoder(
         )
     if attention_operand_dtype is not None:
         attention_operand_dtype = jnp.dtype(attention_operand_dtype).name
-    default_rope = {"rope_type": "default", "rope_theta": 10000.0}
-    ropes = {
-        kind: dict((rope_parameters or {}).get(kind, default_rope))
-        for kind in (SLIDING, FULL)
-    }
     for rope in ropes.values():
         rotary_frequencies(head_dim, rope)  # an unknown rope_type is refused here
     config = {
@@ -295,26 +353,33 @@ def moe_gqa_decoder(
         "n_routed_experts": n_routed_experts, "experts_held": list(held),
         "experts_per_token": experts_per_token, "rms_norm_eps": rms_norm_eps,
         "attention_operand_dtype": attention_operand_dtype,
-        "optimizer": optimizer,
-        "optimizer_kwargs": dict(optimizer_kwargs or {}),
-        "loss": MODULE_LOSS, "remat": remat,
     }
-    module = MoEGQADecoder(
+    fields = dict(
         vocab_size=vocab_size, hidden_size=hidden_size,
         moe_intermediate_size=moe_intermediate_size,
         n_routed_experts=n_routed_experts, experts_held=held,
         experts_per_token=experts_per_token, rms_norm_eps=rms_norm_eps,
-        remat=remat, layer_types=layer_types, sliding_window=sliding_window,
+        layer_types=layer_types, sliding_window=sliding_window,
         rope_parameters=tuple(
             (kind, tuple(sorted(rope.items()))) for kind, rope in sorted(ropes.items())
         ),
         n_heads=n_heads, n_kv_heads=n_kv_heads, head_dim=head_dim,
         attention_operand_dtype=attention_operand_dtype,
     )
+    return fields, config
+
+
+def decoder_spec(module, config: Dict[str, Any], optimizer: str,
+                 optimizer_kwargs: Optional[Dict[str, Any]]) -> ModelSpec:
+    """The spec of a decoder ``module`` that brings its own loss."""
     return ModelSpec(
         module=module,
         optimizer=make_optimizer(optimizer, optimizer_kwargs),
         loss=MODULE_LOSS,
         input_kind="window",
-        config=config,
+        config={
+            **config, "optimizer": optimizer,
+            "optimizer_kwargs": dict(optimizer_kwargs or {}),
+            "loss": MODULE_LOSS, "remat": module.remat,
+        },
     )

@@ -528,6 +528,16 @@ class MoEGQAForecast(MoEMLAForecast):
         super().__init__(kind, **kwargs)
 
 
+class AfMoEForecast(MoEMLAForecast):
+    """The same estimator over the ``afmoe_decoder`` kind (gated, query/key
+    normed grouped-query attention, rotary in the window layers alone,
+    sandwich norms, leading dense layers, sigmoid scores beside a shared
+    expert)."""
+
+    def __init__(self, kind: str = "afmoe_decoder", **kwargs: Any):
+        super().__init__(kind, **kwargs)
+
+
 # Aliases so ported reference configs resolve (the serializer rewrites
 # `gordo_components.model.models.X` → this module).
 KerasAutoEncoder = DenseAutoEncoder

@@ -78,6 +78,7 @@ _SHORT_NAMES: Dict[str, str] = {
         "PatchTSTForecast",
         "MoEMLAForecast",
         "MoEGQAForecast",
+        "AfMoEForecast",
         "KerasAutoEncoder",
         "KerasLSTMAutoEncoder",
         "KerasLSTMForecast",

@@ -6,7 +6,7 @@
   blocks of their own);
 * the kinds' contract, run whole on a kind that brings every optional
   function: the stand-in ``tokens`` kind that lives with the benchmark's
-  tests, and ``moe_mla`` and ``moe_gqa`` at small widths. Through ``make_build``, ``anomaly``,
+  tests, and ``moe_mla``, ``moe_gqa`` and ``afmoe`` at small widths. Through ``make_build``, ``anomaly``,
   ``slice_counts`` and ``compare.machine_numbers``, with a planted fault read
   as one.
 """
@@ -17,6 +17,7 @@ import sys
 import numpy as np
 import pytest
 
+from test_afmoe import SMALL as SMALL_AF
 from test_moe_gqa import SMALL as SMALL_GQA
 from test_moe_mla import SMALL
 
@@ -27,7 +28,7 @@ TOKENS = {
 }
 KINDS = {
     "tokens": TOKENS, "moe_mla": {**SMALL, "n_splits": 1},
-    "moe_gqa": {**SMALL_GQA, "n_splits": 1},
+    "moe_gqa": {**SMALL_GQA, "n_splits": 1}, "afmoe": {**SMALL_AF, "n_splits": 1},
 }
 TAGS, N_ROWS, N_REAL = 3, 160, 150
 
@@ -162,4 +163,27 @@ def test_moe_gqa_counts_the_pairs_inside_the_band_and_no_more_than_it_multiplies
     # 2 of 8 experts held and 2 chosen a token: half a slot a token expected
     tokens = 16 * TAGS
     assert kind.expert_ffn_flops(model, tokens) == 0.5 * (2.0 * tokens * 3 * 64 * 32)
+    assert models.train_flops(model, TAGS) == 3.0 * total
+
+
+def test_afmoe_counts_the_gate_the_dense_layer_and_the_band():
+    """Every layer's projections with the gate (five products, four of them
+    a query head wide), the dense layer's feed-forward, the expert layers'
+    router, shared expert and expected slots, attention's pairs inside the
+    band: under the jaxpr's products, never over."""
+    from benchmarks.reference import models
+    from benchmarks.tests.test_flops_bytes import product_flops_a_sample
+
+    model = KINDS["afmoe"]
+    kind = models.for_kind(model)
+    multiplied = product_flops_a_sample(kind, model, TAGS)
+    total = kind.forward_flops(model, TAGS)["total"]
+    assert 0.4 * multiplied <= total <= multiplied, (total, multiplied)
+    tokens = 16 * TAGS
+    projections = 5 * 2.0 * tokens * 64 * (3 * 8 + 2 * 2) * 16
+    dense = 2.0 * tokens * 3 * 64 * 96
+    experts = 4 * (2.0 * tokens * (64 * 8 + 3 * 64 * 32) + 0.5 * 2.0 * tokens * 3 * 64 * 32)
+    attention = TAGS * 4.0 * 8 * 16 * (4 * (21 + 60) + 16 * 17 / 2)
+    head = 2.0 * tokens * 64 * 64
+    assert total == pytest.approx(projections + dense + experts + attention + head, rel=1e-12)
     assert models.train_flops(model, TAGS) == 3.0 * total
