@@ -237,6 +237,8 @@ def _prepare_slice(
                 item["dataset_metadata"] = {}
             fetched["rows"] = len(item["X"])
             fetched["retries"] = item.pop("fetch_retries", 0)
+            # "numpy" or "pandas": the path join_timeseries resampled by
+            fetched["resample"] = getattr(item["dataset"], "resample_path", None)
 
     # items the width probe already fetched are skipped
     to_fetch = [item for item in local_items if "X" not in item]

@@ -329,3 +329,215 @@ class TestInfluxProvider:
         X, y = ds.get_data()
         assert list(X.columns) == ["t1", "t2"]
         assert len(X) > 100
+
+
+# -- the numpy resampling path against the pandas one ------------------------
+# ``_grid_for`` is where join_timeseries reads from its input whether the
+# numpy path is exact for it; patched to refuse, the same call goes through
+# the pandas path, which is kept as it was.
+
+import gordo_components_tpu.dataset.dataset as dataset_module  # noqa: E402
+
+
+def _tag(name, minutes, values=None, tz="UTC", unit="ns", seed=0):
+    """A series of points ``minutes`` after 2023-01-01 00:00 (wall time in
+    ``tz``, or naive), values a seeded walk well away from zero."""
+    minutes = np.asarray(minutes, dtype=float)
+    if values is None:
+        rng = np.random.default_rng(seed)
+        values = 20.0 + rng.normal(scale=0.3, size=len(minutes)).cumsum()
+    stamps = np.datetime64("2023-01-01T00:00") + (minutes * 60e9).astype(
+        "timedelta64[ns]"
+    )
+    index = pd.DatetimeIndex(stamps).as_unit(unit)
+    if tz is not None:
+        index = index.tz_localize(tz)
+    return pd.Series(np.asarray(values, dtype=float), index=index, name=name)
+
+
+def _every(step, stop, start=0.0):
+    return np.arange(start, stop, step)
+
+
+def _with_nan(values, *spans):
+    values = np.array(values, dtype=float)
+    for lo, hi in spans:
+        values[lo:hi] = np.nan
+    return values
+
+
+def _gap(lo, hi, step=3.0, stop=2000.0):
+    minutes = _every(step, stop)
+    return minutes[(minutes < lo) | (minutes >= hi)]
+
+
+_OSLO_START = pd.Timestamp("2023-01-01", tz="Europe/Oslo").to_pydatetime()
+_NAIVE = (datetime(2023, 1, 1), datetime(2023, 1, 3))
+_BASE = _every(3.0, 2000.0)
+_DUPLICATED = np.concatenate([_BASE, _BASE[::7]])
+_SHUFFLE = np.random.default_rng(3).permutation(len(_DUPLICATED))
+
+# case -> (the series, join_timeseries' arguments beyond them)
+PARITY_CASES = {
+    "duplicates-unsorted": (
+        lambda: [_tag("a", _DUPLICATED[_SHUFFLE], 20.0 + _SHUFFLE % 11)], {}),
+    "nan-values-and-an-all-nan-bin": (
+        lambda: [_tag("a", _BASE, _with_nan(
+            20.0 + np.sin(_BASE), (10, 11), (100, 104), (300, 302)))], {}),
+    "gap-shorter-than-the-limit": (
+        lambda: [_tag("a", _gap(300, 500))], {"interpolation_limit": "4h"}),
+    "gap-longer-than-the-limit-partly-filled": (
+        lambda: [_tag("a", _gap(300, 900)), _tag("b", _BASE, seed=1)],
+        {"interpolation_limit": "1h"}),
+    "leading-and-trailing-empty-bins": (
+        lambda: [_tag("a", _BASE, _with_nan(
+            20.0 + np.cos(_BASE), (0, 25), (len(_BASE) - 30, len(_BASE)))),
+            _tag("b", _BASE, seed=1)],
+        {"interpolation_limit": "40min"}),
+    "points-before-start-and-at-or-after-end": (
+        lambda: [_tag("a", _every(3.0, 3000.0, start=-200.0))],
+        {"resampling_end": datetime(2023, 1, 2, tzinfo=UTC)}),
+    "tz-utc": (lambda: [_tag("a", _BASE), _tag("b", _BASE[5:], seed=1)], {}),
+    "tz-europe-oslo": (
+        lambda: [_tag("a", _BASE, tz="Europe/Oslo"),
+                 _tag("b", _BASE[5:], tz="Europe/Oslo", seed=1)],
+        {"resampling_start": _OSLO_START}),
+    "tz-naive": (
+        lambda: [_tag("a", _BASE, tz=None), _tag("b", _BASE[5:], tz=None, seed=1)],
+        {"resampling_start": _NAIVE[0], "resampling_end": _NAIVE[1]}),
+    "unit-us": (
+        lambda: [_tag("a", _BASE, unit="us"), _tag("b", _BASE, unit="us", seed=1)],
+        {}),
+    "unit-ns-resolution-legacy-10T": (
+        lambda: [_tag("a", _BASE, unit="ns")], {"resolution": "10T"}),
+    "start-not-aligned-to-the-step": (
+        lambda: [_tag("a", _BASE)],
+        {"resampling_start": datetime(2023, 1, 1, 0, 3, 17, tzinfo=UTC)}),
+    "aggregation-mean-max-min": (
+        lambda: [_tag("a", _BASE, _with_nan(20.0 + np.sin(_BASE), (40, 60))),
+                 _tag("b", _BASE, seed=1)],
+        {"aggregation_methods": ["mean", "max", "min"],
+         "interpolation_limit": "30min"}),
+    "ffill-with-a-limit": (
+        lambda: [_tag("a", _gap(300, 900)), _tag("b", _BASE, seed=1)],
+        {"interpolation_method": "ffill", "interpolation_limit": "1h"}),
+    "interpolation-none": (
+        lambda: [_tag("a", _gap(300, 500)), _tag("b", _BASE, seed=1)],
+        {"interpolation_method": "none"}),
+    "ranges-only-partly-overlap": (
+        lambda: [_tag("a", _every(3.0, 1200.0)),
+                 _tag("b", _every(3.0, 1900.0, start=500.0), seed=1),
+                 _tag("c", _every(3.0, 1500.0, start=200.0), seed=2)], {}),
+}
+
+
+class _FixedProvider(GordoBaseDataProvider):
+    """Hands out the series it was given, by tag name."""
+
+    def __init__(self, series):
+        self.series = {s.name: s for s in series}
+
+    def can_handle_tag(self, tag):
+        return tag.name in self.series
+
+    def load_series(self, train_start_date, train_end_date, tag_list, dry_run=False):
+        for tag in tag_list:
+            yield self.series[tag.name].copy()
+
+
+def _join_both(series, monkeypatch, **kwargs):
+    args = {"resampling_start": START, "resampling_end": END,
+            "resolution": "10min", "aggregation_methods": "mean",
+            "interpolation_method": "linear_interpolation",
+            "interpolation_limit": "8h", **kwargs}
+    fast = dataset_module._join_timeseries([s.copy() for s in series], **args)
+    with monkeypatch.context() as patched:
+        patched.setattr(dataset_module, "_grid_for", lambda *a, **k: None)
+        slow = dataset_module._join_timeseries([s.copy() for s in series], **args)
+    return fast, slow
+
+
+def _assert_same_frame(fast, slow):
+    assert fast.index.dtype == slow.index.dtype
+    assert str(fast.index.tz) == str(slow.index.tz)
+    assert fast.index.freq == slow.index.freq
+    assert fast.index.name == slow.index.name
+    pd.testing.assert_frame_equal(
+        fast, slow, check_exact=False, rtol=1e-12, atol=0.0
+    )
+
+
+@pytest.mark.parametrize("case", sorted(PARITY_CASES) + ["row-filter-and-threshold"])
+def test_numpy_path_matches_pandas(case, monkeypatch):
+    if case == "row-filter-and-threshold":
+        series = [_tag("a", _gap(300, 900)), _tag("b", _BASE, seed=1)]
+
+        def dataset(threshold):
+            return TimeSeriesDataset(
+                START, END, ["a", "b"], target_tag_list=["b"],
+                data_provider=_FixedProvider(series), row_filter="`a` > 20",
+                row_threshold=threshold, interpolation_limit="1h",
+            )
+
+        fast = dataset(0)
+        X, y = fast.get_data()
+        with monkeypatch.context() as patched:
+            patched.setattr(dataset_module, "_grid_for", lambda *a, **k: None)
+            slow = dataset(0)
+            X_slow, y_slow = slow.get_data()
+            with pytest.raises(InsufficientDataError):
+                dataset(len(X) + 1).get_data()
+        with pytest.raises(InsufficientDataError):
+            dataset(len(X) + 1).get_data()
+        assert (fast.resample_path, slow.resample_path) == ("numpy", "pandas")
+        assert 0 < len(X) and fast.get_metadata()["rows_filtered"] > 0
+        _assert_same_frame(X, X_slow)
+        _assert_same_frame(y, y_slow)
+        assert fast.get_metadata() == slow.get_metadata()
+        return
+    build, kwargs = PARITY_CASES[case]
+    (fast, fast_meta, fast_path), (slow, slow_meta, slow_path) = _join_both(
+        build(), monkeypatch, **kwargs
+    )
+    assert (fast_path, slow_path) == ("numpy", "pandas")
+    assert len(fast) > 0
+    _assert_same_frame(fast, slow)
+    assert list(fast.dtypes) == list(slow.dtypes)
+    assert fast_meta == slow_meta
+
+
+def _resample_counts():
+    return {
+        path: dataset_module._M_RESAMPLE.collect().get((path,), 0.0)
+        for path in ("numpy", "pandas")
+    }
+
+
+@pytest.mark.parametrize(
+    "series, aggregation, path",
+    [
+        # the benchmark's shape: evenly spaced float64 points on a UTC index
+        (lambda: [_tag(f"t{i}", _BASE, seed=i) for i in range(3)], "mean", "numpy"),
+        (lambda: [_tag("a", _BASE)], "median", "pandas"),
+        (lambda: [_tag("a", _BASE)], ["mean", np.mean], "pandas"),
+        (lambda: [pd.Series(20.0 + np.arange(50.0), name="a")], "mean", "pandas"),
+        (lambda: [_tag("a", _BASE),
+                  _tag("b", _BASE).astype(np.int64)], "mean", "pandas"),
+    ],
+    ids=["benchmark-shaped", "median", "callable", "not-a-datetime-index",
+         "an-integer-tag"],
+)
+def test_resample_counter_counts_each_call_by_its_path(series, aggregation, path):
+    before = _resample_counts()
+    try:
+        _, _, taken = dataset_module._join_timeseries(
+            series(), START, END, "10min", aggregation, "none", None
+        )
+    except TypeError:
+        # pandas refuses to resample a non-DatetimeIndex: still its path
+        taken = "pandas"
+    after = _resample_counts()
+    assert taken == path
+    assert after[path] == before[path] + 1
+    other = "pandas" if path == "numpy" else "numpy"
+    assert after[other] == before[other]

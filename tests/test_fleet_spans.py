@@ -157,6 +157,13 @@ def test_span_has_its_parent_and_thread(traced_build, name):
         ) == [(1, 0), (2, 1), (2, 2)]
 
 
+def test_fetch_span_carries_the_resample_path(traced_build):
+    timeline, _ = traced_build
+    fetches = [s for s in timeline.spans if s.name == "fleet.fetch"]
+    # RandomDataset's float64 series on a UTC index: the numpy path
+    assert [s.attrs["resample"] for s in fetches] == ["numpy"] * N_MACHINES
+
+
 def test_slice_children_cover_it_to_within_its_self_time(traced_build):
     timeline, _ = traced_build
     self_seconds = timeline.self_seconds()
