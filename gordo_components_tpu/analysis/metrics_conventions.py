@@ -47,13 +47,15 @@ COMPONENTS = (
 # is the three-value interactive/standard/bulk enum (§25).
 # ``actor`` is the control ledger's closed writer enum — unknown actors
 # fold into 'operator' before the label is minted (§28).
+# ``cache`` is the persistent compile cache's four-value
+# hit/miss/off/deferred answer for a fleet program's compile (§9).
 ALLOWED_LABELS = frozenset(
     {
         "endpoint", "status", "kind", "outcome", "path", "event", "phase",
         "reason", "stage", "name", "trigger", "format", "worker",
         "machine", "target", "cause", "point", "to", "where", "error",
         "window", "precision", "actuator", "direction", "shard",
-        "tenant", "class", "actor",
+        "tenant", "class", "actor", "cache",
     }
 )
 

@@ -16,10 +16,11 @@ Exit codes: 0 ok · 64 bad config (permanent) · 66 data unavailable/short
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import sys
-from typing import Optional
+from typing import Iterator, Optional
 
 import click
 import yaml
@@ -180,6 +181,59 @@ def build_cmd(name, model_config, data_config, output_dir, model_register_dir,
         click.echo(json.dumps(scores))
 
 
+@contextlib.contextmanager
+def _fleet_exit_codes() -> Iterator[None]:
+    """The exit code of a ``fleet-build`` that fails, by what failed.
+    Imports what it tells apart only where something did fail, so that the
+    command's own span holds its imports."""
+    try:
+        yield
+    except Exception as exc:
+        from jax.errors import JaxRuntimeError
+
+        from ..dataset.dataset import InsufficientDataError
+
+        if isinstance(exc, InsufficientDataError):
+            logger.error("Data error in fleet build: %s", exc)
+            sys.exit(EXIT_DATA)
+        if isinstance(exc, ValueError):
+            logger.error("Config error in fleet build: %s", exc)
+            sys.exit(EXIT_CONFIG)
+        if not isinstance(exc, JaxRuntimeError):
+            raise
+        # Deterministic device failures (HBM OOM = RESOURCE_EXHAUSTED,
+        # invalid XLA program = INVALID_ARGUMENT) exit the permanent code:
+        # the Job's podFailurePolicy Ignores 75, so mapping these to 75
+        # would crash-loop a build that can never succeed on TPU quota
+        # forever without ever counting toward backoffLimit.
+        if _is_permanent_xla_error(str(exc)):
+            logger.error(
+                "Deterministic device failure in fleet build: %s — "
+                "exiting permanent code %d (restarts cannot help)",
+                exc,
+                EXIT_PERMANENT,
+            )
+            sys.exit(EXIT_PERMANENT)
+        # Everything else is a device/collective runtime failure — in
+        # multi-host builds most often a dead peer detected by the
+        # transport (connection reset in an allgather). Deterministically
+        # retryable: restart-all re-runs resume from the registry + slice
+        # checkpoints, so map it to the explicit transient code (75,
+        # EX_TEMPFAIL) rather than a generic crash. The in-process
+        # watchdog (GORDO_SLICE_TIMEOUT_S) exits the same code for the
+        # hangs the transport cannot see.
+        from ..parallel.build_fleet import EXIT_RETRYABLE
+
+        logger.error(
+            "Runtime failure in fleet build (dead peer / device error?): "
+            "%s — exiting retryable code %d; a restarted run resumes from "
+            "the registry and slice checkpoints",
+            exc,
+            EXIT_RETRYABLE,
+        )
+        sys.exit(EXIT_RETRYABLE)
+
+
 @gordo.command("fleet-build")
 @click.option("--machine-config", required=True,
               help="fleet YAML (machines + globals) file path or string")
@@ -241,55 +295,63 @@ def fleet_build_cmd(machine_config, output_dir, model_register_dir, n_devices,
     (or on a TPU pod with autodetectable cluster metadata plus explicit
     ``--num-processes``), the build runs multi-host — every process ingests
     and writes only its own machine shard."""
-    from jax.errors import JaxRuntimeError
+    from ..observability import flightrec, spans
 
-    from ..dataset.dataset import InsufficientDataError
-    from ..parallel import FleetMachineConfig, build_fleet, fleet_mesh
-    from ..parallel.build_fleet import EXIT_RETRYABLE
-    from ..utils.backend import enable_persistent_compile_cache
-    from ..workflow import NormalizedConfig
+    multihost = coordinator_address is not None or num_processes is not None
+    # the job's timeline begins here: the command's own set-up (its imports,
+    # the compile cache, the config, the mesh) is fleet.command on it, and
+    # build_fleet records into the same timeline
+    with _fleet_exit_codes(), flightrec.build_timeline(trace_dir):
+        with spans.stage("fleet.command"):
+            from ..parallel import FleetMachineConfig, build_fleet, fleet_mesh
+            from ..precision import parse_precision_map
+            from ..utils.backend import enable_persistent_compile_cache
+            from ..workflow import NormalizedConfig
 
-    enable_persistent_compile_cache()
-    try:
-        multihost = coordinator_address is not None or num_processes is not None
-        if process_id is not None and not multihost:
-            # a bare process index would silently run a FULL single-host
-            # build on every host — duplicated training and racing writes
-            raise click.UsageError(
-                "--process-id requires --coordinator-address and/or "
-                "--num-processes"
-            )
-        if multihost:
-            # must run BEFORE anything touches the XLA backend
-            from ..parallel.distributed import (
-                global_fleet_mesh,
-                initialize_multihost,
-            )
+            enable_persistent_compile_cache()
+            if process_id is not None and not multihost:
+                # a bare process index would silently run a FULL single-host
+                # build on every host — duplicated training and racing writes
+                raise click.UsageError(
+                    "--process-id requires --coordinator-address and/or "
+                    "--num-processes"
+                )
+            if multihost:
+                # must run BEFORE anything touches the XLA backend
+                from ..parallel.distributed import (
+                    global_fleet_mesh,
+                    initialize_multihost,
+                )
 
-            initialize_multihost(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
-        config = NormalizedConfig(_load_config(machine_config, "machine-config"))
-        machines = [
-            FleetMachineConfig(
-                name=machine.name,
-                model_config=machine.model,
-                data_config=machine.dataset,
-                metadata=machine.metadata,
-                evaluation=machine.evaluation,
-            )
-            for machine in config.machines
-        ]
-        if multihost and n_devices is not None:
-            logger.warning(
-                "--n-devices is ignored in multi-host mode: the global "
-                "fleet mesh spans every device of every process"
-            )
-        from ..precision import parse_precision_map
-
-        mesh = global_fleet_mesh() if multihost else fleet_mesh(n_devices)
+                initialize_multihost(
+                    coordinator_address=coordinator_address,
+                    num_processes=num_processes,
+                    process_id=process_id,
+                )
+            with spans.stage("fleet.config") as configured:
+                config = NormalizedConfig(
+                    _load_config(machine_config, "machine-config")
+                )
+                machines = [
+                    FleetMachineConfig(
+                        name=machine.name,
+                        model_config=machine.model,
+                        data_config=machine.dataset,
+                        metadata=machine.metadata,
+                        evaluation=machine.evaluation,
+                    )
+                    for machine in config.machines
+                ]
+                configured["machines"] = len(machines)
+            precisions = parse_precision_map(precision_map)
+            if multihost and n_devices is not None:
+                logger.warning(
+                    "--n-devices is ignored in multi-host mode: the global "
+                    "fleet mesh spans every device of every process"
+                )
+            with spans.stage("fleet.mesh"):
+                # where the backend is first touched on one host
+                mesh = global_fleet_mesh() if multihost else fleet_mesh(n_devices)
         results = build_fleet(
             machines,
             output_dir,
@@ -300,44 +362,8 @@ def fleet_build_cmd(machine_config, output_dir, model_register_dir, n_devices,
             profile_dir=trace_dir,
             slice_size=slice_size or None,
             precision_default=precision_default,
-            precision_map=parse_precision_map(precision_map),
+            precision_map=precisions,
         )
-    except InsufficientDataError as exc:
-        logger.error("Data error in fleet build: %s", exc)
-        sys.exit(EXIT_DATA)
-    except ValueError as exc:
-        logger.error("Config error in fleet build: %s", exc)
-        sys.exit(EXIT_CONFIG)
-    except JaxRuntimeError as exc:
-        # Deterministic device failures (HBM OOM = RESOURCE_EXHAUSTED,
-        # invalid XLA program = INVALID_ARGUMENT) exit the permanent code:
-        # the Job's podFailurePolicy Ignores 75, so mapping these to 75
-        # would crash-loop a build that can never succeed on TPU quota
-        # forever without ever counting toward backoffLimit.
-        if _is_permanent_xla_error(str(exc)):
-            logger.error(
-                "Deterministic device failure in fleet build: %s — "
-                "exiting permanent code %d (restarts cannot help)",
-                exc,
-                EXIT_PERMANENT,
-            )
-            sys.exit(EXIT_PERMANENT)
-        # Everything else is a device/collective runtime failure — in
-        # multi-host builds most often a dead peer detected by the
-        # transport (connection reset in an allgather). Deterministically
-        # retryable: restart-all re-runs resume from the registry + slice
-        # checkpoints, so map it to the explicit transient code (75,
-        # EX_TEMPFAIL) rather than a generic crash. The in-process
-        # watchdog (GORDO_SLICE_TIMEOUT_S) exits the same code for the
-        # hangs the transport cannot see.
-        logger.error(
-            "Runtime failure in fleet build (dead peer / device error?): "
-            "%s — exiting retryable code %d; a restarted run resumes from "
-            "the registry and slice checkpoints",
-            exc,
-            EXIT_RETRYABLE,
-        )
-        sys.exit(EXIT_RETRYABLE)
     if serving_cache and results and not multihost:
         # pay the SERVING compiles here, once, where the build already
         # owns the device — every later boot/reload/rollback against this

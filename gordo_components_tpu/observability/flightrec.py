@@ -3,10 +3,11 @@ timelines.
 
 A fleet build's one timeline a job (``meta`` ``kind="fleet-build"``,
 thousands of spans for a tall fleet, a few hundred bytes each) is
-recorded here too, however the job ends; :meth:`FlightRecorder.latest`
-finds it. A build process records one; a process that both serves and
-builds keeps a build in the ring until 256 later timelines push it out,
-and in the slow reservoir for good, which a build's length earns it.
+recorded here too, however the job ends (:func:`build_timeline`);
+:meth:`FlightRecorder.latest` finds it. A build process records one; a
+process that both serves and builds keeps a build in the ring until 256
+later timelines push it out, and in the slow reservoir for good, which a
+build's length earns it.
 
 Post-hoc diagnosability is the point: when an operator asks "why did
 trace 3f2a... take 900 ms at 04:12", the histograms have already averaged
@@ -30,12 +31,20 @@ the escape hatch); ``GORDO_FLIGHTREC_KEEP`` / ``_SLOW_KEEP`` /
 
 from __future__ import annotations
 
+import contextlib
+import json
+import logging
 import os
 import threading
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
+from . import spans, tracing
 from .spans import Timeline
+
+logger = logging.getLogger(__name__)
+BUILD_KIND = "fleet-build"
+TIMELINE_FILE = "fleet_build_timeline.json"
 
 
 def _env_int(name: str, default: int) -> int:
@@ -180,3 +189,68 @@ class FlightRecorder:
 # THE process-wide recorder (like observability.REGISTRY): the server
 # records into it, /debug/requests reads from it, tests may clear() it.
 RECORDER = FlightRecorder()
+
+
+@contextlib.contextmanager
+def build_timeline(
+    trace_dir: Optional[str] = None, **meta: Any
+) -> Iterator[Timeline]:
+    """The fleet build's one timeline, bound for the block.
+
+    The first block that asks owns it: ``gordo fleet-build`` at its entry,
+    so that the command's own set-up is on it, or ``build_fleet`` where a
+    library calls it. The owner begins it under the job's trace id with
+    annotating stages (each is also a ``jax.profiler.TraceAnnotation`` of
+    the span's name) and, however the block ends, finishes it, records it
+    here, writes it under ``trace_dir`` as :data:`TIMELINE_FILE` (Chrome
+    trace events) and logs its phases. A block opened inside the owner's
+    records into the same timeline, adds ``meta`` to it and leaves its
+    ending to the owner."""
+    bound = spans.current_timeline()
+    if bound is not None and bound.meta.get("kind") == BUILD_KIND:
+        bound.meta.update((k, v) for k, v in meta.items() if v is not None)
+        yield bound
+        return
+    with tracing.trace(tracing.current_or_new()) as trace_id:
+        timeline, token = spans.begin(
+            trace_id, kind=BUILD_KIND, service="gordo fleet-build", **meta
+        )
+        timeline.annotate = True
+        error = ""
+        try:
+            yield timeline
+        except BaseException as exc:  # the benchmark ends a job with one
+            error = f"{type(exc).__name__}: {exc}"
+            raise
+        finally:
+            spans.end(token)
+            timeline.finish(status="error" if error else "ok", error=error)
+            # kept in memory, written at the end, readable after any ending
+            RECORDER.record(timeline)
+            if trace_dir:
+                _write_timeline(timeline, trace_dir)
+            logger.info(
+                "Fleet build %s: %s machines in %.1fs; phases: %s",
+                timeline.status,
+                timeline.meta.get("machines", "no"),
+                timeline.duration,
+                {
+                    name: round(seconds, 3)
+                    for name, seconds in sorted(
+                        timeline.stage_seconds().items()
+                    )
+                },
+            )
+
+
+def _write_timeline(timeline: Timeline, trace_dir: str) -> None:
+    """Best effort: the timeline is a diagnostic, and this runs while a
+    job's own exception may be propagating."""
+    path = os.path.join(trace_dir, TIMELINE_FILE)
+    try:
+        os.makedirs(trace_dir, exist_ok=True)
+        with open(path, "w") as fh:
+            json.dump(timeline.to_chrome_trace(), fh, default=str)
+        logger.info("Fleet build timeline written to %s", path)
+    except OSError:
+        logger.warning("Could not write %s", path, exc_info=True)

@@ -486,10 +486,12 @@ def stage(name: str, **attrs: Any) -> Iterator[Dict[str, Any]]:
     span_id = timeline.next_id()
     parent = _open_stage.get()
     token = _open_stage.set(span_id)
+    # the annotation's own cost is inside the span it marks (the first
+    # imports jax.profiler: a command's first stage holds that import)
+    started = time.perf_counter()
     annotation = _annotation(name) if timeline.annotate else None
     if annotation is not None:
         annotation.__enter__()
-    started = time.perf_counter()
     try:
         yield attrs
     except BaseException as exc:

@@ -47,8 +47,8 @@ from ..builder.build_model import (
 from ..models.analysis import Analyzed as _Analyzed
 from ..models.analysis import analyze_model as _analyze_model
 from ..models.transformers import MinMaxScaler, StandardScaler
-from ..observability import spans, tracing
-from ..observability.flightrec import RECORDER
+from ..observability import spans
+from ..observability.flightrec import build_timeline
 from ..observability.registry import REGISTRY
 from ..ops.scaling import ScalerParams
 from ..resilience import faults
@@ -92,9 +92,6 @@ _M_BUILD_FETCH = REGISTRY.counter(
 _ROW_QUANTUM = 256
 
 MANIFEST_FILE = "fleet_manifest.json"
-# the job's span timeline as Chrome trace-event JSON, written beside the
-# device trace when a trace dir is given (Perfetto loads both)
-TIMELINE_FILE = "fleet_build_timeline.json"
 
 # exit code for a tripped multi-host watchdog: EX_TEMPFAIL — deliberately
 # NOT the permanent-failure codes the CLI maps config/data errors to
@@ -984,11 +981,13 @@ def build_fleet(
     ``fleet_build_timeline.json`` beside it.
 
     **Spans**: the job is one ``observability.spans.Timeline`` (``fleet.job``
-    → ``fleet.preamble``, ``fleet.bucket`` → ``fleet.slice`` and its phases
-    on this thread, ``fleet.prepare`` and its fetches on the prefetch
-    worker's, ``fleet.commit_loop`` and ``fleet.manifest`` on the commit
-    worker's; docs/ARCHITECTURE.md §13), handed to the flight recorder
-    (``meta`` ``kind="fleet-build"``) however the job ends.
+    → ``fleet.preamble``, ``fleet.bucket`` → ``fleet.plan`` and
+    ``fleet.slice`` and its phases on this thread, ``fleet.prepare`` and its
+    fetches on the prefetch worker's, ``fleet.commit_loop`` and
+    ``fleet.manifest`` on the commit worker's; docs/ARCHITECTURE.md §13),
+    handed to the flight recorder (``meta`` ``kind="fleet-build"``) however
+    the job ends (``flightrec.build_timeline``). ``gordo fleet-build`` begins
+    it at its own entry, and the job records into that one.
 
     Buckets larger than ``slice_size`` train in slices: every slice is padded
     to the same machine count (so the compiled executable is reused across
@@ -1016,55 +1015,13 @@ def build_fleet(
     process writes/reads its own shards), layered on the per-machine
     registry resume.
     """
-    with tracing.trace(tracing.current_or_new()) as trace_id:
-        timeline, token = spans.begin(
-            trace_id, kind="fleet-build", service="gordo fleet-build",
-            machines=len(machines),
-        )
-        timeline.annotate = True
-        error = ""
-        try:
-            with spans.stage("fleet.job", machines=len(machines)):
-                return _build_fleet(
-                    machines, output_dir, model_register_dir, mesh, seed,
-                    n_splits, profile_dir, slice_size, fetch_retries,
-                    fetch_backoff, precision_default, precision_map,
-                )
-        except BaseException as exc:  # the benchmark ends a job with one
-            error = f"{type(exc).__name__}: {exc}"
-            raise
-        finally:
-            spans.end(token)
-            timeline.finish(status="error" if error else "ok", error=error)
-            # kept in memory, written at the end, readable after any ending
-            RECORDER.record(timeline)
-            if profile_dir:
-                _write_timeline(timeline, profile_dir)
-            logger.info(
-                "Fleet build %s: %d machines in %.1fs; phases: %s",
-                timeline.status,
-                len(machines),
-                timeline.duration,
-                {
-                    name: round(seconds, 3)
-                    for name, seconds in sorted(
-                        timeline.stage_seconds().items()
-                    )
-                },
+    with build_timeline(profile_dir, machines=len(machines)):
+        with spans.stage("fleet.job", machines=len(machines)):
+            return _build_fleet(
+                machines, output_dir, model_register_dir, mesh, seed,
+                n_splits, profile_dir, slice_size, fetch_retries,
+                fetch_backoff, precision_default, precision_map,
             )
-
-
-def _write_timeline(timeline: spans.Timeline, trace_dir: str) -> None:
-    """Best effort: the timeline is a diagnostic, and this runs while a
-    job's own exception may be propagating."""
-    path = os.path.join(trace_dir, TIMELINE_FILE)
-    try:
-        os.makedirs(trace_dir, exist_ok=True)
-        with open(path, "w") as fh:
-            json.dump(timeline.to_chrome_trace(), fh, default=str)
-        logger.info("Fleet build timeline written to %s", path)
-    except OSError:
-        logger.warning("Could not write %s", path, exc_info=True)
 
 
 def _build_fleet(
@@ -1314,55 +1271,68 @@ def _build_fleet(
             with spans.stage(
                 "fleet.bucket", bucket=b, machines=len(items)
             ) as bucket:
-                model_config = items[0]["machine"].model_config
-                probe = pipeline_from_definition(model_config)
-                analyzed = _analyze_model(probe)
-                n_features = items[0]["F"]
-                n_targets = items[0]["T"]
-                bucket_splits = items[0]["n_splits"]
-                spec = _spec_for(analyzed, n_features, n_targets, bucket_splits)
-
-                # ---- slice the bucket: each slice is an independent failure
-                # domain with its own data fetch, train call, and artifact
-                # writes. All slices share one padded machine count so the
-                # compiled executable is reused (fleet_program caches on
-                # spec+shape) ---------------------------------------------------
-                n_real = len(items)
-                eff = n_real if not slice_size else min(slice_size, n_real)
-                cap = _slice_cap(spec, n_features)
-                if cap is not None and cap < eff:
-                    logger.info(
-                        "Fleet bucket %d: slices of %d, not %d: the training "
-                        "state of more would not fit the device", b + 1, cap, eff,
+                # the bucket's plan: its spec, slices and padding, before
+                # its first fetch is asked for
+                with spans.stage("fleet.plan", bucket=b):
+                    model_config = items[0]["machine"].model_config
+                    probe = pipeline_from_definition(model_config)
+                    analyzed = _analyze_model(probe)
+                    n_features = items[0]["F"]
+                    n_targets = items[0]["T"]
+                    bucket_splits = items[0]["n_splits"]
+                    spec = _spec_for(
+                        analyzed, n_features, n_targets, bucket_splits
                     )
-                    eff = cap
-                n_padded = (
-                    pad_to_multiple(eff, mesh.size) if mesh is not None else eff
-                )
-                slices = [items[s : s + eff] for s in range(0, n_real, eff)]
-                bucket["slices"] = len(slices)
-                logger.info(
-                    "Fleet bucket %d/%d: %d machines in %d slice(s) of %d "
-                    "(padded %d), F=%d",
-                    b + 1,
-                    len(buckets),
-                    n_real,
-                    len(slices),
-                    eff,
-                    n_padded,
-                    n_features,
-                )
-                span = _local_machine_span(mesh, n_padded) if multihost else None
-                # single-host transfer overlap (see _prepare_slice): the
-                # worker device-places a prepared slice when the bucket's
-                # executable already exists. Memory-constrained (remat)
-                # buckets keep the batch on host until their own turn — their
-                # peak-HBM budget has no room for a second slice's buffers
-                place = (
-                    (spec, mesh)
-                    if not (multihost or spec.memory_constrained)
-                    else None
-                )
+
+                    # ---- slice the bucket: each slice is an independent
+                    # failure domain with its own data fetch, train call,
+                    # and artifact writes. All slices share one padded
+                    # machine count so the compiled executable is reused
+                    # (fleet_program caches on spec+shape) ------------------
+                    n_real = len(items)
+                    eff = n_real if not slice_size else min(slice_size, n_real)
+                    cap = _slice_cap(spec, n_features)
+                    if cap is not None and cap < eff:
+                        logger.info(
+                            "Fleet bucket %d: slices of %d, not %d: the "
+                            "training state of more would not fit the device",
+                            b + 1, cap, eff,
+                        )
+                        eff = cap
+                    n_padded = (
+                        pad_to_multiple(eff, mesh.size)
+                        if mesh is not None else eff
+                    )
+                    slices = [
+                        items[s : s + eff] for s in range(0, n_real, eff)
+                    ]
+                    bucket["slices"] = len(slices)
+                    logger.info(
+                        "Fleet bucket %d/%d: %d machines in %d slice(s) of %d "
+                        "(padded %d), F=%d",
+                        b + 1,
+                        len(buckets),
+                        n_real,
+                        len(slices),
+                        eff,
+                        n_padded,
+                        n_features,
+                    )
+                    span = (
+                        _local_machine_span(mesh, n_padded)
+                        if multihost else None
+                    )
+                    # single-host transfer overlap (see _prepare_slice): the
+                    # worker device-places a prepared slice when the
+                    # bucket's executable already exists. Memory-constrained
+                    # (remat) buckets keep the batch on host until their own
+                    # turn — their peak-HBM budget has no room for a second
+                    # slice's buffers
+                    place = (
+                        (spec, mesh)
+                        if not (multihost or spec.memory_constrained)
+                        else None
+                    )
                 # the prefetch worker inherits no context: it binds this
                 # one, so every fleet.prepare hangs under this bucket's
                 # stage (beside the slices it overlaps, not inside one)

@@ -31,7 +31,6 @@ reference's canonical ``DiffBasedAnomalyDetector(TransformedTargetRegressor
 from __future__ import annotations
 
 import logging
-import time
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -39,7 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.train import FitResult, make_fit_fn, make_predict_fn
-from ..observability import spans
+from ..observability import compiles, spans
 from ..observability.registry import REGISTRY
 from ..ops import windowing
 from ..ops.scaling import ScalerParams
@@ -52,14 +51,10 @@ logger = logging.getLogger(__name__)
 _M_FLEET_PROGRAMS = REGISTRY.counter(
     "gordo_fleet_programs_built_total",
     "Fleet training programs constructed (jit = traced wrapper, compile "
-    "deferred to first call; aot = fleet_executable, compile paid here)",
-    labels=("kind",),
-)
-_M_FLEET_COMPILE_SECONDS = REGISTRY.histogram(
-    "gordo_fleet_compile_seconds",
-    "AOT lower+compile duration of fleet executables — the dominant "
-    "cold-build cost on TPU (tens of seconds per bucket shape)",
-    buckets=(0.1, 0.5, 1, 5, 10, 30, 60, 120, 300, 600, float("inf")),
+    "deferred to first call; aot = fleet_executable, compile paid here) "
+    "and what the persistent compile cache answered for the compile "
+    "(hit / miss / off; deferred for jit)",
+    labels=("kind", "cache"),
 )
 
 
@@ -695,7 +690,7 @@ def fleet_program(
     aliased to the parameters and the optimizer state it hands back."""
 
     def build():
-        _M_FLEET_PROGRAMS.labels("jit").inc()
+        _M_FLEET_PROGRAMS.labels("jit", "deferred").inc()
         program = _over_machines(
             make_machine_program(spec, n_rows, n_features, n_targets)
         )
@@ -715,6 +710,26 @@ def fleet_program(
     return _cached(_PROGRAM_CACHE, _PROGRAM_CACHE_MAX, key, build)
 
 
+def _compile_in_stages(program, avatars, name: str):
+    """``program.lower(*avatars).compile()``, the same executable, in its
+    three stages, each a span under the stage open here (``fleet.program``
+    on the build's path): ``fleet.trace`` (the Python function traced to a
+    jaxpr), ``fleet.lower`` (to StableHLO) and ``fleet.compile`` (the XLA
+    compile, or the persistent cache's load: ``cache`` says which, see
+    ``observability.compiles``). ``name`` (``program``) tells the state and
+    the train program apart. Returns ``(compiled, cache)``."""
+    with spans.stage("fleet.trace", program=name):
+        traced = program.trace(*avatars)
+    with spans.stage("fleet.lower", program=name):
+        lowered = traced.lower()
+    with (
+        spans.stage("fleet.compile", program=name) as attrs,
+        compiles.cache_outcome(attrs),
+    ):
+        compiled = lowered.compile()
+    return compiled, attrs["cache"]
+
+
 _STATE_CACHE: dict = {}
 
 
@@ -730,12 +745,11 @@ def fleet_state(spec: FleetSpec, n_machines: int, n_features: int, mesh=None):
         draw.__name__ = "machine_states"  # the XLA module: not the train program's
         keys = jax.ShapeDtypeStruct((n_machines, prng_key_width()), jnp.uint32)
         if mesh is None:
-            return jax.jit(draw).lower(keys).compile()
-        shard = fleet_sharding(mesh)
-        return (
-            jax.jit(draw, in_shardings=(shard,), out_shardings=shard)
-            .lower(keys).compile()
-        )
+            program = jax.jit(draw)
+        else:
+            shard = fleet_sharding(mesh)
+            program = jax.jit(draw, in_shardings=(shard,), out_shardings=shard)
+        return _compile_in_stages(program, (keys,), "state")[0]
 
     key = (spec, n_machines, n_features, mesh)
     return _cached(_STATE_CACHE, _PROGRAM_CACHE_MAX, key, build)
@@ -795,12 +809,8 @@ def fleet_executable(
         )
         if sequential_fits(spec):
             avatars += (abstract_state(spec, n_machines, n_features),)
-        compile_started = time.perf_counter()
-        compiled = program.lower(*avatars).compile()
-        _M_FLEET_PROGRAMS.labels("aot").inc()
-        _M_FLEET_COMPILE_SECONDS.observe(
-            time.perf_counter() - compile_started
-        )
+        compiled, cache = _compile_in_stages(program, avatars, "train")
+        _M_FLEET_PROGRAMS.labels("aot", cache).inc()
         return compiled, compiled.input_formats[0]
 
     key = (spec, n_machines, n_rows, n_features, n_targets, mesh)
@@ -1003,7 +1013,8 @@ def train_fleet_arrays(
     are accepted as-is.
 
     The three things it does are three stages (``observability.spans``):
-    ``fleet.program`` (executable memo hit, or lower + compile),
+    ``fleet.program`` (executable memo hit, or the programs' trace, lower
+    and compile, each a stage of its own: :func:`_compile_in_stages`),
     ``fleet.ingest`` (the layout-matched ``device_put``; a no-op for
     arrays the prefetch worker already placed) and ``fleet.execute``
     (dispatch until the result is ready on the device, so the call
@@ -1022,16 +1033,10 @@ def train_fleet_arrays(
         found["memo_hit"] = (
             peek_fleet_executable(spec, *shape, mesh=mesh) is not None
         )
-        lookup_started = time.perf_counter()
         if sequential_fits(spec):  # the small program first: what follows
             # the train program's load is then the slice's own work
             draw_state = fleet_state(spec, n_machines, n_features, mesh=mesh)
         compiled, formats = fleet_executable(spec, *shape, mesh=mesh)
-        # lower + compile (or the persistent cache's load) on a miss
-        found["compile_s"] = (
-            0.0 if found["memo_hit"]
-            else time.perf_counter() - lookup_started
-        )
     with spans.stage("fleet.ingest", step="device_put"):
         placed = put_fleet_batch(batch, formats)
     with spans.stage("fleet.execute"):

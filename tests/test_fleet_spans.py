@@ -2,7 +2,8 @@
 ``build_fleet`` call, one span per phase of every slice, recorded by the
 thread that does the work; handed to the flight recorder however the job
 ends; the same names as ``TraceAnnotation`` marks in a profiler session;
-and the benchmark's readers of those spans.
+and the benchmark's readers of those spans. Entered through the CLI, the
+timeline begins at the command's entry, so that its set-up is on it too.
 
 One small fleet (6 machines, slices of 2) is built once through the CLI
 with ``--trace-dir`` and shared by the tests that only read it."""
@@ -12,6 +13,7 @@ import importlib
 import json
 import logging
 import os
+import time
 
 import pytest
 import yaml
@@ -19,8 +21,10 @@ import yaml
 from gordo_components_tpu.cli import gordo
 from gordo_components_tpu.dataset.dataset import RandomDataset
 from gordo_components_tpu.observability import flightrec, tracing
+from gordo_components_tpu.observability.registry import REGISTRY
+from gordo_components_tpu.observability.spans import Timeline
 from gordo_components_tpu.parallel import FleetMachineConfig, build_fleet
-from gordo_components_tpu.parallel.build_fleet import TIMELINE_FILE
+from gordo_components_tpu.observability.flightrec import TIMELINE_FILE
 
 MODEL = {
     "DiffBasedAnomalyDetector": {
@@ -47,20 +51,29 @@ DATASET = {
     "train_end_date": "2023-01-03T00:00:00+00:00",
 }
 N_MACHINES, SLICE = 6, 2
+# a stand-in for the harness's process start, on the timeline's clock
+STARTED = time.perf_counter()
 
 # span -> (its parent's name, the thread that records it); the names are the
 # contract the benchmark's readers and PERF.md use
 MAIN, PREFETCH, POOL = "MainThread", "fleet-prefetch", "fleet-fetch"
 COMMIT = "fleet-commit"  # slice s commits there while slice s+1 trains
 SPAN_TREE = {
+    "fleet.command": (None, MAIN),
+    "fleet.config": ("fleet.command", MAIN),
+    "fleet.mesh": ("fleet.command", MAIN),
     "fleet.job": (None, MAIN),
     "fleet.preamble": ("fleet.job", MAIN),
     "fleet.bucket": ("fleet.job", MAIN),
+    "fleet.plan": ("fleet.bucket", MAIN),
     "fleet.slice": ("fleet.bucket", MAIN),
     "fleet.prefetch_wait": ("fleet.slice", MAIN),
     "fleet.ingest": ("fleet.slice", MAIN),
     "fleet.checkpoint_restore": ("fleet.slice", MAIN),
     "fleet.program": ("fleet.slice", MAIN),
+    "fleet.trace": ("fleet.program", MAIN),
+    "fleet.lower": ("fleet.program", MAIN),
+    "fleet.compile": ("fleet.program", MAIN),
     "fleet.execute": ("fleet.slice", MAIN),
     "fleet.result_fetch": ("fleet.slice", MAIN),
     "fleet.checkpoint_save": ("fleet.slice", MAIN),
@@ -74,14 +87,27 @@ SPAN_TREE = {
     "fleet.assemble": ("fleet.prepare", PREFETCH),
     "fleet.place": ("fleet.prepare", PREFETCH),
 }
-READERS = (
+# the steady slice's readers, and the set-up's
+SLICE_READERS = (
     "prefetch_wait_s_per_slice", "ingest_s_per_slice",
     "result_fetch_s_per_slice", "checkpoint_s_per_slice",
     "commit_s_per_machine", "execute_wait_s_per_slice",
     "slice_unattributed_s", "commit_wait_s_per_slice",
 )
+SETUP_READERS = (
+    "setup_before_command_s", "setup_host_s", "first_fetch_wait_s",
+    "program_trace_lower_s", "program_load_s", "setup_unattributed_s",
+)
+READERS = SLICE_READERS + SETUP_READERS
 # what the loop's thread does in a slice: the readers of these cover it
-LOOP_READERS = tuple(r for r in READERS if r != "commit_s_per_machine")
+LOOP_READERS = tuple(r for r in SLICE_READERS if r != "commit_s_per_machine")
+# the spans of the set-up; a program from before them records none
+SETUP_SPANS = (
+    "fleet.command", "fleet.config", "fleet.mesh", "fleet.plan",
+    "fleet.trace", "fleet.lower", "fleet.compile",
+)
+# what the harness hands a reader: its process start among the rest
+VIEW = {"run": {"started": STARTED}}
 
 
 def _machines(prefix, dataset=None):
@@ -140,6 +166,10 @@ def test_span_has_its_parent_and_thread(traced_build, name):
     per_slice = N_MACHINES // SLICE
     expected = {
         "fleet.job": 1, "fleet.slice": per_slice, "fleet.prepare": per_slice,
+        "fleet.command": 1, "fleet.config": 1, "fleet.mesh": 1,
+        "fleet.plan": 1,
+        # the first slice's program only: the others are memo hits
+        "fleet.trace": 1, "fleet.lower": 1, "fleet.compile": 1,
         "fleet.commit": N_MACHINES, "fleet.fetch": N_MACHINES,
         "fleet.ingest": 2 * per_slice,  # batch assembly, then the device_put
         "fleet.commit_loop": per_slice, "fleet.manifest": per_slice,
@@ -200,7 +230,17 @@ def test_slice_children_cover_it_to_within_its_self_time(traced_build):
         key=lambda s: s.start,
     )
     assert [p.attrs["memo_hit"] for p in programs] == [False, True, True]
-    assert programs[0].attrs["compile_s"] > 0 == programs[1].attrs["compile_s"]
+    staged = [
+        s for s in timeline.spans if s.parent == programs[0].id
+    ]
+    assert [s.name for s in sorted(staged, key=lambda s: s.start)] == [
+        "fleet.trace", "fleet.lower", "fleet.compile"
+    ]
+    assert all(s.attrs["program"] == "train" for s in staged)
+    assert staged[-1].attrs["cache"] in ("hit", "miss", "off")
+    # the stages are the program's work: they fill its span but for the
+    # avatars' shapes
+    assert sum(s.duration for s in staged) > 0.5 * programs[0].duration
 
 
 def test_trace_dir_holds_one_session_and_a_loadable_timeline(traced_build):
@@ -369,9 +409,9 @@ def test_worker_side_spans_and_logs_carry_the_jobs_trace_id(stopped_build):
 @pytest.mark.parametrize("name", READERS)
 def test_reader_reads_the_steady_slices(traced_build, recorder, name):
     reader = importlib.import_module(f"benchmarks.layer_metrics.{name}")
-    assert reader.read({}) is None  # an empty recorder: nothing to read
+    assert reader.read(VIEW) is None  # an empty recorder: nothing to read
     recorder.record(traced_build[0])
-    value = reader.read({})
+    value = reader.read(VIEW)
     assert isinstance(value, float) and value >= 0.0
 
 
@@ -398,7 +438,7 @@ def test_readers_partition_the_steady_slice(traced_build, stopped_build, recorde
             name: importlib.import_module(
                 f"benchmarks.layer_metrics.{name}"
             ).read({})
-            for name in READERS
+            for name in SLICE_READERS
         }
 
     for timeline, steady_index in ((traced_build[0], 1), (stopped_build[0], 1)):
@@ -437,3 +477,158 @@ def test_readers_partition_the_steady_slice(traced_build, stopped_build, recorde
         assert SLICE * per_machine == pytest.approx(
             sum(s.duration for s in commit), abs=1e-9
         )
+
+
+@pytest.mark.parametrize("name", ("fleet.trace", "fleet.lower", "fleet.compile"))
+def test_memo_hit_slice_records_no_program_stage(traced_build, name):
+    """A steady slice finds its executable in the memo: it traces, lowers
+    and compiles nothing, and its spans are the same as before the split."""
+    timeline, _ = traced_build
+    by_id = {span.id: span for span in timeline.spans}
+    programs = [s for s in timeline.spans if s.name == "fleet.program"]
+    staged = [s for s in timeline.spans if s.name == name]
+    assert [by_id[s.parent].attrs["memo_hit"] for s in staged] == [False]
+    for program in programs:
+        if program.attrs["memo_hit"]:
+            assert not [s for s in timeline.spans if s.parent == program.id]
+
+
+def test_compile_span_says_what_the_persistent_cache_answered(
+    tmp_path, monkeypatch, recorder
+):
+    """Two jobs of the same program, each with an empty program memo, over
+    one empty persistent cache that keeps every program: the first compiles
+    and writes it, the second loads it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from gordo_components_tpu.parallel import fleet
+
+    machines = [
+        FleetMachineConfig(
+            name=f"cc-{i}", model_config=MODEL,
+            data_config={**DATASET, "tag_list": [f"cc{i}-a", f"cc{i}-b"]},
+        )
+        for i in range(SLICE)
+    ]
+    was = (
+        jax.config.jax_compilation_cache_dir,
+        jax.config.jax_persistent_cache_min_compile_time_secs,
+    )
+    built = REGISTRY.counter(
+        "gordo_fleet_programs_built_total", labels=("kind", "cache")
+    )
+    before = dict(built.collect())
+    answers = []
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        for job in range(2):
+            compilation_cache.reset_cache()
+            for memo in ("_PROGRAM_CACHE", "_STATE_CACHE", "_EXEC_CACHE"):
+                monkeypatch.setattr(fleet, memo, {})
+            build_fleet(
+                machines, str(tmp_path / f"out-{job}"), n_splits=1,
+                slice_size=SLICE,
+            )
+            timeline = recorder.latest(kind="fleet-build")
+            answers.append([
+                s.attrs["cache"] for s in timeline.spans
+                if s.name == "fleet.compile" and s.attrs["program"] == "train"
+            ])
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was[0])
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", was[1]
+        )
+        compilation_cache.reset_cache()
+    assert answers == [["miss"], ["hit"]]
+    # the counter at the same boundary: one train executable each way
+    after = built.collect()
+    for cache in ("miss", "hit"):
+        key = ("aot", cache)
+        assert after.get(key, 0) - before.get(key, 0) == 1, key
+
+
+def _set_up_end(timeline):
+    """The end of the first slice's commit loop, on the timeline's clock."""
+    (first,) = [
+        s for s in timeline.spans
+        if s.name == "fleet.slice" and s.attrs["slice"] == 0
+    ]
+    (loop,) = [
+        s for s in timeline.spans
+        if s.parent == first.id and s.name == "fleet.commit_loop"
+    ]
+    return first, loop.start + loop.duration
+
+
+def test_setup_readers_add_up_to_the_set_up(traced_build, recorder):
+    """From the process's start to the first slice's commit: the time
+    before the command, the union of the set-up's measured spans and what
+    none of them covers add up to the whole; and the readers read the
+    spans they name."""
+    timeline, _ = traced_build
+    recorder.record(timeline)
+    read = {
+        name: importlib.import_module(
+            f"benchmarks.layer_metrics.{name}"
+        ).read(VIEW)
+        for name in SETUP_READERS
+    }
+    first, end = _set_up_end(timeline)
+    (command,) = [s for s in timeline.spans if s.name == "fleet.command"]
+    listed = [
+        s for s in timeline.spans
+        if s.name in ("fleet.command", "fleet.preamble", "fleet.plan")
+        or (s.parent == first.id and s.name != "fleet.manifest")
+    ]
+    union, edge = 0.0, command.start
+    for s in sorted(listed, key=lambda s: s.start):
+        lo, hi = max(s.start, edge), min(s.start + s.duration, end)
+        if hi > lo:
+            union, edge = union + hi - lo, hi
+    assert read["setup_before_command_s"] + union + read[
+        "setup_unattributed_s"
+    ] == pytest.approx(timeline.started + end - STARTED, abs=1e-3)
+    assert read["setup_before_command_s"] == pytest.approx(
+        timeline.started + command.start - STARTED, abs=1e-9
+    )
+
+    def seconds(*names, parent=None):
+        return sum(
+            s.duration for s in timeline.spans if s.name in names
+            and (parent is None or s.parent == parent)
+        )
+
+    assert read["setup_host_s"] == pytest.approx(
+        seconds("fleet.command", "fleet.preamble", "fleet.plan"), abs=1e-9
+    )
+    assert read["first_fetch_wait_s"] == pytest.approx(
+        seconds("fleet.prefetch_wait", parent=first.id), abs=1e-9
+    )
+    assert read["program_trace_lower_s"] == pytest.approx(
+        seconds("fleet.trace", "fleet.lower"), abs=1e-9
+    )
+    assert read["program_load_s"] == pytest.approx(
+        seconds("fleet.compile"), abs=1e-9
+    )
+    # every phase of the set-up has a span: what is left is the loop's own
+    # few statements between them
+    assert read["setup_unattributed_s"] < 0.25 + 0.05 * (end - command.start)
+
+
+@pytest.mark.parametrize("name", SETUP_READERS)
+def test_setup_reader_reads_nothing_without_the_set_up_spans(
+    traced_build, recorder, name
+):
+    """A program from before the set-up's spans: its timeline has every
+    other span, and the reader gives nothing rather than a wrong number."""
+    timeline, _ = traced_build
+    older = Timeline(timeline.trace_id, **timeline.meta)
+    older.started = timeline.started
+    older.spans = [s for s in timeline.spans if s.name not in SETUP_SPANS]
+    older.finish(status="ok")
+    recorder.record(older)
+    reader = importlib.import_module(f"benchmarks.layer_metrics.{name}")
+    assert reader.read(VIEW) is None
