@@ -125,9 +125,9 @@ class MachineResult(NamedTuple):
     tag_thresholds: jnp.ndarray  # (T,) 99th pct of scaled residuals
     total_threshold: jnp.ndarray  # () 99th pct of residual L2 norms
     # what the model's own loss counted over the final fit's steps (a dict of
-    # arrays; empty for an elementwise loss), and where the fits run in
-    # sequence the samples they predicted (``predicted_samples`` of
-    # ``predictable_samples``): span attributes of the slice
+    # arrays; empty for an elementwise loss), and the samples the fits
+    # predicted (``predicted_samples`` of ``predictable_samples``): span
+    # attributes of the slice
     counters: Any = None
 
 
@@ -222,10 +222,12 @@ def timeseries_fold_masks(wt: jnp.ndarray, n_splits: int):
     trains on every earlier rank (``sklearn.model_selection.TimeSeriesSplit``
     semantics — parity pinned by tests/test_fleet_parity.py). Masks are in
     rank space over real samples, so padding anywhere on the axis (leading
-    row alignment, trailing batch fill) never shifts fold boundaries."""
+    row alignment, trailing batch fill) never shifts fold boundaries. A numpy
+    ``wt`` gives numpy masks (:func:`fold_predict_chunks` runs the rule at
+    trace time)."""
     real = (wt > 0).astype(jnp.float32)
-    n_real = jnp.sum(real).astype(jnp.int32)
-    rank = jnp.cumsum(real) - real  # 0-based rank among real samples
+    n_real = real.sum().astype(jnp.int32)
+    rank = real.cumsum() - real  # 0-based rank among real samples
     test_size = n_real // (n_splits + 1)
     masks = []
     for i in range(n_splits):
@@ -235,6 +237,48 @@ def timeseries_fold_masks(wt: jnp.ndarray, n_splits: int):
         test_mask = real * (rank >= test_start) * (rank < test_end)
         masks.append((train_mask, test_mask))
     return masks
+
+
+def predict_width(spec: FleetSpec, padded: int) -> int:
+    """Samples in one predict call of a windowed model. Prediction has no
+    optimizer state or backward pass, so its chunks can be wider than the
+    training batch: up to 4 training batches in one forward call (largest
+    factor of the step count). The bound is RELATIVE to the training step
+    because the predict runs under the same vmaps as it: a step that keeps
+    its activations holds ~3x its forward pass, so a 4x-wide forward-only
+    chunk peaks at ~4/3 of it. A memory-constrained step recomputes them and
+    is deliberately small, so its chunks stay one batch wide. Values are
+    unchanged: prediction is per-window."""
+    if spec.memory_constrained:
+        return spec.batch_size
+    steps = padded // spec.batch_size
+    return spec.batch_size * next(
+        k for k in range(min(4, steps), 0, -1) if steps % k == 0
+    )
+
+
+def fold_predict_chunks(padded: int, n_splits: int, width: int) -> int:
+    """The chunks of ``width`` samples that hold what any ONE fit of the
+    vmapped fold mode (``n_splits`` ≥ 1) predicts, fixed at trace time: a
+    fold predicts its test samples, ``n_real // (K+1) ≤ padded // (K+1)``,
+    and the final fit every real sample where no fold tests any, which
+    :func:`timeseries_fold_masks` leaves to machines of ``n_real ≤ K``.
+    The rule itself is run on the machines that ask most of each (every
+    sample real; K and K+1 real), and a ``ValueError`` raised where it asks
+    more than that bound: a loop of fewer chunks would drop predictions."""
+    bound = min(max(padded // (n_splits + 1), n_splits), padded)
+    for n_real in (padded, n_splits, n_splits + 1):
+        wt = (np.arange(padded) < n_real).astype(np.float32)
+        tested = [int(test.sum()) for _, test in timeseries_fold_masks(wt, n_splits)]
+        # a fold's test samples, or where none tests any, the fallback's
+        wanted = max(tested) if any(tested) else int(wt.sum())
+        if wanted > bound:
+            raise ValueError(
+                f"the fold rule has a fit of a machine of {int(wt.sum())} "
+                f"real samples of {padded} predict {wanted}, over the "
+                f"{bound} the vmapped fold mode's predict loop holds"
+            )
+    return -(-bound // width)
 
 
 def _initial_params(spec: FleetSpec, n_features: int) -> Callable:
@@ -381,9 +425,8 @@ def make_machine_program(
 
         if la is None:
             fit_local = fit_fn
-            predict_all = lambda params: predict_fn(params, inputs)  # noqa: E731
             # a flat sample is one row: its chunk is the whole set
-            predict_chunk, predict_width = predict_fn, padded
+            predict_chunk, width = predict_fn, padded
         else:
 
             def windowed_apply(variables, starts, **kwargs):
@@ -396,60 +439,58 @@ def make_machine_program(
 
             fit_local = make_fit_fn(windowed_apply, spec.optimizer, **fit_kwargs)
             predict_chunk = make_predict_fn(windowed_apply)
+            width = predict_width(spec, padded)
 
-            # prediction has no optimizer state or backward pass, so its
-            # chunks can be wider than the training batch: up to 4 training
-            # batches in one forward call (largest factor of the step
-            # count). The bound is RELATIVE to the training step because
-            # predict_all runs under the same vmaps as it: a step that
-            # keeps its activations holds ~3x its forward pass, so a
-            # 4x-wide forward-only chunk peaks at ~4/3 of it. A
-            # memory-constrained step recomputes them and is deliberately
-            # small, so its chunks stay one batch wide. Values are
-            # unchanged — prediction is per-window.
-            steps = padded // spec.batch_size
-            if not spec.memory_constrained:
-                predict_width = spec.batch_size * next(
-                    k for k in range(min(4, steps), 0, -1) if steps % k == 0
-                )
-            else:
-                predict_width = spec.batch_size
-
-            def predict_all(params):
-                # bounded-memory full prediction: sequential widened chunks,
-                # so peak HBM per machine stays one (width, L, F) gather
-                chunks = inputs.reshape(-1, predict_width)
-                preds = jax.lax.map(
-                    lambda sb: predict_chunk(params, sb), chunks
-                )
-                return preds.reshape(padded * R, n_targets)
+        def wanted_first(wanted):
+            """``(want, count, order)``: the samples where ``wanted > 0``,
+            how many they are, and every sample's index with theirs first,
+            in index order (a stable sort)."""
+            want = wanted > 0
+            count = jnp.sum(want)
+            return want, count, jnp.argsort(jnp.logical_not(want), stable=True)
 
         def predict_at(params, wanted):
             """Predictions at the samples where ``wanted > 0``, as rows
             ``(padded·R, T)``, zero at every other row: ONE loop over chunks
-            of ``predict_width`` of them (the wanted first, in index order)
+            of ``width`` of them (the wanted first, in index order)
             whose trip count is the chunks they fill, so a fit that wants
             none runs none. The forward is in the graph once: a conditional
             around a second one would double an executable that has to fit
             the machines' compile cache (``PERF.md`` §7 item 13)."""
-            want = wanted > 0
-            count = jnp.sum(want)
-            order = jnp.argsort(jnp.logical_not(want), stable=True)
+            _, count, order = wanted_first(wanted)
 
             def chunk(i, out):
-                at = i * predict_width + jnp.arange(predict_width)
+                at = i * width + jnp.arange(width)
                 samples = order[at]
                 pred = predict_chunk(params, inputs[samples])
                 # the last chunk's tail past the wanted samples is dropped
                 samples = jnp.where(at < count, samples, padded)
                 return out.at[samples].set(
-                    pred.reshape(predict_width, R, n_targets), mode="drop"
+                    pred.reshape(width, R, n_targets), mode="drop"
                 )
 
             out = jax.lax.fori_loop(
-                0, (count + predict_width - 1) // predict_width, chunk,
+                0, (count + width - 1) // width, chunk,
                 jnp.zeros((padded, R, n_targets)),
             )
+            return out.reshape(padded * R, n_targets)
+
+        def predict_within(params, wanted, n_chunks):
+            """:func:`predict_at`'s rows from a loop of a STATIC ``n_chunks``
+            chunks that hold every wanted sample, for fits under a vmap:
+            there a traced trip count would run the longest lane's for every
+            lane, with a batched counter and each chunk's write a batched
+            scatter, where this loop is unbatched and each chunk writes a
+            stack of its own. The rows go back to index order in ONE gather,
+            by each wanted sample's rank among them (the inverse of the
+            sort's permutation), and every other row is exactly zero."""
+            want, _, order = wanted_first(wanted)
+            chunks = order[: n_chunks * width].reshape(n_chunks, width)
+            preds = jax.lax.map(
+                lambda samples: predict_chunk(params, inputs[samples]), chunks
+            ).reshape(n_chunks * width, R, n_targets)
+            rank = jnp.where(want, jnp.cumsum(want) - 1, n_chunks * width)
+            out = preds.at[rank].get(mode="fill", fill_value=0.0)
             return out.reshape(padded * R, n_targets)
 
         keys = jax.random.split(key, spec.n_splits + 2)
@@ -473,6 +514,23 @@ def make_machine_program(
         # to final-model residuals below
         sample_test_masks = test_masks * wt[None, :]  # (K, samples)
         fold_test_masks = per_row(sample_test_masks)  # (K, rows)
+        # A fit predicts only the samples its result reads: a fold its own
+        # test samples, the final fit every real sample where no fold tested
+        # any (the fallback below) and none otherwise; the rows left at zero
+        # are rows that no mask below reads
+        fallback = wt * (jnp.sum(sample_test_masks) == 0)
+        predict_masks = jnp.concatenate([sample_test_masks, fallback[None]])
+        n_fits = spec.n_splits + 1
+
+        def counted(counters):
+            """The fits' counters and the sample passes they predicted, of
+            those a prediction of every padded sample in each would be."""
+            return {
+                **counters,
+                "predicted_samples": jnp.sum(predict_masks > 0),
+                "predictable_samples": jnp.asarray(n_fits * padded),
+            }
+
         if not sequential_fits(spec):
             # parallel CV: the K fold fits and the final fit are independent
             # programs with identical shapes, so ONE vmapped fit of K+1
@@ -485,7 +543,15 @@ def make_machine_program(
                 lambda wv, kv: fit_local(params0, inputs, targets, wv, kv)
             )(all_w, all_keys)
             with jax.named_scope("cv_predict"):
-                preds = jax.vmap(predict_all)(fits.params)  # (K+1, rows, T)
+                if la is None:  # one chunk, the whole set: one call a fit
+                    preds = jax.vmap(lambda p: predict_fn(p, inputs))(
+                        fits.params
+                    )
+                else:
+                    n_chunks = fold_predict_chunks(padded, spec.n_splits, width)
+                    preds = jax.vmap(
+                        lambda p, m: predict_within(p, m, n_chunks)
+                    )(fits.params, predict_masks)  # (K+1, rows, T)
             preds_raw = (preds - sy.offset) / sy.scale
             errs = jnp.abs(raw_targets[None] - preds_raw)
             fmask = (fold_test_masks > 0)[:, :, None]
@@ -495,6 +561,7 @@ def make_machine_program(
                 raw_targets, preds_raw[:-1], fold_test_masks
             )
             final = jax.tree_util.tree_map(lambda a: a[-1], fits)
+            final = final._replace(counters=counted(final.counters))
             state_out = None
         else:
             # sequential fits: ONE fit in the compiled graph, scanned over
@@ -513,14 +580,6 @@ def make_machine_program(
             # would be a second copy of the state, alive through the fit).
             # The final fit runs last and leaves the machine's parameters
             # there.
-
-            # A fit predicts only the samples its result reads: a fold its
-            # own test samples, the final fit every real sample where no
-            # fold tested any (the fallback below) and none otherwise; the
-            # rows left at zero are rows that no mask below reads
-            fallback = wt * (jnp.sum(sample_test_masks) == 0)
-            predict_masks = jnp.concatenate([sample_test_masks, fallback[None]])
-
             def one_fit(carry, xs):
                 (params, opt_state), emin, emax = carry
                 weights, wtest, wpredict, fit_key_, first = xs
@@ -553,7 +612,6 @@ def make_machine_program(
             if state is None:  # a caller without buffers of its own
                 params0 = draw(init_key)
                 state = (params0, spec.optimizer.init(params0))
-            n_fits = spec.n_splits + 1
             (state, emin, emax), (scores, errs, histories, counters) = (
                 jax.lax.scan(
                     one_fit,
@@ -575,13 +633,9 @@ def make_machine_program(
             final = FitResult(
                 params=state[0],
                 loss_history=histories[-1],
-                counters={
-                    **jax.tree_util.tree_map(lambda c: c[-1], counters),
-                    # the sample passes the K+1 fits predicted, of those
-                    # a prediction of every padded sample in each would be
-                    "predicted_samples": jnp.sum(predict_masks > 0),
-                    "predictable_samples": jnp.asarray(n_fits * padded),
-                },
+                counters=counted(
+                    jax.tree_util.tree_map(lambda c: c[-1], counters)
+                ),
             )
             state_out = state[1]
         err_final = errs[-1]
@@ -888,10 +942,11 @@ def fleet_flops_accounting(
     chunk — reads each one's XLA-reported flops, and multiplies by the
     Python-known trip counts from the program structure (no hand FLOP
     model anywhere). ``predict_chunks`` counts BATCH-SIZE-EQUIVALENT
-    chunks, not literal ``lax.map`` iterations: the program may execute
-    wider predict chunks (see ``predict_width`` in
-    :func:`make_machine_program`), and the total is invariant because
-    per-chunk flops are linear in width.
+    chunks of every padded sample in every fit, not literal loop
+    iterations: the program may execute wider predict chunks
+    (:func:`predict_width`; per-chunk flops are linear in width), and
+    predicts only the samples its result reads (:func:`fold_predict_chunks`),
+    which this count leaves as an over-count.
 
     The total is a slight UNDERcount still: scaler fits, fold masks,
     thresholds, and metrics (all O(rows×tags) elementwise, no matmuls) are

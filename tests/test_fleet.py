@@ -203,19 +203,20 @@ def test_cv_parallel_windowed_matches_scan():
 RESULT_READS = ("cv_scores", "error_scaler", "tag_thresholds", "total_threshold")
 
 
-def _windowed_case(case, n_splits=2):
+def _windowed_case(case, n_splits=2, model_config=LSTM_CONFIG):
     """(spec, batch) of LSTM machines of 96 rows (89 windows of 8, padded to
-    96 samples; 16 a batch). ``fallback``: a second machine with 9 real rows,
-    2 real windows, fewer than ``n_splits + 1``; ``holes``: rows 80-81 of the
-    first machine weigh nothing, so the 9 windows over them drop out of the
-    last fold's test region and cut it in two (80 real samples)."""
+    96 samples; 16 a batch). ``fallback``: a second machine with
+    ``n_splits + 7`` real rows, ``n_splits`` real windows, fewer than
+    ``n_splits + 1``; ``holes``: rows 80-81 of the first machine weigh
+    nothing, so the 9 windows over them drop out of the last fold's test
+    region and cut it in two (80 real samples)."""
     n_machines = 1 if case == "one_machine" else 2
     spec, batch = _make_spec_and_batch(
-        n_machines, n_rows=96, model_config=LSTM_CONFIG, n_splits=n_splits
+        n_machines, n_rows=96, model_config=model_config, n_splits=n_splits
     )
     w = batch.w.copy()
     if case == "fallback":
-        w[1, :87] = 0.0
+        w[1, : 89 - n_splits] = 0.0
     if case in ("holes", "counted"):
         w[0, 80:82] = 0.0
     if case == "counted":
@@ -225,13 +226,14 @@ def _windowed_case(case, n_splits=2):
 
 @pytest.mark.parametrize("case", ["one_machine", "fallback", "holes"])
 def test_sequential_fits_read_what_the_vmapped_fits_read(case):
-    """The sequential fold mode predicts only what its result reads (a fold
-    its test samples; the final fit nothing while a fold covers the machine,
-    every real sample where none does) and its result is the vmapped mode's,
-    which predicts every sample in every fit: on a slice of ONE machine (the
-    unbatched predict loop), beside a machine that falls back to the final
-    fit's residuals (the loop under a vmap, to the longer machine's trip
-    count), and with a test region that is not contiguous in index space."""
+    """Both fold modes predict only what their result reads (a fold its
+    test samples; the final fit nothing while a fold covers the machine,
+    every real sample where none does), the sequential one in a loop whose
+    trip count is traced, the vmapped one in a loop of a static count, and
+    their results agree: on a slice of ONE machine (the unbatched predict
+    loop), beside a machine that falls back to the final fit's residuals
+    (the traced loop under a vmap, to the longer machine's trip count), and
+    with a test region that is not contiguous in index space."""
     spec, batch = _windowed_case(case)
     assert not spec.memory_constrained
     fast = train_fleet_arrays(spec, batch)
@@ -251,22 +253,93 @@ def test_sequential_fits_read_what_the_vmapped_fits_read(case):
         assert float(slow.total_threshold[1]) > 0
 
 
+LSTM_BATCH_4 = _definition(
+    "LSTMAutoEncoder", kind="lstm_symmetric", lookback_window=8, dims=[8],
+    epochs=2, batch_size=4,
+)
+
+
+@pytest.mark.parametrize("n_splits", [1, 2, 3])
+@pytest.mark.parametrize("case", ["padding", "holes", "fallback"])
+def test_vmapped_fits_predict_within_the_static_bound(case, n_splits):
+    """The vmapped fold mode predicts a fit's wanted samples in a loop of
+    ``fold_predict_chunks`` chunks, a count fixed at trace time: every fit
+    wants at most that many chunks' samples (a fold its test samples, the
+    final fit where it falls back, with the trailing padding of 89 samples
+    to 92, holes, or a machine of ``n_splits`` real samples), and the
+    result is the sequential mode's. Four samples a batch make the bound
+    several chunks (23 steps: chunks of one batch)."""
+    from gordo_components_tpu.ops import windowing
+    from gordo_components_tpu.parallel.fleet import (
+        fold_predict_chunks,
+        predict_width,
+        timeseries_fold_masks,
+    )
+
+    spec, batch = _windowed_case(case, n_splits, model_config=LSTM_BATCH_4)
+    assert not spec.memory_constrained
+    L, la, n_rows = spec.lookback_window, spec.lookahead, batch.X.shape[1]
+    starts = windowing.window_starts(n_rows, L, la)
+    target = windowing.window_output_index(n_rows, L, la)
+    padded = 92
+    width = predict_width(spec, padded)
+    n_chunks = fold_predict_chunks(padded, n_splits, width)
+    assert (width, n_chunks) == (4, -(-max(padded // (n_splits + 1), n_splits) // 4))
+    counted = []
+    for w in batch.w:
+        real = (w[starts[:, None] + np.arange(L)].min(axis=1) > 0) & (w[target] > 0)
+        wt = np.pad(real.astype(np.float32), (0, padded - real.size))
+        tested = [test.sum() for _, test in timeseries_fold_masks(wt, n_splits)]
+        wanted = tested + [wt.sum() if sum(tested) == 0 else 0.0]
+        assert max(wanted) <= n_chunks * width
+        counted.append(int(sum(wanted)))
+    fast = train_fleet_arrays(spec, batch)
+    slow = train_fleet_arrays(spec._replace(memory_constrained=True), batch)
+    assert np.asarray(fast.counters["predicted_samples"]).tolist() == counted
+    for name in RESULT_READS:
+        for la_, lb in zip(
+            jax.tree_util.tree_leaves(getattr(fast, name)),
+            jax.tree_util.tree_leaves(getattr(slow, name)),
+        ):
+            np.testing.assert_allclose(
+                np.asarray(la_), np.asarray(lb), rtol=2e-4, atol=1e-5,
+                err_msg=f"vmapped vs sequential mismatch in {name} ({case})",
+            )
+
+
+def test_fold_predict_chunks_refuses_a_rule_that_asks_more(monkeypatch):
+    """The static bound is held against the fold rule itself: a rule whose
+    folds test more than ``padded // (K+1)`` samples is refused at trace
+    time, where a loop of the old bound's chunks would drop predictions."""
+    from gordo_components_tpu.parallel import fleet
+
+    def every_sample_tested(wt, n_splits):
+        return [(wt * 0, wt) for _ in range(n_splits)]
+
+    assert fleet.fold_predict_chunks(96, 2, 48) == 1
+    monkeypatch.setattr(fleet, "timeseries_fold_masks", every_sample_tested)
+    with pytest.raises(ValueError, match="over the 32"):
+        fleet.fold_predict_chunks(96, 2, 48)
+
+
 def test_sequential_fits_count_the_samples_they_predict():
     """``predicted_samples``: a covered machine's folds each predict their
     test samples, ``n_real // (K+1)``, and its final fit none; a machine no
     fold covers predicts its real samples in the final fit alone.
     ``predictable_samples``: (K+1) x padded samples. The vmapped mode counts
-    neither."""
+    the same."""
     spec, batch = _windowed_case("counted")
-    slow = train_fleet_arrays(spec._replace(memory_constrained=True), batch)
-    # machine 0: 80 real samples, 2 folds of 80 // 3; machine 1: 2 real
-    assert np.asarray(slow.counters["predicted_samples"]).tolist() == [
-        2 * (80 // 3), 2
-    ]
-    assert np.asarray(slow.counters["predictable_samples"]).tolist() == [
-        3 * 96, 3 * 96
-    ]
-    assert train_fleet_arrays(spec, batch).counters == {}
+    for constrained in (True, False):
+        result = train_fleet_arrays(
+            spec._replace(memory_constrained=constrained), batch
+        )
+        # machine 0: 80 real samples, 2 folds of 80 // 3; machine 1: 2 real
+        assert np.asarray(result.counters["predicted_samples"]).tolist() == [
+            2 * (80 // 3), 2
+        ]
+        assert np.asarray(result.counters["predictable_samples"]).tolist() == [
+            3 * 96, 3 * 96
+        ]
 
 
 @pytest.mark.parametrize("constrained", [False, True])

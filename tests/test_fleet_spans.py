@@ -15,6 +15,7 @@ import logging
 import os
 import time
 
+import numpy as np
 import pytest
 import yaml
 
@@ -416,15 +417,28 @@ def test_reader_reads_the_steady_slices(traced_build, recorder, name):
 
 
 def test_vmapped_folds_count_no_predicted_samples(traced_build, recorder):
-    """``predicted_samples_pct`` reads a program whose fits run in sequence:
-    the vmapped folds' slices carry no such count, so the reader finds
-    nothing, as it finds nothing on a program from before the count."""
-    from benchmarks.layer_metrics import predicted_samples_pct
+    """The vmapped folds predict only what their result reads, as the
+    sequential ones do, and count it the same way: every slice carries
+    ``predicted_samples`` of ``predictable_samples`` a machine, and
+    ``predicted_samples_pct`` reads their share over the steady slices."""
+    from benchmarks.layer_metrics import predicted_samples_pct, slice_spans
 
     recorder.record(traced_build[0])
     slices = [s for s in traced_build[0].spans if s.name == "fleet.slice"]
-    assert slices and all("predicted_samples" not in s.attrs for s in slices)
-    assert predicted_samples_pct.read({}) is None
+    assert slices
+    for one in slices:
+        predicted = np.asarray(one.attrs["predicted_samples"])
+        predictable = np.asarray(one.attrs["predictable_samples"])
+        assert predicted.shape == predictable.shape == (SLICE,)
+        # the one fold (two fits of padded samples) predicts half the real
+        # samples, the final fit none
+        assert np.all(predicted > 0) and np.all(4 * predicted <= predictable)
+    steady = slice_spans.steady()
+    predicted = sum(np.sum(s["attrs"]["predicted_samples"]) for s in steady)
+    predictable = sum(np.sum(s["attrs"]["predictable_samples"]) for s in steady)
+    assert predicted_samples_pct.read({}) == pytest.approx(
+        100.0 * predicted / predictable
+    )
 
 
 def test_readers_partition_the_steady_slice(traced_build, stopped_build, recorder):
